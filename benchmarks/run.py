@@ -68,6 +68,9 @@ def write_json(path: str, rows: List[Dict[str, Any]],
 
 
 def main(argv: Optional[Sequence[str]] = None) -> None:
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     from benchmarks import (
         fig8_dse, fig10_decode, fig11_batch, fig12_e2e, fig14_spurious,
         measured, serve, smoke, tbl_iii_vq_configs, tbl_v_accuracy_proxy,

@@ -26,6 +26,11 @@ The four constants are PER BACKEND. They come from one of two places:
     MatmulPlan (`describe()` prints it), so every log/bench row says
     which model ranked it.
 
+A calibration names the device kind its rows ran on (`device`, from the
+rows' `derived.device`). The Planner applies it only on that device: a
+calibration whose rows name no device, or another device, leaves the
+ranking analytic.
+
 The default calibration file is `CALIBRATION.json` in the current
 working directory; override with the EVA_CALIBRATION environment
 variable. `Planner` loads it at construction and
@@ -81,7 +86,7 @@ class BackendCalibration:
 # these produce matters (it must be deterministic); absolute numbers are
 # provenance-labeled "analytic" everywhere they surface. The byte and
 # launch terms make the two-kernel split backend analytically more
-# expensive than the fused kernel (it round-trips the (C, M, V, 2^n)
+# expensive than the fused kernel (it round-trips the (C, V, M, 2^n)
 # intermediate through HBM and launches twice), which matches the
 # paper's no-fusion-cost argument — measured calibration can flip it.
 ANALYTIC = BackendCalibration(
@@ -94,14 +99,20 @@ ANALYTIC = BackendCalibration(
 
 @dataclasses.dataclass(frozen=True)
 class Calibration:
-    """A versioned set of per-backend fitted constants."""
+    """A versioned set of per-backend fitted constants, measured on the
+    device kind ``device`` ("" when its rows name none)."""
 
     version: str
     source: str
     backends: Mapping[str, BackendCalibration]
+    device: str = ""
 
     def get(self, backend: str) -> Optional[BackendCalibration]:
         return self.backends.get(backend)
+
+    def applies_to(self, device_kind: str) -> bool:
+        """True only when the rows ran on ``device_kind``."""
+        return bool(self.device) and self.device == device_kind
 
 
 def predict_us(cost: Any, entry: BackendCalibration) -> float:
@@ -166,6 +177,9 @@ def eligible_rows(doc: Mapping[str, Any]) -> List[Tuple[str, np.ndarray, float]]
 def fit_calibration(doc: Mapping[str, Any], *, source: str = "<inline>"
                     ) -> Calibration:
     """Fit per-backend constants from an `eva-bench-rows/v1` document."""
+    devices = {str((row.get("derived") or {}).get("device", ""))
+               for row in doc.get("rows", ())}
+    device = devices.pop() if len(devices) == 1 else ""
     by_backend: Dict[str, List[Tuple[np.ndarray, float]]] = {}
     for backend, feat, us in eligible_rows(doc):
         by_backend.setdefault(backend, []).append((feat, us))
@@ -182,7 +196,8 @@ def fit_calibration(doc: Mapping[str, Any], *, source: str = "<inline>"
             us_per_add=float(coef[2]), us_per_byte=float(coef[3]),
             rows=len(samples), mean_abs_rel_err=float(rel.mean()),
         )
-    return Calibration(version=SCHEMA, source=source, backends=backends)
+    return Calibration(version=SCHEMA, source=source, backends=backends,
+                       device=device)
 
 
 def fit_calibration_file(bench_path: str) -> Calibration:
@@ -200,6 +215,7 @@ def save_calibration(calib: Calibration, path: str) -> None:
     doc = {
         "schema": calib.version,
         "source": calib.source,
+        "device": calib.device,
         "backends": {
             name: dataclasses.asdict(entry)
             for name, entry in sorted(calib.backends.items())
@@ -235,7 +251,7 @@ def load_calibration(path: str) -> Optional[Calibration]:
     except (KeyError, TypeError, ValueError):
         return None
     return Calibration(version=SCHEMA, source=str(doc.get("source", path)),
-                       backends=backends)
+                       backends=backends, device=str(doc.get("device", "")))
 
 
 def default_calibration_path() -> str:

@@ -116,12 +116,14 @@ RECON_SLAB_BYTES = 16 * 1024 * 1024
 # dominates.
 _MIN_BLOCK_V = 8
 
-# Shared VMEM budgets for the Pallas kernels' tile models (the fused
-# wrapper's OC scratch holds C*m_tile*V_pad*2^n fp32 and must fit
-# comfortably under the ~16 MB/core VMEM; the gathered/reconstructed tile
-# is each kernel's live slab). The per-kernel tile *functions* live with
-# their wrappers in kernels/*/ops.py — only the budgets are shared.
-FUSED_OC_SCRATCH_BYTES = 8 * 1024 * 1024
+# Shared VMEM budgets for the Pallas kernels' tile models. The fused
+# kernel's OC scratch holds C*V_pad*8*2^n fp32 (one 8-row token tile) for
+# the whole sweep; past this budget (of the 128 MiB VMEM of a v5e core)
+# the plan leaves the shape to the split backend. The gather tile bounds
+# each kernel's per-step streamed slab. The per-kernel tile *functions*
+# live with their wrappers in kernels/*/ops.py — only the budgets are
+# shared.
+FUSED_OC_SCRATCH_BYTES = 96 * 1024 * 1024
 FUSED_GATHER_TILE_BYTES = 2 * 1024 * 1024
 
 

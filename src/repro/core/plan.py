@@ -217,7 +217,7 @@ class PlanCost:
     ``weight_bytes`` : per-call weight-side HBM traffic (compressed for
                        VQ kinds).
     ``intermediate_bytes`` : extra HBM round-trip traffic of multi-kernel
-                       formulations (the split backend's (C, M, V, 2^n)
+                       formulations (the split backend's (C, V, M, 2^n)
                        output-codebook buffer; 0 for fused/jnp paths).
     ``launches``     : kernel launches per call (prices dispatch overhead
                        in the calibrated time model)."""
@@ -398,8 +398,9 @@ class Planner:
     Selection is cost-ranked: every backend whose matcher accepts the
     (spec, policy) pair is built as a candidate and priced through the
     per-backend time model (``calibration`` — fitted constants from
-    CALIBRATION.json — when an entry exists, the shared analytic rates
-    otherwise); the cheapest predicted time wins and ties fall back to
+    CALIBRATION.json — when an entry exists and the calibration was
+    measured on this device kind, the shared analytic rates otherwise);
+    the cheapest predicted time wins and ties fall back to
     registration order. ``calibration="default"`` loads the file named
     by $EVA_CALIBRATION (default ./CALIBRATION.json) at construction;
     ``reload_calibration`` swaps the model for FUTURE planning without
@@ -418,6 +419,7 @@ class Planner:
         self._calibration: Optional[calibrate_mod.Calibration] = (
             calibrate_mod.load_default_calibration()
             if calibration == "default" else calibration)
+        self._refused_for: Any = None  # last (calibration, device) refused
         # graceful degradation: backend name -> monotonic quarantine
         # expiry. A quarantined backend is skipped by ranking until its
         # cool-off passes; both quarantine and release clear the plan
@@ -620,12 +622,29 @@ class Planner:
 
         return run
 
+    def _applied_calibration(self) -> Optional[calibrate_mod.Calibration]:
+        """The loaded calibration when its rows ran on this process's
+        device kind; None (analytic ranking) otherwise."""
+        calib = self._calibration
+        if calib is None:
+            return None
+        kind = jax.devices()[0].device_kind
+        if calib.applies_to(kind):
+            return calib
+        if self._refused_for != (calib, kind):
+            self._refused_for = (calib, kind)
+            log.info("calibration %s was measured on %s, not %s: ranking "
+                     "is analytic", calib.source,
+                     calib.device or "no named device", kind)
+        return None
+
     def _usable_entry(self, backend: str
                       ) -> Optional["calibrate_mod.BackendCalibration"]:
-        """The backend's fitted entry when it rests on enough samples to
-        trust (calibrate.MIN_FIT_ROWS — an NNLS over fewer rows than
-        free parameters fits perfectly but means nothing)."""
-        calib = self._calibration
+        """The backend's fitted entry when the calibration applies to
+        this device and the entry rests on enough samples to trust
+        (calibrate.MIN_FIT_ROWS — an NNLS over fewer rows than free
+        parameters fits perfectly but means nothing)."""
+        calib = self._applied_calibration()
         entry = calib.get(backend) if calib is not None else None
         if entry is not None and entry.rows >= calibrate_mod.MIN_FIT_ROWS:
             return entry

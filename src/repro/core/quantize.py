@@ -33,6 +33,7 @@ Three methods:
 """
 from __future__ import annotations
 
+import zlib
 from typing import TYPE_CHECKING, Any, Dict, List, Optional, Tuple, Union
 
 import jax
@@ -98,7 +99,10 @@ def _quantize_leaf(w, cfg: ModelConfig, method: str, key,
             K=K, N=N, d=d, n=n, splits=splits,
         )
     if method == "synthetic":
-        kk = jax.random.fold_in(key, hash(str(w.shape)) % (2 ** 31))
+        # crc32, not hash(): str hashes are salted per process, and the
+        # same seed must give the same weights in every run
+        kk = jax.random.fold_in(key, zlib.crc32(str(w.shape).encode())
+                                % (2 ** 31))
         base = synthetic_vq(kk, K, N, d=d, n=n, C=C, splits=splits)
         # indices must differ per stacked layer — tile with per-layer perm-ish noise
         if lead:

@@ -72,7 +72,8 @@ class VQWeight:
 
     @property
     def C(self) -> int:
-        return self.codebooks.shape[0] if hasattr(self.codebooks, "shape") else 0
+        # codebooks are (..., C, d, k): leading dims stack layers/experts
+        return self.codebooks.shape[-3] if hasattr(self.codebooks, "shape") else 0
 
     @property
     def V(self) -> int:

@@ -16,21 +16,17 @@ from repro.core import ops as core_ops
 from repro.core import plan as plan_mod
 from repro.core.vq import VQWeight
 from repro.kernels.dequant_gemv.kernel import dequant_gemv_pallas
+from repro.kernels.gather import SUBLANES
 from repro.kernels.dequant_gemv.ref import dequant_gemv_ref
 
 
-def _auto_tiles(M: int, V: int, N: int, d: int) -> Tuple[int, int]:
-    """This kernel's VMEM footprint per grid step is the reconstructed
-    weight slab (bv, bn, d) fp32 plus the (M, bv, d) x tile — no OC
-    scratch — so it gets its own model rather than the fused kernel's:
-    start at the paper's v=32 / 512-lane tiles and shrink bn, then bv,
-    until 4*d*(bv*bn + M*bv) fits the tile budget."""
-    bv, bn = min(32, V), min(512, N)
-    while bn > 128 and 4 * d * (bv * bn + M * bv) > core_ops.FUSED_GATHER_TILE_BYTES:
-        bn //= 2
-    while bv > 8 and 4 * d * (bv * bn + M * bv) > core_ops.FUSED_GATHER_TILE_BYTES:
-        bv //= 2
-    return bv, min(bn, N)
+def _auto_tiles(V: int, N: int) -> Tuple[int, int]:
+    """(block_v, block_n): the paper's v=32 tile height and 512 output
+    lanes, clamped to the problem. Per grid step the kernel holds the
+    rebuilt weight slab (bv, d, bn) fp32 (512 KB at d=8) plus the index
+    tile and its widening — independent of M, whose 8-row tiles the grid
+    walks."""
+    return min(core_ops.DEFAULT_BLOCK_V, V), min(512, N)
 
 
 @functools.partial(
@@ -53,30 +49,31 @@ def dequant_gemv(
     K, N, V, d, C = vq.K, vq.N, vq.V, vq.d, vq.C
     M = x.size // K
     X = x.reshape(M, V, d).astype(jnp.float32)
-    cb = vq.codebooks.transpose(0, 2, 1).astype(jnp.float32)  # (C, k, d)
     # stream indices at storage width (uint8 for n<=8); in-kernel upcast
     I = vq.idx
     scale = vq.scale.astype(jnp.float32)
 
     if not use_pallas:
+        cb = vq.codebooks.transpose(0, 2, 1).astype(jnp.float32)  # (C,k,d)
         y = dequant_gemv_ref(X, cb, I, scale)
         return y.reshape(*lead, N).astype(out_dtype)
 
-    auto_bv, auto_bn = _auto_tiles(M, V, N, d)
+    auto_bv, auto_bn = _auto_tiles(V, N)
     bv = auto_bv if block_v == "auto" else min(block_v, V)
     bn = auto_bn if block_n == "auto" else min(block_n, N)
     pad_v = (-V) % bv
     pad_n = (-N) % bn
+    X = jnp.pad(X, ((0, (-M) % SUBLANES), (0, pad_v), (0, 0)))
     if pad_v:
-        X = jnp.pad(X, ((0, 0), (0, pad_v), (0, 0)))
         I = jnp.pad(I, ((0, 0), (0, pad_v), (0, 0)))
     if pad_n:
         I = jnp.pad(I, ((0, 0), (0, 0), (0, pad_n)))
         scale = jnp.pad(scale, (0, pad_n))
-    y = dequant_gemv_pallas(X, cb, I, scale, block_v=bv, block_n=bn, interpret=interpret)
-    if pad_n:
-        y = y[:, :N]
-    return y.reshape(*lead, N).astype(out_dtype)
+    y = dequant_gemv_pallas(X.reshape(X.shape[0], -1),
+                            vq.codebooks.astype(jnp.float32), I,
+                            scale[None, :], block_v=bv, block_n=bn,
+                            interpret=interpret)
+    return y[:M, :N].reshape(*lead, N).astype(out_dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -92,9 +89,8 @@ def _match_dequant_pallas(spec: plan_mod.LinearSpec,
 
 def _plan_dequant_pallas(spec: plan_mod.LinearSpec,
                          policy: plan_mod.PlanPolicy) -> plan_mod.MatmulPlan:
-    auto_bv, auto_bn = _auto_tiles(spec.M, spec.V, spec.N, spec.d)
+    auto_bv, bn = _auto_tiles(spec.V, spec.N)
     bv = auto_bv if policy.block_v is None else min(policy.block_v, spec.V)
-    bn = auto_bn
     out_dt = jnp.dtype(spec.out_dtype)
     interpret = policy.interpret
 

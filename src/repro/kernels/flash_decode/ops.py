@@ -27,6 +27,7 @@ from repro.kernels.flash_decode.kernel import (flash_decode_kvq_pallas,
                                                flash_decode_pallas)
 from repro.kernels.flash_decode.ref import (flash_decode_kvq_ref,
                                             flash_decode_ref)
+from repro.kernels.gather import SUBLANES
 
 
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret", "use_pallas"))
@@ -46,14 +47,18 @@ def flash_decode(
     if not use_pallas:
         o = flash_decode_ref(q, k, v, lengths)
     else:
-        B, S = k.shape[0], k.shape[1]
+        B, S, Hk, hd = k.shape
+        H = q.shape[1]
         bs = min(block_s, S)
         pad = (-S) % bs
         if pad:
             k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
             v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        o = flash_decode_pallas(q, k, v, lengths.astype(jnp.int32),
-                                block_s=bs, interpret=interpret)
+        # (B, S, Hk*hd) is a free view; the kernel DMAs per-head columns
+        o = flash_decode_pallas(
+            q.reshape(B, Hk, H // Hk, hd), k.reshape(B, S + pad, Hk * hd),
+            v.reshape(B, S + pad, Hk * hd), lengths.astype(jnp.int32),
+            block_s=bs, interpret=interpret).reshape(B, H, hd)
     return o[:, None] if squeeze else o
 
 
@@ -135,17 +140,24 @@ def flash_decode_kvq(
     qd = (qd / math.sqrt(hd)).reshape(B, Hk, g, R * G, E)
     bs = min(block_s, S)
     pad = (-S) % bs
-    if pad:
-        pad4 = ((0, 0), (0, pad), (0, 0), (0, 0))
-        pad3 = ((0, 0), (0, pad), (0, 0))
-        k_idx = jnp.pad(k_idx, pad4)
-        v_idx = jnp.pad(v_idx, pad4)
-        k_s = jnp.pad(k_s, pad3)
-        v_s = jnp.pad(v_s, pad3)
+
+    def tokens_minor(a):  # (B, S, Hk, ...) -> (B, Hk, ..., S + pad)
+        a = jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+        return jnp.moveaxis(a, 1, -1)
+
     o = flash_decode_kvq_pallas(
-        qd, k_idx, v_idx, k_s.astype(jnp.float32), v_s.astype(jnp.float32),
-        cb_v.astype(jnp.float32), lengths.astype(jnp.int32),
+        qd, tokens_minor(k_idx), tokens_minor(v_idx),
+        tokens_minor(k_s.astype(jnp.float32))[:, :, None],
+        tokens_minor(v_s.astype(jnp.float32))[:, :, None],
+        # V tables gather one row per sublane: pre-broadcast each
+        # codebook column over a sublane tile (Hk, R, vd, 8, E)
+        jnp.broadcast_to(
+            jnp.swapaxes(cb_v.astype(jnp.float32), 2, 3)[:, :, :, None],
+            (Hk, R, vd, SUBLANES, E)),
+        lengths.astype(jnp.int32),
         out_dtype=q.dtype, block_s=bs, interpret=interpret)
+    # (B, Hk, vd, g, G) -> (B, Hk*g, G*vd): interleave each group's channels
+    o = jnp.transpose(o, (0, 1, 3, 4, 2)).reshape(B, H, hd)
     return o[:, None] if squeeze else o
 
 

@@ -1,7 +1,8 @@
 """Jit'd wrapper for the fused EVA matmul kernel + its plan backend.
 
-Accepts a VQWeight and activations of any leading shape; handles padding,
-M-tiling (to bound the VMEM OC scratch), and dtype conversion.
+Accepts a VQWeight and activations of any leading shape; handles padding
+(M to the kernel's 8-row token tile, V/N to the block tiles), the
+v-major activation layout, and dtype conversion.
 
 The index matrix is handed to the kernel in its storage dtype (uint8 for
 n <= 8) — the kernel upcasts per streamed tile, so HBM index traffic
@@ -11,7 +12,7 @@ here: one call, one OC scratch fill, every member's output columns swept
 against the same VMEM-resident OC.
 
 This module OWNS the fused kernel's tile model (`select_fused_tiles` /
-`fused_m_tile`, sized against the shared VMEM budgets in core/ops.py)
+`fused_oc_bytes`, checked against the OC budget in core/ops.py)
 and registers the "eva_fused_pallas" backend with core/plan.py: the
 planner freezes (m_tile, block_v, block_n) once per (spec, policy) and
 execution re-derives nothing.
@@ -27,55 +28,33 @@ import jax.numpy as jnp
 from repro.core import ops as core_ops
 from repro.core import plan as plan_mod
 from repro.core.vq import VQWeight
+from repro.kernels.gather import SUBLANES
 from repro.kernels.fused_vq_matmul.kernel import fused_vq_matmul_pallas
 from repro.kernels.fused_vq_matmul.ref import fused_vq_matmul_ref
 
 
-def fused_m_tile(C: int, v_padded: int, k: int) -> int:
-    """Largest m_tile whose VMEM OC scratch (C, m_tile, v_padded, k) fp32
-    stays under FUSED_OC_SCRATCH_BYTES. The single source of truth for
-    the fused wrapper's M-tiling (it passes the ACTUAL padded V)."""
-    return max(1, core_ops.FUSED_OC_SCRATCH_BYTES // max(C * v_padded * k * 4, 1))
+def fused_oc_bytes(V: int, C: int, k: int, block_v: int) -> int:
+    """VMEM held by the fused kernel's OC scratch: (C, V_pad, 8, k) fp32
+    — one 8-row token tile of the output codebook over the whole
+    (block_v-padded) V."""
+    v_padded = V + ((-V) % block_v)
+    return 4 * C * v_padded * SUBLANES * k
 
 
 def select_fused_tiles(M: int, V: int, N: int, C: int, k: int = 256
                        ) -> Tuple[int, int, int]:
     """(m_tile, block_v, block_n) for the fused Pallas wrapper.
 
-    m_tile caps the VMEM OC scratch (C * m_tile * V_pad * k fp32) at
-    FUSED_OC_SCRATCH_BYTES (via fused_m_tile); block_v/block_n bound the
-    gathered epilogue tile (C, m_tile, block_v, block_n) fp32 at
-    FUSED_GATHER_TILE_BYTES, shrinking block_v first (the paper's v=32
-    tile height is the upper bound), then block_n (512-lane default)."""
-    bn = min(512, N)
-    bv = min(core_ops.DEFAULT_BLOCK_V, V)
-    m_tile = min(fused_m_tile(C, V + ((-V) % bv), k), M)
-
-    def tile_bytes(bv_, bn_):
-        return 4 * C * m_tile * bv_ * bn_
-
-    while bv > core_ops._MIN_BLOCK_V and \
-            tile_bytes(bv, bn) > core_ops.FUSED_GATHER_TILE_BYTES:
-        bv //= 2
-    while bn > 128 and tile_bytes(bv, bn) > core_ops.FUSED_GATHER_TILE_BYTES:
-        bn //= 2
-    return m_tile, bv, min(bn, N)
-
-
-def _resolve_m_tile(V: int, C: int, k: int, bv: int, bn: int) -> int:
-    """M-tile for realized tiles (bv, bn): cap the OC scratch at the
-    ACTUAL padded V, then shrink until the gathered tile (C, mt, bv, bn)
-    honors the budget (an explicit block_v may pad more than the auto
-    sizing assumed)."""
-    v_padded = V + ((-V) % bv)
-    mt = fused_m_tile(C, v_padded, k)
-    while mt > 1 and 4 * C * mt * bv * bn > core_ops.FUSED_GATHER_TILE_BYTES:
-        mt = max(1, mt // 2)
-    return mt
+    m_tile is one sublane group (8 token rows; the grid walks M and the
+    wrapper pads it), block_v the paper's v=32 tile height and block_n
+    512 output lanes, each clamped to the problem. The per-step index
+    tile (C, bv, bn) and its int32 widening stay far below the tile
+    budget; the OC scratch (fused_oc_bytes) is what the plan checks."""
+    return SUBLANES, min(core_ops.DEFAULT_BLOCK_V, V), min(512, N)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("block_v", "block_n", "m_tile", "interpret",
+    jax.jit, static_argnames=("block_v", "block_n", "interpret",
                               "use_pallas", "out_dtype")
 )
 def fused_vq_matmul(
@@ -84,17 +63,13 @@ def fused_vq_matmul(
     *,
     block_v="auto",
     block_n="auto",
-    m_tile="auto",
     interpret: bool = False,
     use_pallas: bool = True,
     out_dtype=None,
 ) -> jax.Array:
-    """block_v/block_n/m_tile default to "auto": select_fused_tiles sizes
-    the v/n tiles AND the m-tiling jointly from the VMEM footprint model
-    (OC scratch C*m_tile*V_pad*2^n fp32 capped at FUSED_OC_SCRATCH_BYTES,
-    gathered tile capped at FUSED_GATHER_TILE_BYTES). Explicit ints pin
-    the tile sizes (plans pass fully-resolved tiles; tests / TPU tuning
-    may too)."""
+    """block_v/block_n default to "auto" (select_fused_tiles); explicit
+    ints pin the tile sizes (plans pass fully-resolved tiles; tests /
+    TPU tuning may too)."""
     out_dtype = out_dtype or x.dtype
     lead = x.shape[:-1]
     K, N, V, d, C = vq.K, vq.N, vq.V, vq.d, vq.C
@@ -115,30 +90,20 @@ def fused_vq_matmul(
     bn = auto_bn if block_n == "auto" else min(block_n, N)
     pad_v = (-V) % bv
     pad_n = (-N) % bn
+    pad_m = (-M) % SUBLANES
+    # v-major activations: each v-tile's x block is (bv, 8, d), so the
+    # kernel's OC slab lands in the (C, V, 8, k) scratch without a relayout
+    X = jnp.pad(X, ((0, pad_m), (0, pad_v), (0, 0))).transpose(1, 0, 2)
     if pad_v:
         # padded V rows gather index 0 from zeroed X rows -> contribute 0
-        X = jnp.pad(X, ((0, 0), (0, pad_v), (0, 0)))
         I = jnp.pad(I, ((0, 0), (0, pad_v), (0, 0)))
     if pad_n:
         I = jnp.pad(I, ((0, 0), (0, 0), (0, pad_n)))
         scale = jnp.pad(scale, (0, pad_n))
-
-    # M-tiling bounds the OC scratch at C*mt*V_padded*k*4 bytes per call;
-    # this Python loop is unrolled under jit (one pallas_call per M-tile).
-    mt = _resolve_m_tile(V, C, k, bv, bn) if m_tile == "auto" \
-        else max(1, m_tile)
-    cb = vq.codebooks.astype(jnp.float32)
-    outs = [
-        fused_vq_matmul_pallas(
-            X[m0:m0 + mt], cb, I, scale,
-            block_v=bv, block_n=bn, interpret=interpret,
-        )
-        for m0 in range(0, M, mt)
-    ]
-    y = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
-    if pad_n:
-        y = y[:, :N]
-    return y.reshape(*lead, N).astype(out_dtype)
+    y = fused_vq_matmul_pallas(
+        X, vq.codebooks.astype(jnp.float32), I, scale[None, :],
+        block_v=bv, block_n=bn, interpret=interpret)
+    return y[:M, :N].reshape(*lead, N).astype(out_dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -150,8 +115,13 @@ def fused_vq_matmul(
 
 def _match_eva_fused(spec: plan_mod.LinearSpec, policy: plan_mod.PlanPolicy
                      ) -> bool:
+    # the OC scratch must fit VMEM; wider K leaves the split backend
+    bv = policy.block_v or select_fused_tiles(spec.M, spec.V, spec.N,
+                                              spec.C, spec.k)[1]
     return (spec.kind == "vq" and policy.impl == "pallas"
-            and policy.vq_mode in ("eva", "none"))
+            and policy.vq_mode in ("eva", "none")
+            and fused_oc_bytes(spec.V, spec.C, spec.k, min(bv, spec.V))
+            <= core_ops.FUSED_OC_SCRATCH_BYTES)
 
 
 def _plan_eva_fused(spec: plan_mod.LinearSpec, policy: plan_mod.PlanPolicy
@@ -161,17 +131,14 @@ def _plan_eva_fused(spec: plan_mod.LinearSpec, policy: plan_mod.PlanPolicy
             "impl='pallas' always runs the fused tiled kernel; epilogue="
             f"{policy.epilogue!r} does not apply (pass block_v to size its "
             "v-tiles)")
-    _, auto_bv, auto_bn = select_fused_tiles(spec.M, spec.V, spec.N, spec.C,
-                                             spec.k)
+    mt, auto_bv, bn = select_fused_tiles(spec.M, spec.V, spec.N, spec.C,
+                                         spec.k)
     bv = auto_bv if policy.block_v is None else min(policy.block_v, spec.V)
-    bn = auto_bn
-    # clamp once: the recorded config IS the static m_tile baked into run
-    mt = min(_resolve_m_tile(spec.V, spec.C, spec.k, bv, bn), spec.M)
     out_dt = jnp.dtype(spec.out_dtype)
     interpret = policy.interpret
 
     def run(x, vq):
-        return fused_vq_matmul(x, vq, block_v=bv, block_n=bn, m_tile=mt,
+        return fused_vq_matmul(x, vq, block_v=bv, block_n=bn,
                                interpret=interpret, out_dtype=out_dt)
 
     cost = plan_mod.PlanCost(

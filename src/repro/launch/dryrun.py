@@ -146,7 +146,7 @@ def run_cell(arch: str, shape: str, mesh_kind: str, out_dir: str,
         )
         mem = compiled.memory_analysis()
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):  # jax-0.4.37 API drift
+        if isinstance(ca, (list, tuple)):  # one entry per program
             ca = ca[0] if ca else {}
         result.update({
             "status": "ok",

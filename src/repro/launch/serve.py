@@ -9,7 +9,7 @@ from __future__ import annotations
 
 import argparse
 import time
-from typing import Any, Dict
+from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -17,31 +17,42 @@ import numpy as np
 
 from repro.configs import get_config, get_smoke_config
 from repro.core.plan import PlanPolicy
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.api import build_model
 from repro.models.common import RunConfig
 from repro.serve import Engine, EngineConfig, GenerationRequest, SamplingParams
 
 
 def serve(arch: str = "llama2-7b", *, smoke: bool = True, requests: int = 8,
-          max_new: int = 16, prompt_len: int = 12, num_slots: int = 4,
+          max_new: int = 16, prompt_len: int = 12, min_prompt_len: int = 4,
+          num_slots: int = 4, max_len: Optional[int] = None,
           vq_mode: str = "eva", quantize: bool = True,
-          impl: str = "jnp", seed: int = 0,
+          impl: str = "jnp", interpret: bool = False, seed: int = 0,
           sample: bool = False, temperature: float = 0.8, top_k: int = 40,
-          top_p: float = 0.95, eos: Any = None) -> Dict[str, Any]:
-    """Drive a synthetic trace through the engine. ``sample=True`` mixes
-    sampled requests (temperature/top_k/top_p, per-request seeds) among
-    the greedy ones; ``eos`` adds a per-request stop token."""
+          top_p: float = 0.95, eos: Any = None, params: Any = None,
+          **engine_kw: Any) -> Dict[str, Any]:
+    """Drive a synthetic trace through the engine. Prompt lengths are
+    drawn from [min_prompt_len, prompt_len]; ``max_len`` defaults to the
+    longest request. ``sample=True`` mixes sampled requests
+    (temperature/top_k/top_p, per-request seeds) among the greedy ones;
+    ``eos`` adds a per-request stop token. ``params`` reuses weights built
+    once for several calls; ``engine_kw`` are further EngineConfig fields
+    (``paged``, ``kv_bits``, ``fault_plan``, ...)."""
     cfg = get_smoke_config(arch) if smoke else get_config(arch)
     model = build_model(cfg)
     key = jax.random.PRNGKey(seed)
-    params = model.init(key)
-    if quantize:
-        params = model.quantize(params, method="synthetic", key=key)
+    if params is None:
+        # synthetic EVA weights are built on the device without dense
+        # weight matrices, so a full-width model fits wherever its 2-bit
+        # form does
+        params = model.init_synthetic(key) if quantize else model.init(key)
     rc = RunConfig(mode="decode", remat=False, attn_chunk=64,
                    plan_policy=PlanPolicy(
-                       vq_mode=vq_mode if quantize else "none", impl=impl))
+                       vq_mode=vq_mode if quantize else "none", impl=impl,
+                       interpret=interpret))
     ecfg = EngineConfig(num_slots=num_slots,
-                        max_len=prompt_len + max_new + 8)
+                        max_len=max_len or prompt_len + max_new + 8,
+                        **engine_kw)
     extras = {}
     if cfg.family == "whisper":
         extras["frames"] = np.asarray(
@@ -55,7 +66,7 @@ def serve(arch: str = "llama2-7b", *, smoke: bool = True, requests: int = 8,
     reqs = []
     for i in range(requests):
         prompt = rng.integers(0, cfg.vocab_size,
-                              rng.integers(4, prompt_len + 1))
+                              rng.integers(min_prompt_len, prompt_len + 1))
         sp = SamplingParams() if not sample or i % 2 == 0 else SamplingParams(
             greedy=False, temperature=temperature, top_k=top_k, top_p=top_p,
             seed=i)
@@ -74,6 +85,7 @@ def serve(arch: str = "llama2-7b", *, smoke: bool = True, requests: int = 8,
         "outputs": {u: eng.output(u) for u in uids},
         "events": events,
         "metrics": eng.metrics(),
+        "plans": eng.plans,
         "wall_s": dt,
         "tokens": total_tokens,
         "tok_per_s": total_tokens / max(dt, 1e-9),
@@ -95,6 +107,7 @@ def main():
     ap.add_argument("--eos", type=int, default=None,
                     help="per-request stop token id")
     args = ap.parse_args()
+    use_compile_cache()
     out = serve(args.arch, smoke=args.smoke, requests=args.requests,
                 max_new=args.max_new, num_slots=args.slots,
                 vq_mode=args.vq_mode, quantize=args.quantize,

@@ -2,6 +2,7 @@
 
     model = Model(cfg)
     params = model.init(key)                      # dense training params
+    params = model.init_synthetic(key)            # EVA serving params
     logits, _ = model.forward(params, batch, rc)  # train-mode forward
     loss = model.loss(params, batch, rc)
     caches = model.init_cache(batch_size, max_len)
@@ -65,6 +66,21 @@ class Model:
         return quantize_params(params, self.cfg, method=method, key=key,
                                quantize_lm_head=quantize_lm_head,
                                mesh=mesh, report=report)
+
+    def init_synthetic(self, key, *, quantize_lm_head: bool = False) -> Any:
+        """EVA serving params with synthetic VQ weights, built on the
+        device in one program: ``quantize(init(key), method="synthetic")``
+        under jit. Synthetic VQ leaves depend only on their shapes and
+        ``key``, so XLA drops the dense initializers they replace and no
+        dense weight matrix is materialized — a full-width model fits
+        wherever its quantized form does. The leaves that stay dense
+        (embedding, lm_head, norms) come from the model's own initializer,
+        cast to their serving dtype inside the same program."""
+        def build(k):
+            return self.quantize(self.init(k), method="synthetic", key=k,
+                                 quantize_lm_head=quantize_lm_head)
+
+        return jax.jit(build)(key)
 
     # --------------------------------------------------------------- forward
     def _extra_kwargs(self, batch: Dict[str, Any]) -> Dict[str, Any]:

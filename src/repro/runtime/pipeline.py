@@ -19,7 +19,6 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -110,7 +109,7 @@ def make_pipelined_forward(
         param_specs = jax.tree_util.tree_map(
             lambda a: P(axis, *([None] * (a.ndim - 1))), stage_params
         )
-        outs = shard_map(
+        outs = jax.shard_map(
             inner, mesh=mesh,
             in_specs=(param_specs, P()),
             out_specs=P(),

@@ -24,14 +24,10 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
 def _abstract_mesh():
-    """Production-shaped AbstractMesh across jax API revisions (0.4.37
-    takes ((name, size), ...) pairs; older releases took sizes + names)."""
+    """Production-shaped AbstractMesh (no devices)."""
     from jax.sharding import AbstractMesh
 
-    try:
-        return AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
-    except TypeError:
-        return AbstractMesh((2, 16, 16), ("pod", "data", "model"))
+    return AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 class TestShardingRules:
@@ -98,7 +94,7 @@ _SUBPROC_SCRIPT = textwrap.dedent("""
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     from repro.configs import get_smoke_config
     from repro.data import DataConfig, DataPipeline, global_batch_at

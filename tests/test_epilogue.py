@@ -241,7 +241,8 @@ class TestFusedTiles:
         from repro.kernels.fused_vq_matmul.ops import select_fused_tiles
 
         mt, bv, bn = select_fused_tiles(1, 10, 70, 2, 256)
-        assert mt == 1 and bv == 10 and bn == 70
+        # one 8-row token tile; v/n tiles clamp to the problem
+        assert mt == 8 and bv == 10 and bn == 70
 
     def test_block_v_upper_bound_is_paper_tile(self):
         from repro.kernels.fused_vq_matmul.ops import select_fused_tiles

@@ -233,7 +233,8 @@ class TestRankedSelection:
         return calibrate.Calibration(
             version=calibrate.SCHEMA, source="test",
             backends={"eva_fused_pallas": cls._entry(fused_overhead, rows),
-                      "eva_split_pallas": cls._entry(split_overhead, rows)})
+                      "eva_split_pallas": cls._entry(split_overhead, rows)},
+            device=jax.devices()[0].device_kind)
 
     def test_analytic_fallback_ranks_fused_first(self):
         """No calibration: the analytic model prices the split backend's
@@ -281,6 +282,19 @@ class TestRankedSelection:
         planner = plan_mod.Planner(calibration=partial)
         pl = planner.plan(self._spec(x, vq), self.PALLAS)
         assert pl.backend == "eva_fused_pallas"  # analytic order holds
+        assert pl.provenance == "analytic"
+
+    @pytest.mark.parametrize("device", ["", "TPU v5 lite"])
+    def test_calibration_from_another_device_not_applied(self, device):
+        """A calibration whose rows name no device, or a device other
+        than this process's, leaves the ranking analytic."""
+        import dataclasses as dc
+
+        x, vq = _mk(80, 70, (), 2)
+        foreign = dc.replace(self._calib(1e6, 1.0), device=device)
+        planner = plan_mod.Planner(calibration=foreign)
+        pl = planner.plan(self._spec(x, vq), self.PALLAS)
+        assert pl.backend == "eva_fused_pallas"
         assert pl.provenance == "analytic"
 
     def test_underfitted_entries_not_trusted_for_ranking(self):

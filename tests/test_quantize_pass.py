@@ -132,3 +132,36 @@ class TestStructure:
             w_hat = np.asarray(dequantize(vql))
             errs.append(np.linalg.norm(wl - w_hat) / np.linalg.norm(wl))
         assert max(errs) < 0.9  # random-gaussian bound; structured << this
+
+
+_SYNTHETIC_DIGEST = """
+import hashlib, jax, numpy as np
+from repro.configs import get_smoke_config
+from repro.models import build_model
+params = build_model(get_smoke_config("minitron_4b")).init_synthetic(
+    jax.random.PRNGKey(7))
+h = hashlib.sha256()
+for leaf in jax.tree_util.tree_leaves(params):
+    h.update(np.asarray(leaf).tobytes())
+print(h.hexdigest())
+"""
+
+
+def test_synthetic_weights_depend_only_on_the_seed():
+    """Two processes with different string-hash salts build identical
+    synthetic params from one seed."""
+    import os
+    import subprocess
+    import sys
+
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    digests = []
+    for salt in ("1", "2"):
+        env = dict(os.environ, PYTHONHASHSEED=salt, JAX_PLATFORMS="cpu",
+                   PYTHONPATH=src)
+        proc = subprocess.run([sys.executable, "-c", _SYNTHETIC_DIGEST],
+                              env=env, capture_output=True, text=True,
+                              timeout=300)
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        digests.append(proc.stdout.split()[-1])
+    assert digests[0] == digests[1]

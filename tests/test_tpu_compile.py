@@ -1,0 +1,132 @@
+"""Compile every Pallas kernel at Minitron-4B widths for a described TPU
+v5e (no chip attached): the TPU compiler refuses here what interpret mode
+cannot show — gathers it cannot lower, misaligned blocks, VMEM overruns.
+
+Minitron-4B: d_model 3072, 24 heads (GQA kv=8, head_dim 128), d_ff 9216,
+2-bit EVA weights (C=2, d=8, n=8). Decode linears run at M=8 slots; the
+int8 prefill GEMM at a 512-token bucket; decode attention at B=8 over a
+2048-token cache.
+
+The topology is described inside a module fixture (never at import:
+only one process may load the TPU library, and pytest-xdist workers all
+import this file), and JAX's persistent compilation cache is off around
+these compiles (an entry written for a described chip cannot be read
+back without one).
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.vq import KVQuantConfig, VQWeight
+
+M_DECODE = 8
+M_PREFILL = 512
+B, S, H, HK, HD = 8, 2048, 24, 8, 128
+# (name, K, N, splits): the grouped QKV family, the o-projection, the
+# grouped gate/up family and the down projection of one layer
+LINEARS = [
+    ("wqkv", 3072, 5120, (3072, 1024, 1024)),
+    ("wo", 3072, 3072, ()),
+    ("gu", 3072, 18432, (9216, 9216)),
+    ("down", 9216, 3072, ()),
+]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means no topology
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.experimental.compilation_cache import compilation_cache
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+def _sds(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _vq(sharding, K, N, splits):
+    return VQWeight(idx=_sds(sharding, (2, K // 8, N), jnp.uint8),
+                    codebooks=_sds(sharding, (2, 8, 256), jnp.float32),
+                    scale=_sds(sharding, (N,), jnp.float32),
+                    K=K, N=N, d=8, n=8, splits=splits)
+
+
+def _assert_kernel(lowered):
+    compiled = lowered.compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+@pytest.mark.parametrize("name,K,N,splits", LINEARS)
+def test_fused_vq_matmul_compiles(one_chip, name, K, N, splits):
+    from repro.kernels.fused_vq_matmul import fused_vq_matmul
+
+    x = _sds(one_chip, (M_DECODE, K), jnp.bfloat16)
+    _assert_kernel(fused_vq_matmul.lower(x, _vq(one_chip, K, N, splits),
+                                         out_dtype=jnp.bfloat16))
+
+
+@pytest.mark.parametrize("name,K,N,splits", LINEARS)
+def test_eva_split_compiles(one_chip, name, K, N, splits):
+    """vq_gemm -> HBM output codebook -> oc_lookup."""
+    from repro.kernels.oc_lookup.ops import eva_split_matmul
+
+    x = _sds(one_chip, (M_DECODE, K), jnp.bfloat16)
+    _assert_kernel(jax.jit(eva_split_matmul).lower(
+        x, _vq(one_chip, K, N, splits)))
+
+
+@pytest.mark.parametrize("name,K,N,splits", LINEARS[::3])
+def test_dequant_gemv_compiles(one_chip, name, K, N, splits):
+    from repro.kernels.dequant_gemv import dequant_gemv
+
+    x = _sds(one_chip, (M_DECODE, K), jnp.bfloat16)
+    _assert_kernel(dequant_gemv.lower(x, _vq(one_chip, K, N, splits)))
+
+
+def test_int8_gemm_compiles(one_chip):
+    from repro.kernels.int8_gemm import int8_matmul_kernel
+
+    x = _sds(one_chip, (M_PREFILL, 3072), jnp.bfloat16)
+    w = _sds(one_chip, (3072, 5120), jnp.bfloat16)
+    _assert_kernel(int8_matmul_kernel.lower(x, w))
+
+
+def test_flash_decode_compiles(one_chip):
+    from repro.kernels.flash_decode import flash_decode
+
+    q = _sds(one_chip, (B, 1, H, HD), jnp.bfloat16)
+    kv = _sds(one_chip, (B, S, HK, HD), jnp.bfloat16)
+    lens = _sds(one_chip, (B,), jnp.int32)
+    _assert_kernel(flash_decode.lower(q, kv, kv, lens))
+
+
+@pytest.mark.parametrize("kv_bits", [4, 2])
+def test_flash_decode_kvq_compiles(one_chip, kv_bits):
+    from repro.kernels.flash_decode import flash_decode_kvq
+
+    kvq = KVQuantConfig(kv_bits=kv_bits)
+    w = kvq.idx_width(HD)
+    q = _sds(one_chip, (B, 1, H, HD), jnp.bfloat16)
+    idx = _sds(one_chip, (B, S, HK, w), jnp.uint8)
+    sc = _sds(one_chip, (B, S, HK), jnp.bfloat16)
+    lens = _sds(one_chip, (B,), jnp.int32)
+    cb = _sds(one_chip, (HK, kvq.residual, kvq.entries, kvq.vec_d),
+              jnp.float32)
+    _assert_kernel(flash_decode_kvq.lower(q, idx, idx, sc, sc, lens, cb, cb))
