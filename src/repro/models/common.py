@@ -557,53 +557,59 @@ def attention_fwd(
             # over the compressed arena view.
             variant = rc.kv_vq.variant if rc.kv_vq is not None else "outlier"
             cb_k, cb_v = p["kv_cb"]["k"], p["kv_cb"]["v"]
-            k_idx, k_sc = kv_encode(k, cb_k, variant)
-            v_idx, v_sc = kv_encode(v, cb_v, variant)
-            k_arena = cache["k"].at[phys, off].set(k_idx, mode="drop")
-            v_arena = cache["v"].at[phys, off].set(v_idx, mode="drop")
-            ks_arena = cache["k_s"].at[phys, off].set(
-                k_sc.astype(cache["k_s"].dtype), mode="drop")
-            vs_arena = cache["v_s"].at[phys, off].set(
-                v_sc.astype(cache["v_s"].dtype), mode="drop")
-            o = _kvq_decode_attention(
-                q, _paged_view(k_arena, bt), _paged_view(v_arena, bt),
-                _paged_view(ks_arena, bt), _paged_view(vs_arena, bt),
-                new_len, cb_k, cb_v, rc, window)
+            with jax.named_scope("kv_write"):
+                k_idx, k_sc = kv_encode(k, cb_k, variant)
+                v_idx, v_sc = kv_encode(v, cb_v, variant)
+                k_arena = cache["k"].at[phys, off].set(k_idx, mode="drop")
+                v_arena = cache["v"].at[phys, off].set(v_idx, mode="drop")
+                ks_arena = cache["k_s"].at[phys, off].set(
+                    k_sc.astype(cache["k_s"].dtype), mode="drop")
+                vs_arena = cache["v_s"].at[phys, off].set(
+                    v_sc.astype(cache["v_s"].dtype), mode="drop")
+            with jax.named_scope("attend"):
+                o = _kvq_decode_attention(
+                    q, _paged_view(k_arena, bt), _paged_view(v_arena, bt),
+                    _paged_view(ks_arena, bt), _paged_view(vs_arena, bt),
+                    new_len, cb_k, cb_v, rc, window)
             new_cache = {"k": k_arena, "v": v_arena, "k_s": ks_arena,
                          "v_s": vs_arena, "len": new_len,
                          "block_table": bt}
         elif "k_s" in cache:
             cdt = cache["k"].dtype
-            kq, ks_ = _quantize_kv(k, cdt)
-            vq_, vs_ = _quantize_kv(v, cdt)
-            k_arena = cache["k"].at[phys, off].set(kq, mode="drop")
-            v_arena = cache["v"].at[phys, off].set(vq_, mode="drop")
-            ks_arena = cache["k_s"].at[phys, off].set(ks_, mode="drop")
-            vs_arena = cache["v_s"].at[phys, off].set(vs_, mode="drop")
-            k_view = (_paged_view(k_arena, bt).astype(jnp.bfloat16)
-                      * _paged_view(ks_arena, bt)[..., None].astype(jnp.bfloat16))
-            v_view = (_paged_view(v_arena, bt).astype(jnp.bfloat16)
-                      * _paged_view(vs_arena, bt)[..., None].astype(jnp.bfloat16))
-            o = decode_attention(q, k_view, v_view, new_len,
-                                 window=window, ring=window > 0)
+            with jax.named_scope("kv_write"):
+                kq, ks_ = _quantize_kv(k, cdt)
+                vq_, vs_ = _quantize_kv(v, cdt)
+                k_arena = cache["k"].at[phys, off].set(kq, mode="drop")
+                v_arena = cache["v"].at[phys, off].set(vq_, mode="drop")
+                ks_arena = cache["k_s"].at[phys, off].set(ks_, mode="drop")
+                vs_arena = cache["v_s"].at[phys, off].set(vs_, mode="drop")
+            with jax.named_scope("attend"):
+                k_view = (_paged_view(k_arena, bt).astype(jnp.bfloat16)
+                          * _paged_view(ks_arena, bt)[..., None].astype(jnp.bfloat16))
+                v_view = (_paged_view(v_arena, bt).astype(jnp.bfloat16)
+                          * _paged_view(vs_arena, bt)[..., None].astype(jnp.bfloat16))
+                o = decode_attention(q, k_view, v_view, new_len,
+                                     window=window, ring=window > 0)
             new_cache = {"k": k_arena, "v": v_arena, "k_s": ks_arena,
                          "v_s": vs_arena, "len": new_len,
                          "block_table": bt}
         else:
-            k_arena = cache["k"].at[phys, off].set(
-                k.astype(cache["k"].dtype), mode="drop")
-            v_arena = cache["v"].at[phys, off].set(
-                v.astype(cache["v"].dtype), mode="drop")
-            if rc.policy.impl == "pallas" and window == 0 and S == 1:
-                from repro.kernels.flash_decode import flash_decode_paged
+            with jax.named_scope("kv_write"):
+                k_arena = cache["k"].at[phys, off].set(
+                    k.astype(cache["k"].dtype), mode="drop")
+                v_arena = cache["v"].at[phys, off].set(
+                    v.astype(cache["v"].dtype), mode="drop")
+            with jax.named_scope("attend"):
+                if rc.policy.impl == "pallas" and window == 0 and S == 1:
+                    from repro.kernels.flash_decode import flash_decode_paged
 
-                o = flash_decode_paged(q, k_arena, v_arena, bt, new_len,
-                                       interpret=rc.policy.interpret)
-            else:
-                o = decode_attention(
-                    q, _paged_view(k_arena, bt), _paged_view(v_arena, bt),
-                    new_len, window=window, ring=window > 0,
-                )
+                    o = flash_decode_paged(q, k_arena, v_arena, bt, new_len,
+                                           interpret=rc.policy.interpret)
+                else:
+                    o = decode_attention(
+                        q, _paged_view(k_arena, bt), _paged_view(v_arena, bt),
+                        new_len, window=window, ring=window > 0,
+                    )
             new_cache = {"k": k_arena, "v": v_arena, "len": new_len,
                          "block_table": bt}
     elif rc.mode == "decode" and cache is not None and kv_source is None:
@@ -625,56 +631,63 @@ def attention_fwd(
             # the (ring) cache, attend via the planned backend
             variant = rc.kv_vq.variant if rc.kv_vq is not None else "outlier"
             cb_k, cb_v = p["kv_cb"]["k"], p["kv_cb"]["v"]
-            k_idx, k_sc = kv_encode(k, cb_k, variant)
-            v_idx, v_sc = kv_encode(v, cb_v, variant)
-            k_cache = cache["k"].at[b_iota, slot].set(k_idx, mode="drop")
-            v_cache = cache["v"].at[b_iota, slot].set(v_idx, mode="drop")
-            k_s = cache["k_s"].at[b_iota, slot].set(
-                k_sc.astype(cache["k_s"].dtype), mode="drop")
-            v_s = cache["v_s"].at[b_iota, slot].set(
-                v_sc.astype(cache["v_s"].dtype), mode="drop")
+            with jax.named_scope("kv_write"):
+                k_idx, k_sc = kv_encode(k, cb_k, variant)
+                v_idx, v_sc = kv_encode(v, cb_v, variant)
+                k_cache = cache["k"].at[b_iota, slot].set(k_idx, mode="drop")
+                v_cache = cache["v"].at[b_iota, slot].set(v_idx, mode="drop")
+                k_s = cache["k_s"].at[b_iota, slot].set(
+                    k_sc.astype(cache["k_s"].dtype), mode="drop")
+                v_s = cache["v_s"].at[b_iota, slot].set(
+                    v_sc.astype(cache["v_s"].dtype), mode="drop")
             new_len = cache_len + S
-            o = _kvq_decode_attention(q, k_cache, v_cache, k_s, v_s,
-                                      new_len, cb_k, cb_v, rc, window)
+            with jax.named_scope("attend"):
+                o = _kvq_decode_attention(q, k_cache, v_cache, k_s, v_s,
+                                          new_len, cb_k, cb_v, rc, window)
             new_cache = {"k": k_cache, "v": v_cache, "k_s": k_s, "v_s": v_s,
                          "len": new_len}
         elif int8_cache:
             cdt = cache["k"].dtype
-            kq, ks_ = _quantize_kv(k, cdt)
-            vq_, vs_ = _quantize_kv(v, cdt)
-            k_cache = cache["k"].at[b_iota, slot].set(kq, mode="drop")
-            v_cache = cache["v"].at[b_iota, slot].set(vq_, mode="drop")
-            k_s = cache["k_s"].at[b_iota, slot].set(ks_, mode="drop")
-            v_s = cache["v_s"].at[b_iota, slot].set(vs_, mode="drop")
+            with jax.named_scope("kv_write"):
+                kq, ks_ = _quantize_kv(k, cdt)
+                vq_, vs_ = _quantize_kv(v, cdt)
+                k_cache = cache["k"].at[b_iota, slot].set(kq, mode="drop")
+                v_cache = cache["v"].at[b_iota, slot].set(vq_, mode="drop")
+                k_s = cache["k_s"].at[b_iota, slot].set(ks_, mode="drop")
+                v_s = cache["v_s"].at[b_iota, slot].set(vs_, mode="drop")
             new_len = cache_len + S
-            o = decode_attention(
-                q,
-                k_cache.astype(jnp.bfloat16) * k_s[..., None].astype(jnp.bfloat16),
-                v_cache.astype(jnp.bfloat16) * v_s[..., None].astype(jnp.bfloat16),
-                new_len, window=window, ring=window > 0,
-            )
+            with jax.named_scope("attend"):
+                o = decode_attention(
+                    q,
+                    k_cache.astype(jnp.bfloat16) * k_s[..., None].astype(jnp.bfloat16),
+                    v_cache.astype(jnp.bfloat16) * v_s[..., None].astype(jnp.bfloat16),
+                    new_len, window=window, ring=window > 0,
+                )
             new_cache = {"k": k_cache, "v": v_cache, "k_s": k_s, "v_s": v_s,
                          "len": new_len}
         else:
-            k_cache = cache["k"].at[b_iota, slot].set(
-                k.astype(cache["k"].dtype), mode="drop")
-            v_cache = cache["v"].at[b_iota, slot].set(
-                v.astype(cache["v"].dtype), mode="drop")
+            with jax.named_scope("kv_write"):
+                k_cache = cache["k"].at[b_iota, slot].set(
+                    k.astype(cache["k"].dtype), mode="drop")
+                v_cache = cache["v"].at[b_iota, slot].set(
+                    v.astype(cache["v"].dtype), mode="drop")
             new_len = cache_len + S
-            if rc.policy.impl == "pallas" and window == 0 and S == 1:
-                from repro.kernels.flash_decode import flash_decode
+            with jax.named_scope("attend"):
+                if rc.policy.impl == "pallas" and window == 0 and S == 1:
+                    from repro.kernels.flash_decode import flash_decode
 
-                o = flash_decode(q, k_cache, v_cache, new_len,
-                                 interpret=rc.policy.interpret)
-            else:
-                o = decode_attention(
-                    q, k_cache, v_cache, new_len, window=window,
-                    ring=window > 0,
-                )
+                    o = flash_decode(q, k_cache, v_cache, new_len,
+                                     interpret=rc.policy.interpret)
+                else:
+                    o = decode_attention(
+                        q, k_cache, v_cache, new_len, window=window,
+                        ring=window > 0,
+                    )
             new_cache = {"k": k_cache, "v": v_cache, "len": new_len}
     elif rc.mode == "decode" and cache is not None and kv_source is not None:
         # cross-attention decode: static memory cache
-        o = decode_attention(q, cache["k"], cache["v"], cache["len"])
+        with jax.named_scope("attend"):
+            o = decode_attention(q, cache["k"], cache["v"], cache["len"])
         new_cache = cache
     elif (cache is not None and "block_table" in cache
           and kv_source is None):
@@ -708,24 +721,27 @@ def attention_fwd(
         blk_ids = jnp.take(bt[0], jnp.clip(p0 // bs_blk, 0, W - 1))
         phys = jnp.where(valid, blk_ids, NB)
         off = p0 % bs_blk
-        k_arena = cache["k"].at[phys, off].set(
-            k[0].astype(cache["k"].dtype), mode="drop")
-        v_arena = cache["v"].at[phys, off].set(
-            v[0].astype(cache["v"].dtype), mode="drop")
+        with jax.named_scope("kv_write"):
+            k_arena = cache["k"].at[phys, off].set(
+                k[0].astype(cache["k"].dtype), mode="drop")
+            v_arena = cache["v"].at[phys, off].set(
+                v[0].astype(cache["v"].dtype), mode="drop")
         # traced q_offset forbids the static chunk-skip schedule
-        o = blocked_attention(
-            q, _paged_view(k_arena, bt), _paged_view(v_arena, bt),
-            causal=causal, window=window, q_offset=hist[0],
-            chunk=rc.attn_chunk, skip_oob_chunks=False,
-        )
+        with jax.named_scope("attend"):
+            o = blocked_attention(
+                q, _paged_view(k_arena, bt), _paged_view(v_arena, bt),
+                causal=causal, window=window, q_offset=hist[0],
+                chunk=rc.attn_chunk, skip_oob_chunks=False,
+            )
         new_cache = {"k": k_arena, "v": v_arena, "len": hist + true_c,
                      "block_table": bt, "prefill_len": true_c}
     else:
-        o = blocked_attention(
-            q, k, v,
-            causal=causal, window=window,
-            chunk=rc.attn_chunk, skip_oob_chunks=rc.attn_skip_oob_chunks,
-        )
+        with jax.named_scope("attend"):
+            o = blocked_attention(
+                q, k, v,
+                causal=causal, window=window,
+                chunk=rc.attn_chunk, skip_oob_chunks=rc.attn_skip_oob_chunks,
+            )
         if rc.mode == "prefill":
             new_cache = {"k": k, "v": v, "len": positions[:, -1] + 1}
 
@@ -1112,6 +1128,7 @@ def embed(p: Params, tokens: jax.Array, dtype) -> jax.Array:
     return jnp.take(p["emb"], tokens, axis=0).astype(dtype)
 
 
+@jax.named_scope("lm_head")
 def lm_head(p: Params, x: jax.Array, rc: RunConfig, emb_params=None) -> jax.Array:
     if p is None:  # tied
         w = emb_params["emb"].T

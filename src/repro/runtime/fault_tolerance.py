@@ -31,7 +31,10 @@ class StepWatchdog:
     """Tracks per-step wall time; flags stragglers vs the rolling median.
 
     On a real deployment each host feeds its own step times and the
-    controller aggregates; here the same logic runs host-local.
+    controller aggregates; here the same logic runs host-local. A server
+    feeds it every decode step for as long as it runs, so it keeps only
+    the latest ``window`` reports, and of the rest only the step numbers
+    of the stragglers.
     """
 
     def __init__(self, window: int = 50, threshold: float = 2.0,
@@ -39,7 +42,8 @@ class StepWatchdog:
         self.window: Deque[float] = deque(maxlen=window)
         self.threshold = threshold
         self.warmup_steps = warmup_steps
-        self.reports: List[StragglerReport] = []
+        self.reports: Deque[StragglerReport] = deque(maxlen=window)
+        self._stragglers: List[int] = []
         self._t0: Optional[float] = None
         self._step = 0
 
@@ -61,11 +65,13 @@ class StepWatchdog:
             self.window.append(dt)
         rep = StragglerReport(self._step, dt, med, ratio, is_straggler)
         self.reports.append(rep)
+        if is_straggler:
+            self._stragglers.append(self._step)
         return rep
 
     @property
     def straggler_steps(self) -> List[int]:
-        return [r.step for r in self.reports if r.is_straggler]
+        return list(self._stragglers)
 
 
 @dataclasses.dataclass

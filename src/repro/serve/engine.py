@@ -72,6 +72,16 @@ log = logging.getLogger(__name__)
 _BUCKETABLE_FAMILIES = ("dense", "whisper", "vision")
 
 
+def _named_jit(impl, **bound):
+    """``jax.jit`` of ``impl`` with ``bound`` keywords fixed, compiled
+    under impl's own name: the module is ``jit_<name>`` and its ops'
+    metadata reads ``jit(<name>)/...`` (a bare partial compiles as
+    ``jit__unknown``). Dispatch still shows as ``PjitFunction(<name>)``."""
+    fn = functools.partial(impl, **bound)
+    fn.__name__ = impl.__name__
+    return jax.jit(fn)
+
+
 def _insert_slot(batched: Any, single: Any, b: int) -> Any:
     """Write a single-request cache (batch size 1 at axis 1) into slot b of
     the batched cache tree."""
@@ -331,18 +341,7 @@ class Engine:
             for rk, count in sorted(rankings.items()):
                 log.info("%s ranking [%d leaves] %s", phase, count, rk)
 
-        self._decode_fn = self._make_decode_fn()
-        self._prefill_fn = jax.jit(
-            functools.partial(self._prefill_impl,
-                              rc=self.rc.replace(mode="prefill")),
-        )
-        if ecfg.paged:
-            self._paged_prefill_fn = jax.jit(
-                functools.partial(self._paged_prefill_impl,
-                                  rc=self.rc.replace(mode="prefill")))
-            self._chunk_fn = jax.jit(
-                functools.partial(self._prefill_chunk_impl,
-                                  rc=self.rc.replace(mode="prefill")))
+        self._jit_steps()
         # prefill extras (whisper frames / vision embeds), batched once
         self._extra_batch = {
             k: (v[None] if getattr(v, "ndim", 0) == 2 else v[:1])
@@ -462,18 +461,30 @@ class Engine:
         batch = {"tokens": tokens}
         batch.update(extras)
         logits, cache = self.model.prefill(params, batch, rc)
-        last = jax.lax.dynamic_slice_in_dim(
-            logits[0], true_len - 1, 1, axis=0)[0]
-        last = last[: self.model.cfg.vocab_size][None] + poison  # (1, V)
-        bad = ~jnp.all(jnp.isfinite(last.astype(jnp.float32)))
-        tok, new_key = api.sample_tokens(
-            last, key[None], temperature[None], top_k[None], top_p[None],
-            greedy[None])
-        lp = api.token_logprobs(last, tok)[0]
+        tok, bad, lp, new_key = self._sample_first(
+            logits, true_len, key, temperature, top_k, top_p, greedy, poison)
         cache = self._encode_cache(cache)
         cache = pad_prefill_cache(cache, self.ecfg.max_len,
                                   window=self.window, true_len=true_len)
-        return tok[0], bad, lp, new_key[0], cache
+        return tok, bad, lp, new_key, cache
+
+    def _sample_first(self, logits, true_len, key, temperature, top_k,
+                      top_p, greedy, poison):
+        """The prefill epilogue: sample the first token from the logits
+        at the TRUE last position (padded ids sliced off, ``poison``
+        added). Returns (token, bad, logprob, new_key)."""
+        with jax.named_scope("lm_head"):
+            last = jax.lax.dynamic_slice_in_dim(
+                logits[0], true_len - 1, 1, axis=0)[0]
+            last = last[: self.model.cfg.vocab_size][None]       # (1, V)
+        with jax.named_scope("sample"):
+            last = last + poison
+            bad = ~jnp.all(jnp.isfinite(last.astype(jnp.float32)))
+            tok, new_key = api.sample_tokens(
+                last, key[None], temperature[None], top_k[None],
+                top_p[None], greedy[None])
+            lp = api.token_logprobs(last, tok)[0]
+        return tok[0], bad, lp, new_key[0]
 
     def _paged_prefill_impl(self, params, caches, tokens, true_len, slot,
                             bt_row, key, temperature, top_k, top_p, greedy,
@@ -488,18 +499,12 @@ class Engine:
         batch = {"tokens": tokens}
         batch.update(extras)
         logits, fresh = self.model.prefill(params, batch, rc)
-        last = jax.lax.dynamic_slice_in_dim(
-            logits[0], true_len - 1, 1, axis=0)[0]
-        last = last[: self.model.cfg.vocab_size][None] + poison
-        bad = ~jnp.all(jnp.isfinite(last.astype(jnp.float32)))
-        tok, new_key = api.sample_tokens(
-            last, key[None], temperature[None], top_k[None], top_p[None],
-            greedy[None])
-        lp = api.token_logprobs(last, tok)[0]
+        tok, bad, lp, new_key = self._sample_first(
+            logits, true_len, key, temperature, top_k, top_p, greedy, poison)
         caches = paging.write_prefill_into_blocks(
             caches, self._encode_cache(fresh), slot, bt_row, true_len,
             self.paging, window=self.window)
-        return tok[0], bad, lp, new_key[0], caches
+        return tok, bad, lp, new_key, caches
 
     def _prefill_chunk_impl(self, params, caches, tokens, hist, true_len,
                             slot, bt_row, key, temperature, top_k, top_p,
@@ -518,16 +523,10 @@ class Engine:
                  "positions": hist + jnp.arange(S, dtype=jnp.int32)[None]}
         batch.update(extras)
         logits, new_view = self.model.forward(params, batch, rc, caches=view)
-        last = jax.lax.dynamic_slice_in_dim(
-            logits[0], true_len - 1, 1, axis=0)[0]
-        last = last[: self.model.cfg.vocab_size][None] + poison
-        bad = ~jnp.all(jnp.isfinite(last.astype(jnp.float32)))
-        tok, new_key = api.sample_tokens(
-            last, key[None], temperature[None], top_k[None], top_p[None],
-            greedy[None])
-        lp = api.token_logprobs(last, tok)[0]
+        tok, bad, lp, new_key = self._sample_first(
+            logits, true_len, key, temperature, top_k, top_p, greedy, poison)
         caches = paging.merge_slot(caches, new_view, slot)
-        return tok[0], bad, lp, new_key[0], caches
+        return tok, bad, lp, new_key, caches
 
     def _prefill_target(self, tr: TrackedRequest) -> int:
         """Positions to prefill before ``slot`` can (re)join decode: the
@@ -537,6 +536,14 @@ class Engine:
         if tr.preempted and tr.generated:
             return tr.prompt_len + len(tr.generated) - 1
         return tr.prompt_len
+
+    def _chunk_len(self, tr: TrackedRequest) -> int:
+        """Prompt positions the next prefill step of ``tr`` runs: the
+        whole target, or (chunked prefill) the next chunk of it."""
+        target = self._prefill_target(tr)
+        if self._chunked and target > int(self.ecfg.prefill_chunk):
+            return min(int(self.ecfg.prefill_chunk), target - tr.prefill_pos)
+        return target
 
     def _prefill_tokens(self, tr: TrackedRequest) -> np.ndarray:
         seq = np.asarray(tr.request.prompt, np.int32)
@@ -571,10 +578,8 @@ class Engine:
         req = tr.request
         sp = req.sampling
         target = self._prefill_target(tr)
-        chunked = self._chunked and target > int(self.ecfg.prefill_chunk)
         pos0 = tr.prefill_pos
-        c = min(int(self.ecfg.prefill_chunk), target - pos0) if chunked \
-            else target
+        c = self._chunk_len(tr)
         final = pos0 + c >= target
         chunk = self._prefill_tokens(tr)[pos0: pos0 + c]
         if self._bucketed:
@@ -795,12 +800,17 @@ class Engine:
         self.trace_counts["decode"] += 1
         logits, new_caches = self.model.decode(
             params, tokens[:, None], positions[:, None], caches, rc)
-        logits = logits[:, 0, : self.model.cfg.vocab_size] + poison[:, None]
-        tok, done, bad, new_keys = api.sample_and_stop(
-            logits, keys=keys, temperature=temperature, top_k=top_k,
-            top_p=top_p, greedy=greedy, stop_ids=stop_ids,
-            remaining=remaining, active=active)
-        lp = api.token_logprobs(logits, tok)
+        # named scopes (with models/common.py's kv_write, attend and
+        # lm_head) let a profiler trace attribute device time by part
+        with jax.named_scope("lm_head"):
+            logits = logits[:, 0, : self.model.cfg.vocab_size]
+        with jax.named_scope("sample"):
+            logits = logits + poison[:, None]
+            tok, done, bad, new_keys = api.sample_and_stop(
+                logits, keys=keys, temperature=temperature, top_k=top_k,
+                top_p=top_p, greedy=greedy, stop_ids=stop_ids,
+                remaining=remaining, active=active)
+            lp = api.token_logprobs(logits, tok)
         return tok, done, bad, lp, new_keys, new_caches
 
     def _spec_decode_impl(self, params, caches, tokens, positions, succ,
@@ -818,14 +828,20 @@ class Engine:
             temperature, top_k, top_p, greedy, stop_ids, remaining, active,
             spec_on, poison, rc=rc, k=self.spec_k)
 
-    def _make_decode_fn(self):
-        """Jit the batched decode step — the speculative multi-token body
-        or the classic single-token one, chosen ONCE at construction (and
-        at backend-quarantine re-jit); the step shape never flips
-        mid-serve."""
+    def _jit_steps(self) -> None:
+        """Jit the serving steps, at construction and at backend-quarantine
+        re-jit. The batched decode step is the speculative multi-token
+        body or the classic single-token one, chosen here; the step shape
+        never flips mid-serve."""
         impl = self._spec_decode_impl if self.spec_k else self._decode_impl
-        return jax.jit(
-            functools.partial(impl, rc=self.rc.replace(mode="decode")))
+        self._decode_fn = _named_jit(impl, rc=self.rc.replace(mode="decode"))
+        prefill_rc = self.rc.replace(mode="prefill")
+        self._prefill_fn = _named_jit(self._prefill_impl, rc=prefill_rc)
+        if self.ecfg.paged:
+            self._paged_prefill_fn = _named_jit(self._paged_prefill_impl,
+                                                rc=prefill_rc)
+            self._chunk_fn = _named_jit(self._prefill_chunk_impl,
+                                        rc=prefill_rc)
 
     def _prefill_step_events(self, slot: int,
                              events: List[StreamEvent]) -> bool:
@@ -842,7 +858,9 @@ class Engine:
         tr = self.sched.slots[slot]
         now = time.perf_counter()
         pos0 = tr.prefill_pos
-        tok, bad, final = self._prefill_one(slot, tr)
+        with jax.profiler.TraceAnnotation("engine.prefill", uid=tr.uid,
+                                          tokens=self._chunk_len(tr)):
+            tok, bad, final = self._prefill_one(slot, tr)
         dt = time.perf_counter() - now
         tr.prefill_s += dt
         m.prefill_s += dt
@@ -938,7 +956,17 @@ class Engine:
         faults, real crashes) leave this tick's events undelivered —
         ``snapshot()``/``restore()`` (serve/resilience.py
         ``serve_with_restarts``) is the recovery path that resumes
-        token-identically without double-delivering."""
+        token-identically without double-delivering.
+
+        Each tick writes host spans into a running profiler trace
+        (``engine.step`` and, inside it, ``engine.admit``,
+        ``engine.prefill`` and ``engine.decode.{upload, dispatch, wait,
+        readback, emit}``); with no profiler running they are inactive."""
+        with jax.profiler.StepTraceAnnotation("engine.step",
+                                              step_num=self._tick):
+            return self._step()
+
+    def _step(self) -> List[StreamEvent]:
         m = self.metrics_counters
         tick = self._tick
         fp = self.fault_plan
@@ -977,18 +1005,20 @@ class Engine:
             planned_free -= need
             return True
 
-        for slot in self.sched.admit(can_admit):
-            tr = self.sched.slots[slot]
-            did_work = True
-            now = time.perf_counter()
-            tr.queue_wait_s = now - tr.submit_t
-            m.admitted += 1
-            m.queue_wait_s += tr.queue_wait_s
-            if self.paging is not None:
-                need = self.paging.blocks_for(self._prefill_target(tr))
-                ok = self._alloc_blocks(slot, need)
-                assert ok, "can_admit reserved blocks the pool cannot supply"
-            any_poisoned |= self._prefill_step_events(slot, events)
+        with jax.profiler.TraceAnnotation("engine.admit"):
+            for slot in self.sched.admit(can_admit):
+                tr = self.sched.slots[slot]
+                did_work = True
+                now = time.perf_counter()
+                tr.queue_wait_s = now - tr.submit_t
+                m.admitted += 1
+                m.queue_wait_s += tr.queue_wait_s
+                if self.paging is not None:
+                    need = self.paging.blocks_for(self._prefill_target(tr))
+                    ok = self._alloc_blocks(slot, need)
+                    assert ok, ("can_admit reserved blocks the pool cannot "
+                                "supply")
+                any_poisoned |= self._prefill_step_events(slot, events)
 
         # every active slot must own blocks for the position this decode
         # step writes; an exhausted pool preempts the youngest request
@@ -1010,42 +1040,51 @@ class Engine:
                                      else np.inf)
             t0 = time.perf_counter()
             self.watchdog.start_step()
-            dev_args = [
-                self.params, self.caches,
-                jnp.asarray(np.where(self.active, self.last_token, 0)),
-                jnp.asarray(np.where(self.active, self.positions, 0)),
-            ]
-            if self.spec_k:
-                dev_args.append(jnp.asarray(self.succ))
-            dev_args += [
-                jnp.asarray(self.rng_keys),
-                jnp.asarray(self.temperature),
-                jnp.asarray(self.top_k),
-                jnp.asarray(self.top_p),
-                jnp.asarray(self.greedy),
-                jnp.asarray(self.stop_ids),
-                jnp.asarray(self.remaining),
-                jnp.asarray(self.active),
-            ]
-            if self.spec_k:
-                dev_args.append(jnp.asarray(self.spec_on))
-            dev_args.append(jnp.asarray(poison))
-            if self.spec_k:
-                (toks, lps, e_cnt, acc, done, bad, new_keys, new_succ,
-                 self.caches) = self._decode_fn(*dev_args)
-                toks = np.asarray(toks)                 # (B, K+1)
-                lps = np.asarray(lps)
-                e_cnt = np.asarray(e_cnt).astype(np.int32)
-                acc = np.asarray(acc)
-                self.succ = np.array(new_succ)
-            else:
-                tok, done, bad, lp, new_keys, self.caches = self._decode_fn(
-                    *dev_args)
-                toks = np.asarray(tok)[:, None]         # (B, 1)
-                lps = np.asarray(lp)[:, None]
-                acc = None
-            done = np.asarray(done)
-            bad = np.asarray(bad)
+            with jax.profiler.TraceAnnotation("engine.decode.upload"):
+                dev_args = [
+                    self.params, self.caches,
+                    jnp.asarray(np.where(self.active, self.last_token, 0)),
+                    jnp.asarray(np.where(self.active, self.positions, 0)),
+                ]
+                if self.spec_k:
+                    dev_args.append(jnp.asarray(self.succ))
+                dev_args += [
+                    jnp.asarray(self.rng_keys),
+                    jnp.asarray(self.temperature),
+                    jnp.asarray(self.top_k),
+                    jnp.asarray(self.top_p),
+                    jnp.asarray(self.greedy),
+                    jnp.asarray(self.stop_ids),
+                    jnp.asarray(self.remaining),
+                    jnp.asarray(self.active),
+                ]
+                if self.spec_k:
+                    dev_args.append(jnp.asarray(self.spec_on))
+                dev_args.append(jnp.asarray(poison))
+            with jax.profiler.TraceAnnotation("engine.decode.dispatch"):
+                *outs, self.caches = self._decode_fn(*dev_args)
+            # the one wait for the device: the reads below find it done
+            with jax.profiler.TraceAnnotation("engine.decode.wait"):
+                jax.block_until_ready(outs)
+            with jax.profiler.TraceAnnotation("engine.decode.readback"):
+                if self.spec_k:
+                    toks, lps, e_cnt, acc, done, bad, new_keys, new_succ = outs
+                    toks = np.asarray(toks)                 # (B, K+1)
+                    lps = np.asarray(lps)
+                    e_cnt = np.asarray(e_cnt).astype(np.int32)
+                    acc = np.asarray(acc)
+                    self.succ = np.array(new_succ)
+                else:
+                    tok, done, bad, lp, new_keys = outs
+                    toks = np.asarray(tok)[:, None]         # (B, 1)
+                    lps = np.asarray(lp)[:, None]
+                    acc = None
+                done = np.asarray(done)
+                bad = np.asarray(bad)
+                # np.array (copy) — np.asarray of a device array is
+                # read-only, and the next prefill writes per-slot keys in
+                # place
+                keys = np.array(new_keys)
             if not self.spec_k:
                 e_cnt = (self.active & ~bad).astype(np.int32)
             rep = self.watchdog.end_step()
@@ -1056,64 +1095,65 @@ class Engine:
                 # ran, host bookkeeping has not — only a snapshot
                 # restore recovers consistently
                 raise InjectedFault("sample", tick)
-            # np.array (copy) — np.asarray of a device array is read-only,
-            # and the next prefill writes per-slot keys in place
-            self.rng_keys = np.array(new_keys)
-            n_bad = int(np.count_nonzero(bad))
-            n_emit = int(e_cnt.sum())
-            m.decode_steps += 1
-            m.decode_slot_steps += int(active_idx.size)
-            m.decode_s += time.perf_counter() - t0
-            m.tokens_generated += n_emit
-            m.extra_decode_tokens += n_emit - (int(active_idx.size) - n_bad)
-            m.poisoned_slot_steps += n_bad
-            if self.spec_k:
-                spec_lanes = self.active & ~bad & self.spec_on
-                n_spec = int(np.count_nonzero(spec_lanes))
-                n_acc = int(acc[spec_lanes].sum())
-                m.drafted_tokens += self.spec_k * n_spec
-                m.accepted_draft_tokens += n_acc
-                m.rejected_draft_tokens += self.spec_k * n_spec - n_acc
-            any_poisoned = any_poisoned or n_bad > 0
+            self.rng_keys = keys
+            with jax.profiler.TraceAnnotation("engine.decode.emit"):
+                n_bad = int(np.count_nonzero(bad))
+                n_emit = int(e_cnt.sum())
+                m.decode_steps += 1
+                m.decode_slot_steps += int(active_idx.size)
+                m.decode_s += time.perf_counter() - t0
+                m.tokens_generated += n_emit
+                m.extra_decode_tokens += (n_emit
+                                          - (int(active_idx.size) - n_bad))
+                m.poisoned_slot_steps += n_bad
+                if self.spec_k:
+                    spec_lanes = self.active & ~bad & self.spec_on
+                    n_spec = int(np.count_nonzero(spec_lanes))
+                    n_acc = int(acc[spec_lanes].sum())
+                    m.drafted_tokens += self.spec_k * n_spec
+                    m.accepted_draft_tokens += n_acc
+                    m.rejected_draft_tokens += self.spec_k * n_spec - n_acc
+                any_poisoned = any_poisoned or n_bad > 0
 
-            # only healthy lanes advance and emit; a poisoned lane's
-            # token never reaches its stream. e_cnt is the per-lane
-            # emission count (always 1 for non-speculative steps, up to
-            # K+1 for accepted draft windows) — already zero for
-            # inactive/bad lanes
-            self.positions += e_cnt
-            self.remaining -= e_cnt
-            last_idx = np.maximum(e_cnt - 1, 0)
-            new_last = toks[np.arange(toks.shape[0]), last_idx]
-            self.last_token = np.where(e_cnt > 0, new_last, self.last_token)
-            for b in active_idx:
-                tr = self.sched.slots[b]
-                if bad[b]:
-                    events.append(StreamEvent(tr.uid, len(tr.generated),
-                                              None, "error"))
-                    self._finish_slot(int(b), "error")
-                    continue
-                n = int(e_cnt[b])
-                if n == 0:  # pragma: no cover - defensive
-                    continue
-                want_lp = tr.request.sampling.logprobs
-                reason = None
-                if done[b]:
-                    last_t = int(toks[b, n - 1])
-                    reason = "stop" if last_t in tr.stop_set else "length"
-                base = len(tr.generated)
-                for j in range(n):
-                    t = int(toks[b, j])
-                    tr.generated.append(t)
-                    lpj = None
-                    if want_lp:
-                        lpj = float(lps[b, j])
-                        tr.logprobs.append(lpj)
-                    events.append(StreamEvent(
-                        tr.uid, base + j, t,
-                        reason if j == n - 1 else None, logprob=lpj))
-                if reason is not None:
-                    self._finish_slot(int(b), reason)
+                # only healthy lanes advance and emit; a poisoned lane's
+                # token never reaches its stream. e_cnt is the per-lane
+                # emission count (always 1 for non-speculative steps, up to
+                # K+1 for accepted draft windows) — already zero for
+                # inactive/bad lanes
+                self.positions += e_cnt
+                self.remaining -= e_cnt
+                last_idx = np.maximum(e_cnt - 1, 0)
+                new_last = toks[np.arange(toks.shape[0]), last_idx]
+                self.last_token = np.where(e_cnt > 0, new_last,
+                                           self.last_token)
+                for b in active_idx:
+                    tr = self.sched.slots[b]
+                    if bad[b]:
+                        events.append(StreamEvent(tr.uid, len(tr.generated),
+                                                  None, "error"))
+                        self._finish_slot(int(b), "error")
+                        continue
+                    n = int(e_cnt[b])
+                    if n == 0:  # pragma: no cover - defensive
+                        continue
+                    want_lp = tr.request.sampling.logprobs
+                    reason = None
+                    if done[b]:
+                        last_t = int(toks[b, n - 1])
+                        reason = "stop" if last_t in tr.stop_set else "length"
+                    base = len(tr.generated)
+                    for j in range(n):
+                        t = int(toks[b, j])
+                        tr.generated.append(t)
+                        lpj = None
+                        if want_lp:
+                            lpj = float(lps[b, j])
+                            tr.logprobs.append(lpj)
+                        events.append(StreamEvent(
+                            tr.uid, base + j, t,
+                            reason if j == n - 1 else None, logprob=lpj))
+                    if reason is not None:
+                        self._finish_slot(int(b), reason)
 
         if did_work:
             was_tripped = self.breaker.tripped
@@ -1156,17 +1196,7 @@ class Engine:
         self.metrics_counters.backend_fallbacks += 1
         log.warning("backend %r failed and was quarantined; re-planning "
                     "decode/prefill on the remaining candidates", name)
-        self._decode_fn = self._make_decode_fn()
-        self._prefill_fn = jax.jit(
-            functools.partial(self._prefill_impl,
-                              rc=self.rc.replace(mode="prefill")))
-        if self.ecfg.paged:
-            self._paged_prefill_fn = jax.jit(
-                functools.partial(self._paged_prefill_impl,
-                                  rc=self.rc.replace(mode="prefill")))
-            self._chunk_fn = jax.jit(
-                functools.partial(self._prefill_chunk_impl,
-                                  rc=self.rc.replace(mode="prefill")))
+        self._jit_steps()
         self.plans["decode"] = plan_mod.preplan_params(
             self.params, self.rc.policy, mode="decode",
             m=self.ecfg.num_slots, act_dtype=self.model.cfg.act_dtype)

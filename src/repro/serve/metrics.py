@@ -116,13 +116,6 @@ class EngineMetrics:
             return 0.0
         return self.decode_slot_steps / self.decode_s
 
-    @property
-    def tokens_per_s(self) -> float:
-        dt = time.perf_counter() - self.started_at
-        if dt <= 0.0:
-            return 0.0
-        return self.tokens_generated / dt
-
     def state(self) -> Dict[str, float]:
         """The restorable counter fields (everything but the wall
         clock), as used by Engine.snapshot()/restore()."""
@@ -140,6 +133,5 @@ class EngineMetrics:
         out["draft_acceptance_rate"] = self.draft_acceptance_rate
         out["decode_tokens_per_step"] = self.decode_tokens_per_step
         out["decode_tokens_per_s"] = self.decode_tokens_per_s
-        out["tokens_per_s"] = self.tokens_per_s
         return out
 

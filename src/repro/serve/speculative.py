@@ -222,12 +222,15 @@ def spec_decode_step(model, params, caches, tokens, positions, succ, keys,
         [t0[:, None], jnp.clip(drafts, 0, vocab - 1)], axis=1)
     pos = positions[:, None] + jnp.arange(S, dtype=positions.dtype)[None, :]
     logits, new_caches = model.decode(params, feed, pos, caches, rc)
-    logits = logits[:, :, :vocab].astype(jnp.float32) + poison[:, None, None]
-    finite = jnp.all(jnp.isfinite(logits), axis=-1)      # (B, S)
-    toks, lps, ktraj = sample_window(logits, keys, temperature, top_k,
-                                     top_p, greedy)
-    emit, e, accepted, done, bad = accept_window(
-        toks, drafts, finite, stop_ids, remaining, active, spec_on)
+    with jax.named_scope("lm_head"):
+        logits = logits[:, :, :vocab].astype(jnp.float32)
+    with jax.named_scope("sample"):
+        logits = logits + poison[:, None, None]
+        finite = jnp.all(jnp.isfinite(logits), axis=-1)  # (B, S)
+        toks, lps, ktraj = sample_window(logits, keys, temperature, top_k,
+                                         top_p, greedy)
+        emit, e, accepted, done, bad = accept_window(
+            toks, drafts, finite, stop_ids, remaining, active, spec_on)
     # key rollback: after this step the slot must sit e splits ahead,
     # exactly where the baseline would be after emitting e tokens
     last = jnp.clip(e - 1, 0, S - 1)
