@@ -86,7 +86,7 @@ class BackendCalibration:
 # these produce matters (it must be deterministic); absolute numbers are
 # provenance-labeled "analytic" everywhere they surface. The byte and
 # launch terms make the two-kernel split backend analytically more
-# expensive than the fused kernel (it round-trips the (C, V, M, 2^n)
+# expensive than the fused kernel (it round-trips the (C, M, V, 2^n)
 # intermediate through HBM and launches twice), which matches the
 # paper's no-fusion-cost argument — measured calibration can flip it.
 ANALYTIC = BackendCalibration(

@@ -117,9 +117,10 @@ RECON_SLAB_BYTES = 16 * 1024 * 1024
 _MIN_BLOCK_V = 8
 
 # Shared VMEM budgets for the Pallas kernels' tile models. The fused
-# kernel's OC scratch holds C*V_pad*8*2^n fp32 (one 8-row token tile) for
-# the whole sweep; past this budget (of the 128 MiB VMEM of a v5e core)
-# the plan leaves the shape to the split backend. The gather tile bounds
+# kernel's OC scratch holds C*mt*V_pad*2^n fp32 (one token tile: every
+# row of the call, or as many as fit) for the whole sweep; when not even
+# 8 rows fit this budget (of the 128 MiB VMEM of a v5e core) the plan
+# leaves the shape to the split backend. The gather tile bounds
 # each kernel's per-step streamed slab. The per-kernel tile *functions*
 # live with their wrappers in kernels/*/ops.py — only the budgets are
 # shared.

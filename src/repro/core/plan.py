@@ -217,7 +217,7 @@ class PlanCost:
     ``weight_bytes`` : per-call weight-side HBM traffic (compressed for
                        VQ kinds).
     ``intermediate_bytes`` : extra HBM round-trip traffic of multi-kernel
-                       formulations (the split backend's (C, V, M, 2^n)
+                       formulations (the split backend's (C, M, V, 2^n)
                        output-codebook buffer; 0 for fused/jnp paths).
     ``launches``     : kernel launches per call (prices dispatch overhead
                        in the calibrated time model)."""

@@ -1,8 +1,8 @@
 """Jit'd wrapper for the fused EVA matmul kernel + its plan backend.
 
 Accepts a VQWeight and activations of any leading shape; handles padding
-(M to the kernel's 8-row token tile, V/N to the block tiles), the
-v-major activation layout, and dtype conversion.
+(M to a whole number of token tiles, V/N to the block tiles), the
+token-major activation layout, and dtype conversion.
 
 The index matrix is handed to the kernel in its storage dtype (uint8 for
 n <= 8) — the kernel upcasts per streamed tile, so HBM index traffic
@@ -28,33 +28,40 @@ import jax.numpy as jnp
 from repro.core import ops as core_ops
 from repro.core import plan as plan_mod
 from repro.core.vq import VQWeight
-from repro.kernels.gather import SUBLANES
+from repro.kernels.gather import SUBLANES, token_tile
 from repro.kernels.fused_vq_matmul.kernel import fused_vq_matmul_pallas
 from repro.kernels.fused_vq_matmul.ref import fused_vq_matmul_ref
 
 
-def fused_oc_bytes(V: int, C: int, k: int, block_v: int) -> int:
-    """VMEM held by the fused kernel's OC scratch: (C, V_pad, 8, k) fp32
-    — one 8-row token tile of the output codebook over the whole
+def fused_oc_bytes(V: int, C: int, k: int, block_v: int, m_tile: int) -> int:
+    """VMEM held by the fused kernel's OC scratch: (C, m_tile, V_pad, k)
+    fp32 — one token tile of the output codebook over the whole
     (block_v-padded) V."""
     v_padded = V + ((-V) % block_v)
-    return 4 * C * v_padded * SUBLANES * k
+    return 4 * C * m_tile * v_padded * k
 
 
-def select_fused_tiles(M: int, V: int, N: int, C: int, k: int = 256
+def select_fused_tiles(M: int, V: int, N: int, C: int, k: int = 256, *,
+                       block_v: int | None = None,
+                       oc_budget: int = core_ops.FUSED_OC_SCRATCH_BYTES,
                        ) -> Tuple[int, int, int]:
     """(m_tile, block_v, block_n) for the fused Pallas wrapper.
 
-    m_tile is one sublane group (8 token rows; the grid walks M and the
-    wrapper pads it), block_v the paper's v=32 tile height and block_n
-    512 output lanes, each clamped to the problem. The per-step index
-    tile (C, bv, bn) and its int32 widening stay far below the tile
-    budget; the OC scratch (fused_oc_bytes) is what the plan checks."""
-    return SUBLANES, min(core_ops.DEFAULT_BLOCK_V, V), min(512, N)
+    block_v is the paper's v=32 tile height (or the one pinned) and
+    block_n 512 output lanes, each clamped to the problem. m_tile is every
+    row of the call when the rows' OC scratch (fused_oc_bytes) and
+    (8, bn) f32 accumulators fit ``oc_budget``, else the tile of a
+    multiple of 8 rows that fits and pads M least (gather.token_tile); 0
+    when not even 8 rows fit. The per-step index tile (C, bv, bn) and its
+    int32 widening stay far below the budget."""
+    bv = min(block_v or core_ops.DEFAULT_BLOCK_V, V)
+    bn = min(512, N)
+    per_token = fused_oc_bytes(V, C, k, bv, 1) + 4 * SUBLANES * bn
+    return token_tile(M, per_token, oc_budget), bv, bn
 
 
 @functools.partial(
-    jax.jit, static_argnames=("block_v", "block_n", "interpret",
+    jax.jit, static_argnames=("block_v", "block_n", "m_tile", "interpret",
                               "use_pallas", "out_dtype")
 )
 def fused_vq_matmul(
@@ -63,13 +70,14 @@ def fused_vq_matmul(
     *,
     block_v="auto",
     block_n="auto",
+    m_tile="auto",
     interpret: bool = False,
     use_pallas: bool = True,
     out_dtype=None,
 ) -> jax.Array:
-    """block_v/block_n default to "auto" (select_fused_tiles); explicit
-    ints pin the tile sizes (plans pass fully-resolved tiles; tests /
-    TPU tuning may too)."""
+    """block_v/block_n/m_tile default to "auto" (select_fused_tiles);
+    explicit ints pin the tile sizes (plans pass fully-resolved tiles;
+    tests / TPU tuning may too)."""
     out_dtype = out_dtype or x.dtype
     lead = x.shape[:-1]
     K, N, V, d, C = vq.K, vq.N, vq.V, vq.d, vq.C
@@ -85,15 +93,21 @@ def fused_vq_matmul(
         y = fused_vq_matmul_ref(X, vq.codebooks, I, scale)
         return y.reshape(*lead, N).astype(out_dtype)
 
-    _, auto_bv, auto_bn = select_fused_tiles(M, V, N, C, k)
-    bv = auto_bv if block_v == "auto" else min(block_v, V)
+    auto_mt, bv, auto_bn = select_fused_tiles(
+        M, V, N, C, k, block_v=None if block_v == "auto" else block_v)
     bn = auto_bn if block_n == "auto" else min(block_n, N)
+    mt = auto_mt if m_tile == "auto" else min(m_tile, M)
+    if mt < 1:
+        raise ValueError(f"the fused kernel's OC scratch for V={V} does not "
+                         "fit its VMEM budget; plan the split backend")
+    pad_m = (-M) % mt
     pad_v = (-V) % bv
     pad_n = (-N) % bn
-    pad_m = (-M) % SUBLANES
-    # v-major activations: each v-tile's x block is (bv, 8, d), so the
-    # kernel's OC slab lands in the (C, V, 8, k) scratch without a relayout
-    X = jnp.pad(X, ((0, pad_m), (0, pad_v), (0, 0))).transpose(1, 0, 2)
+    # token-major activations: each (token tile, v-tile) x block is
+    # (mt, bv, d), so the kernel's OC slab lands in the (C, mt, V, k)
+    # scratch without a relayout
+    if pad_m or pad_v:
+        X = jnp.pad(X, ((0, pad_m), (0, pad_v), (0, 0)))
     if pad_v:
         # padded V rows gather index 0 from zeroed X rows -> contribute 0
         I = jnp.pad(I, ((0, 0), (0, pad_v), (0, 0)))
@@ -102,7 +116,7 @@ def fused_vq_matmul(
         scale = jnp.pad(scale, (0, pad_n))
     y = fused_vq_matmul_pallas(
         X, vq.codebooks.astype(jnp.float32), I, scale[None, :],
-        block_v=bv, block_n=bn, interpret=interpret)
+        m_tile=mt, block_v=bv, block_n=bn, interpret=interpret)
     return y[:M, :N].reshape(*lead, N).astype(out_dtype)
 
 
@@ -115,13 +129,12 @@ def fused_vq_matmul(
 
 def _match_eva_fused(spec: plan_mod.LinearSpec, policy: plan_mod.PlanPolicy
                      ) -> bool:
-    # the OC scratch must fit VMEM; wider K leaves the split backend
-    bv = policy.block_v or select_fused_tiles(spec.M, spec.V, spec.N,
-                                              spec.C, spec.k)[1]
+    # the OC scratch of one token tile must fit VMEM; wider K leaves the
+    # split backend
     return (spec.kind == "vq" and policy.impl == "pallas"
             and policy.vq_mode in ("eva", "none")
-            and fused_oc_bytes(spec.V, spec.C, spec.k, min(bv, spec.V))
-            <= core_ops.FUSED_OC_SCRATCH_BYTES)
+            and select_fused_tiles(spec.M, spec.V, spec.N, spec.C, spec.k,
+                                   block_v=policy.block_v)[0] > 0)
 
 
 def _plan_eva_fused(spec: plan_mod.LinearSpec, policy: plan_mod.PlanPolicy
@@ -131,14 +144,13 @@ def _plan_eva_fused(spec: plan_mod.LinearSpec, policy: plan_mod.PlanPolicy
             "impl='pallas' always runs the fused tiled kernel; epilogue="
             f"{policy.epilogue!r} does not apply (pass block_v to size its "
             "v-tiles)")
-    mt, auto_bv, bn = select_fused_tiles(spec.M, spec.V, spec.N, spec.C,
-                                         spec.k)
-    bv = auto_bv if policy.block_v is None else min(policy.block_v, spec.V)
+    mt, bv, bn = select_fused_tiles(spec.M, spec.V, spec.N, spec.C, spec.k,
+                                    block_v=policy.block_v)
     out_dt = jnp.dtype(spec.out_dtype)
     interpret = policy.interpret
 
     def run(x, vq):
-        return fused_vq_matmul(x, vq, block_v=bv, block_n=bn,
+        return fused_vq_matmul(x, vq, block_v=bv, block_n=bn, m_tile=mt,
                                interpret=interpret, out_dtype=out_dt)
 
     cost = plan_mod.PlanCost(
@@ -151,7 +163,8 @@ def _plan_eva_fused(spec: plan_mod.LinearSpec, policy: plan_mod.PlanPolicy
     )
     return plan_mod.MatmulPlan(
         "eva_fused_pallas", spec, policy,
-        (("mt", mt), ("bv", bv), ("bn", bn)), cost, run)
+        (("mt", mt), ("token_tiles", -(-spec.M // mt)), ("bv", bv),
+         ("bn", bn)), cost, run)
 
 
 plan_mod.register_backend("eva_fused_pallas", _match_eva_fused,

@@ -2,7 +2,7 @@
 the two-kernel ``eva_split_pallas`` plan backend.
 
 The split backend is the paper-faithful no-fusion formulation: kernel 1
-(kernels/vq_gemm) materializes the full (C, V, M, 2^n) output-codebook
+(kernels/vq_gemm) materializes the full (C, M, V, 2^n) output-codebook
 buffer in HBM, kernel 2 (this module's oc_lookup) runs the structured,
 conflict-free gather + add-only reduction over it. Against the fused
 kernel it trades one extra HBM round-trip of the OC buffer (priced as
@@ -21,19 +21,28 @@ import jax.numpy as jnp
 from repro.core import ops as core_ops
 from repro.core import plan as plan_mod
 from repro.core.vq import VQWeight
-from repro.kernels.gather import SUBLANES
+from repro.kernels.gather import SUBLANES, token_tile
 from repro.kernels.oc_lookup.kernel import oc_lookup_pallas
 from repro.kernels.oc_lookup.ref import oc_lookup_ref
 from repro.kernels.vq_gemm.ops import select_gemm_block_mv, vq_gemm
 
 
-def select_lookup_tiles(V: int, N: int) -> Tuple[int, int]:
-    """(block_v, block_n) for the lookup kernel: the paper's v=32 tile
-    height and 512 output lanes, clamped to the problem. Token rows are
-    tiled by 8 in the grid, so the per-step VMEM — the O tile
-    (C, bv, 8, k) fp32, the streamed index tile and its int32 widening —
-    is independent of M and far below the scoped-VMEM budget."""
-    return min(core_ops.DEFAULT_BLOCK_V, V), min(512, N)
+def select_lookup_tiles(M: int, V: int, N: int, C: int, k: int = 256, *,
+                        block_v: int | None = None) -> Tuple[int, int, int]:
+    """(m_tile, block_v, block_n) for the lookup kernel: the paper's v=32
+    tile height (or the one pinned) and 512 output lanes, clamped to the
+    problem, and every row of the call per grid step while the streamed
+    O tile (C, mt, bv, k) fp32 and the (mt, 8, bn) f32 accumulator fit
+    the shared tile budget (else the tile of a multiple of 8 rows that
+    pads M least, gather.token_tile; 8 rows at least, whose tiles stay
+    far below the scoped VMEM even past the budget, so the backend takes
+    every shape). The index tile and its int32 widening stay far below
+    the scoped-VMEM budget."""
+    bv = min(block_v or core_ops.DEFAULT_BLOCK_V, V)
+    bn = min(512, N)
+    mt = token_tile(M, 4 * C * bv * k + 4 * SUBLANES * bn,
+                    core_ops.FUSED_GATHER_TILE_BYTES)
+    return mt or SUBLANES, bv, bn
 
 
 @functools.partial(
@@ -49,11 +58,11 @@ def oc_lookup(
     interpret: bool = False,
     use_pallas: bool = True,
 ) -> jax.Array:
-    """y (M, N) from the v-major output codebook O (C, V, M, k).
+    """y (M, N) from the token-major output codebook O (C, M, V, k).
     block_v/block_n accept "auto" (select_lookup_tiles) or explicit
-    ints; non-divisible V/N and M not a multiple of 8 are padded (padded
+    ints; non-divisible V/N and M past one token tile are padded (padded
     O rows are zero -> contribute 0)."""
-    _, V, M, _ = O.shape
+    C, M, V, k = O.shape
     N = I.shape[-1]
     # indices stream in their storage dtype (uint8 for n<=8); the kernel
     # upcasts per tile — see the uint8 streaming contract in kernel.py
@@ -61,21 +70,21 @@ def oc_lookup(
     if not use_pallas:
         return oc_lookup_ref(O, I, scale)
 
-    auto_bv, auto_bn = select_lookup_tiles(V, N)
-    bv = auto_bv if block_v == "auto" else min(block_v, V)
+    mt, bv, auto_bn = select_lookup_tiles(
+        M, V, N, C, k, block_v=None if block_v == "auto" else block_v)
     bn = auto_bn if block_n == "auto" else min(block_n, N)
+    pad_m = (-M) % mt
     pad_v = (-V) % bv
     pad_n = (-N) % bn
-    pad_m = (-M) % SUBLANES
     if pad_v or pad_m:
         # padded rows gather index 0 from zeroed O rows -> contribute 0
-        O = jnp.pad(O, ((0, 0), (0, pad_v), (0, pad_m), (0, 0)))
+        O = jnp.pad(O, ((0, 0), (0, pad_m), (0, pad_v), (0, 0)))
         I = jnp.pad(I, ((0, 0), (0, pad_v), (0, 0)))
     if pad_n:
         I = jnp.pad(I, ((0, 0), (0, 0), (0, pad_n)))
         scale = jnp.pad(scale, (0, pad_n))
-    y = oc_lookup_pallas(O, I, scale[None, :], block_v=bv, block_n=bn,
-                         interpret=interpret)
+    y = oc_lookup_pallas(O, I, scale[None, :], m_tile=mt, block_v=bv,
+                         block_n=bn, interpret=interpret)
     return y[:M, :N]
 
 
@@ -95,8 +104,8 @@ def eva_split_matmul(
     use_pallas: bool = True,
     out_dtype=None,
 ) -> jax.Array:
-    """EVA decode matmul as TWO kernels with the (C, V, M, 2^n) output
-    codebook (v-major) materialized in HBM between them — the paper's architecture
+    """EVA decode matmul as TWO kernels with the (C, M, V, 2^n) output
+    codebook (token-major) materialized in HBM between them — the paper's architecture
     drawn at kernel granularity, no fusion. A grouped family is just a
     wider N in the lookup stage (the OC buffer is N-independent, so the
     amortization argument is identical to the fused kernel's)."""
@@ -108,7 +117,7 @@ def eva_split_matmul(
     bmv = select_gemm_block_mv(M * vq.V, d, k) if block_mv == "auto" \
         else int(block_mv)
     O = vq_gemm(x, vq.codebooks, block_mv=bmv, interpret=interpret,
-                use_pallas=use_pallas)                    # (C, V, M, k)
+                use_pallas=use_pallas)                    # (C, M, V, k)
     y = oc_lookup(O, vq.idx, vq.scale, block_v=block_v, block_n=block_n,
                   interpret=interpret, use_pallas=use_pallas)
     return y.reshape(*lead, N).astype(out_dtype)
@@ -132,8 +141,8 @@ def _match_eva_split(spec: plan_mod.LinearSpec, policy: plan_mod.PlanPolicy
 
 def _plan_eva_split(spec: plan_mod.LinearSpec, policy: plan_mod.PlanPolicy
                     ) -> plan_mod.MatmulPlan:
-    auto_bv, bn = select_lookup_tiles(spec.V, spec.N)
-    bv = auto_bv if policy.block_v is None else min(policy.block_v, spec.V)
+    mt, bv, bn = select_lookup_tiles(spec.M, spec.V, spec.N, spec.C, spec.k,
+                                     block_v=policy.block_v)
     bmv = select_gemm_block_mv(spec.M * spec.V, spec.d, spec.k)
     out_dt = jnp.dtype(spec.out_dtype)
     interpret = policy.interpret
@@ -155,7 +164,8 @@ def _plan_eva_split(spec: plan_mod.LinearSpec, policy: plan_mod.PlanPolicy
     )
     return plan_mod.MatmulPlan(
         "eva_split_pallas", spec, policy,
-        (("bmv", bmv), ("bv", bv), ("bn", bn)), cost, run)
+        (("bmv", bmv), ("mt", mt), ("token_tiles", -(-spec.M // mt)),
+         ("bv", bv), ("bn", bn)), cost, run)
 
 
 plan_mod.register_backend("eva_split_pallas", _match_eva_split,

@@ -40,17 +40,17 @@ def vq_gemm(
     interpret: bool = False,
     use_pallas: bool = True,
 ) -> jax.Array:
-    """Compute the output codebook O (C, V, M, k) for activations x.
+    """Compute the output codebook O (C, M, V, k) for activations x.
 
-    O is v-major: each (c, v) table O[c, v] holds every token row, the
-    layout the oc_lookup kernel gathers from (one index row serves all
-    token rows of a table)."""
+    O is token-major: for token m the slab O[c, m, v0:v0+8] holds the
+    tables of 8 v-rows, the layout the oc_lookup kernel gathers from (an
+    index register's sublanes are the same 8 v-rows)."""
     C, d, k = codebooks.shape
     K = x.shape[-1]
     assert K % d == 0
     V = K // d
     M = x.size // K
-    x_flat = x.reshape(M, V, d).transpose(1, 0, 2).reshape(V * M, d)
+    x_flat = x.reshape(M * V, d)
 
     if not use_pallas:
         O = vq_gemm_ref(x_flat, codebooks)
@@ -62,4 +62,4 @@ def vq_gemm(
         O = vq_gemm_pallas(x_flat, codebooks, block_mv=block_mv, interpret=interpret)
         if pad:
             O = O[:, :MV]
-    return O.reshape(C, V, M, k)
+    return O.reshape(C, M, V, k)

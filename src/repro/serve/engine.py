@@ -340,6 +340,9 @@ class Engine:
                 log.info("%s plan [%d leaves] %s", phase, count, desc)
             for rk, count in sorted(rankings.items()):
                 log.info("%s ranking [%d leaves] %s", phase, count, rk)
+        self.metrics_counters.eva_token_tiles = max(
+            (pl.config_dict.get("token_tiles", 0)
+             for _path, pl in self.plans["decode"]), default=0)
 
         self._jit_steps()
         # prefill extras (whisper frames / vision embeds), batched once

@@ -65,6 +65,10 @@ class EngineMetrics:
     snapshots: int = 0
     restores: int = 0
     straggler_steps: int = 0         # watchdog-flagged slow decode steps
+    # most token tiles of any decode EVA kernel (set at construction): 1
+    # when every decode row shares each index tile's handling, more when
+    # the kernel's VMEM budget split the rows
+    eva_token_tiles: int = 0
     queue_wait_s: float = 0.0        # summed over admitted requests
     prefill_s: float = 0.0           # summed wall time of prefill calls
     decode_s: float = 0.0            # summed wall time of batched decode steps

@@ -583,3 +583,28 @@ def test_logprobs_surface_in_events_and_output(setup):
     assert len(out.logprobs) == 4
     np.testing.assert_allclose(out.logprobs, [e.logprob for e in on])
     assert eng.output(u_off).logprobs == ()
+
+
+def test_eva_token_tiles_counter():
+    """A 16-slot engine on the Pallas EVA path plans every decode VQ
+    linear as one token tile of all 16 rows and says so in its metrics;
+    on the jnp path no kernel tiles tokens and the counter reads 0."""
+    from repro.core.plan import PlanPolicy
+
+    cfg = get_smoke_config("minitron_4b")
+    model = build_model(cfg)
+    params = model.init_synthetic(KEY)
+    for impl, tiles in (("pallas", 1), ("jnp", 0)):
+        rc = RunConfig(mode="decode", remat=False, attn_chunk=64,
+                       plan_policy=PlanPolicy(vq_mode="eva", impl=impl,
+                                              interpret=True))
+        eng = Engine(model, params, rc, EngineConfig(num_slots=16,
+                                                     max_len=64))
+        vq_plans = [pl for _path, pl in eng.plans["decode"]
+                    if pl.spec.kind == "vq"]
+        assert vq_plans
+        if impl == "pallas":
+            assert all(pl.config_dict["mt"] == 16
+                       and pl.config_dict["token_tiles"] == 1
+                       for pl in vq_plans)
+        assert eng.metrics_counters.snapshot()["eva_token_tiles"] == tiles

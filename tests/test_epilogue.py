@@ -234,15 +234,19 @@ class TestFusedTiles:
 
         mt, bv, bn = select_fused_tiles(64, 512, 4096, 2, 256)
         v_pad = 512 + ((-512) % bv)
-        assert 2 * mt * v_pad * 256 * 4 <= ops.FUSED_OC_SCRATCH_BYTES
-        assert 2 * mt * bv * bn * 4 <= ops.FUSED_GATHER_TILE_BYTES
+        # the OC scratch and the (mt, 8, bn) accumulator of one token tile
+        assert (2 * mt * v_pad * 256 * 4 + mt * 8 * bn * 4
+                <= ops.FUSED_OC_SCRATCH_BYTES)
+        # the widened index tile streamed per grid step
+        assert 2 * bv * bn * 4 <= ops.FUSED_GATHER_TILE_BYTES
 
     def test_small_shapes_single_tile(self):
         from repro.kernels.fused_vq_matmul.ops import select_fused_tiles
 
         mt, bv, bn = select_fused_tiles(1, 10, 70, 2, 256)
-        # one 8-row token tile; v/n tiles clamp to the problem
-        assert mt == 8 and bv == 10 and bn == 70
+        # one token tile of every row, unpadded; v/n tiles clamp to the
+        # problem
+        assert mt == 1 and bv == 10 and bn == 70
 
     def test_block_v_upper_bound_is_paper_tile(self):
         from repro.kernels.fused_vq_matmul.ops import select_fused_tiles
@@ -262,4 +266,37 @@ class TestFusedTiles:
         cfgd = pl.config_dict
         _, bv, bn = select_fused_tiles(4, vq.V, vq.N, vq.C, 256)
         assert pl.backend == "eva_fused_pallas"
-        assert cfgd["bv"] == bv and cfgd["bn"] == bn and cfgd["mt"] >= 1
+        assert cfgd["bv"] == bv and cfgd["bn"] == bn
+        # every row in one token tile
+        assert cfgd["mt"] == 4 and cfgd["token_tiles"] == 1
+
+    @pytest.mark.parametrize("M,k,mt", [(16, 256, 16), (16, 1024, 8),
+                                        (3, 1024, 3), (3, 8192, 8)])
+    def test_split_token_tile_takes_every_shape(self, M, k, mt):
+        """The split backend's token tile: every row while the O tile
+        fits the tile budget, else 8 rows at least, so the backend that
+        takes what the fused kernel cannot never plans an empty tile."""
+        from repro.kernels.oc_lookup.ops import select_lookup_tiles
+
+        assert select_lookup_tiles(M, 1152, 3072, 2, k)[0] == mt
+
+    @pytest.mark.parametrize("M,mt,tiles", [(16, 16, 1), (32, 16, 2),
+                                            (24, 8, 3), (17, 8, 3),
+                                            (40, 8, 5), (48, 16, 3)])
+    def test_token_tiles_follow_the_oc_budget(self, M, mt, tiles):
+        """Every row while their OC fits the budget, else the tile of a
+        multiple of 8 rows that pads M least; nothing when not even 8
+        rows fit (the plan then leaves the shape to the split
+        backend)."""
+        from repro.kernels.fused_vq_matmul.ops import (fused_oc_bytes,
+                                                       select_fused_tiles)
+
+        V, C = 1152, 2
+        room = lambda rows: rows * (fused_oc_bytes(V, C, 256, 32, 1)
+                                    + 4 * 8 * 512)
+        got = select_fused_tiles(M, V, 3072, C, 256, oc_budget=room(16))[0]
+        assert (got, -(-M // got)) == (mt, tiles)
+        assert select_fused_tiles(M, V, 3072, C, 256,
+                                  oc_budget=room(M))[0] == M
+        assert select_fused_tiles(M, V, 3072, C, 256,
+                                  oc_budget=room(8) - 1)[0] == 0

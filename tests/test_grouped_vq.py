@@ -132,13 +132,13 @@ class TestUint8Streaming:
         """fused_vq_matmul_pallas accepts storage-dtype (uint8) index tiles
         directly and upcasts per tile in-kernel."""
         x, vq = _grouped(64, (64, 32, 32), 2, 8, 8, 2)
-        # the kernel's layout: v-major activations (V, M, d), M padded to
-        # the 8-row token tile, scale as a (1, N) row
-        X = jnp.pad(x.reshape(2, vq.V, vq.d), ((0, 6), (0, 0), (0, 0)))
+        # the kernel's layout: token-major activations (M, V, d), every
+        # row in one token tile, scale as a (1, N) row
         got = fused_vq_matmul_pallas(
-            X.transpose(1, 0, 2), vq.codebooks, vq.idx, vq.scale[None, :],
-            block_v=4, block_n=64, interpret=True,
-        )[:2]
+            x.reshape(2, vq.V, vq.d), vq.codebooks, vq.idx,
+            vq.scale[None, :], m_tile=2, block_v=4, block_n=64,
+            interpret=True,
+        )
         ref = core_ops.eva_matmul(x, vq, out_dtype=jnp.float32)
         assert vq.idx.dtype == jnp.uint8
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),

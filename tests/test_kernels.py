@@ -25,6 +25,25 @@ SHAPE_SWEEP = [
     (160, 100, 2, 8, 8, 4),      # C=4 (4-bit)
     (88, 130, 3, 8, 8, 2),       # V=11, N=130: pads BOTH v and n tiles
     (104, 52, 2, 8, 8, 1),       # V=13 odd vs block_v, N < block_n
+    (128, 256, 16, 8, 8, 2),     # 16 decode slots: one token group
+    (96, 130, 24, 8, 8, 2),      # a group of 16 tokens and one of 8
+    (64, 70, 40, 8, 8, 1),       # two groups of 16 in a loop, one of 8
+]
+
+
+def _ids(shape):
+    return "-".join(map(str, shape))
+
+
+# The fused kernel's sweep: every SHAPE_SWEEP shape (ungrouped, every
+# row in one token tile), a grouped family, and a VMEM budget so small
+# that the token tile model splits M=20 into three tiles of 8 rows (the
+# last padded): (K, N, M, d, n, C, splits, oc_budget)
+FUSED_SWEEP = [pytest.param(*shape, (), None, id=_ids(shape))
+               for shape in SHAPE_SWEEP] + [
+    pytest.param(96, 96, 16, 8, 8, 2, (50, 26, 20), None, id="grouped-M16"),
+    pytest.param(128, 96, 20, 8, 8, 2, (), 12 * 4 * 2 * 16 * 256,
+                 id="three-token-tiles-M20"),
 ]
 
 DTYPE_SWEEP = [jnp.float32, jnp.bfloat16]
@@ -56,12 +75,20 @@ def test_oc_lookup_kernel(K, N, M, d, n, C):
                                rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("K,N,M,d,n,C", SHAPE_SWEEP)
+@pytest.mark.parametrize("K,N,M,d,n,C,splits,oc_budget", FUSED_SWEEP)
 @pytest.mark.parametrize("dtype", DTYPE_SWEEP)
-def test_fused_vq_matmul_kernel(K, N, M, d, n, C, dtype):
-    x, vq = _mk(K, N, M, d, n, C, dtype)
+def test_fused_vq_matmul_kernel(K, N, M, d, n, C, splits, oc_budget, dtype):
+    from repro.kernels.fused_vq_matmul.ops import select_fused_tiles
+
+    x, vq = _mk(K, N, M, d, n, C, dtype, splits=splits)
+    kw = {}
+    if oc_budget is not None:
+        mt, _, _ = select_fused_tiles(M, vq.V, N, C, 2 ** n, block_v=4,
+                                      oc_budget=oc_budget)
+        assert mt == 8 and -(-M // mt) == 3
+        kw["m_tile"] = mt
     got = fused_vq_matmul(x, vq, interpret=True, block_v=4, block_n=64,
-                          out_dtype=jnp.float32)
+                          out_dtype=jnp.float32, **kw)
     ref = core_ops.eva_matmul(x, vq, out_dtype=jnp.float32)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-3 if dtype == jnp.bfloat16 else 1e-5,
@@ -151,7 +178,8 @@ def test_eva_split_matmul_two_kernel_pipeline():
     from repro.kernels.oc_lookup.ops import eva_split_matmul
 
     for K, N, splits, M in ((128, 96, (), 2), (80, 70, (), 3),
-                            (96, 96, (50, 26, 20), 1)):
+                            (96, 96, (50, 26, 20), 1),
+                            (96, 96, (50, 26, 20), 16)):
         x, vq = _mk(K, N, M, 8, 8, 2, jnp.float32, splits=splits)
         got = eva_split_matmul(x, vq, interpret=True, out_dtype=jnp.float32)
         ref = core_ops.dequant_matmul(x, vq, out_dtype=jnp.float32)

@@ -346,7 +346,8 @@ class TestRankedSelection:
         planner = plan_mod.Planner(calibration=self._calib(1e6, 1.0))
         pl = planner.plan(self._spec(x, vq), self.PALLAS)
         cfg = pl.config_dict
-        assert set(cfg) == {"bmv", "bv", "bn"}
+        assert set(cfg) == {"bmv", "mt", "token_tiles", "bv", "bn"}
+        assert cfg["mt"] == 1 and cfg["token_tiles"] == 1
         assert pl.cost.launches == 2
         # the HBM OC round-trip is priced: write + read of (C, M, V, 2^n)
         assert pl.cost.intermediate_bytes == 2 * 4 * vq.C * 1 * vq.V * 256

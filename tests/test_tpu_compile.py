@@ -3,9 +3,12 @@ v5e (no chip attached): the TPU compiler refuses here what interpret mode
 cannot show — gathers it cannot lower, misaligned blocks, VMEM overruns.
 
 Minitron-4B: d_model 3072, 24 heads (GQA kv=8, head_dim 128), d_ff 9216,
-2-bit EVA weights (C=2, d=8, n=8). Decode linears run at M=8 slots; the
-int8 prefill GEMM at a 512-token bucket; decode attention at B=8 over a
-2048-token cache.
+2-bit EVA weights (C=2, d=8, n=8). Decode linears run at M=8 slots, and
+the EVA kernels also at M=16 (the long-decode cell's slots, one token
+tile) and M=1 (one live request, unpadded); the int8 prefill GEMM at a
+512-token bucket; decode attention at B=8 over a 2048-token cache. The
+Qwen2-72B down projection (K=29568) compiles at M=16 in two token tiles
+of 8, its OC scratch being too large for one.
 
 The topology is described inside a module fixture (never at import:
 only one process may load the TPU library, and pytest-xdist workers all
@@ -23,6 +26,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.core.vq import KVQuantConfig, VQWeight
 
 M_DECODE = 8
+M_SLOTS = (1, 16)
 M_PREFILL = 512
 B, S, H, HK, HD = 8, 2048, 24, 8, 128
 # (name, K, N, splits): the grouped QKV family, the o-projection, the
@@ -90,6 +94,40 @@ def test_eva_split_compiles(one_chip, name, K, N, splits):
     x = _sds(one_chip, (M_DECODE, K), jnp.bfloat16)
     _assert_kernel(jax.jit(eva_split_matmul).lower(
         x, _vq(one_chip, K, N, splits)))
+
+
+@pytest.mark.parametrize("M", M_SLOTS)
+@pytest.mark.parametrize("name,K,N,splits", LINEARS)
+def test_fused_vq_matmul_compiles_at_slots(one_chip, name, K, N, splits, M):
+    from repro.kernels.fused_vq_matmul import fused_vq_matmul
+
+    x = _sds(one_chip, (M, K), jnp.bfloat16)
+    _assert_kernel(fused_vq_matmul.lower(x, _vq(one_chip, K, N, splits),
+                                         out_dtype=jnp.bfloat16))
+
+
+@pytest.mark.parametrize("M", M_SLOTS)
+@pytest.mark.parametrize("name,K,N,splits", LINEARS)
+def test_eva_split_compiles_at_slots(one_chip, name, K, N, splits, M):
+    from repro.kernels.oc_lookup.ops import eva_split_matmul
+
+    x = _sds(one_chip, (M, K), jnp.bfloat16)
+    _assert_kernel(jax.jit(eva_split_matmul).lower(
+        x, _vq(one_chip, K, N, splits)))
+
+
+def test_fused_vq_matmul_compiles_in_two_token_tiles(one_chip):
+    """Qwen2-72B's down projection at 16 slots: the OC of 16 rows
+    (2 x 16 x 3712 x 256 fp32, 121.6 MB) exceeds the VMEM budget, so the
+    tile model takes two token tiles of 8 rows."""
+    from repro.kernels.fused_vq_matmul import fused_vq_matmul
+    from repro.kernels.fused_vq_matmul.ops import select_fused_tiles
+
+    K, N = 29568, 8192
+    assert select_fused_tiles(16, K // 8, N, 2, 256)[0] == 8
+    x = _sds(one_chip, (16, K), jnp.bfloat16)
+    _assert_kernel(fused_vq_matmul.lower(x, _vq(one_chip, K, N, ()),
+                                         out_dtype=jnp.bfloat16))
 
 
 @pytest.mark.parametrize("name,K,N,splits", LINEARS[::3])
