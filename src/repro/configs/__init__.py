@@ -18,7 +18,7 @@ ARCH_IDS: List[str] = [
     "qwen2_72b",
     "whisper_medium",
     "xlstm_125m",
-    "deepseek_v2_lite_16b",
+    "deepseek_v2_lite",
     "mixtral_8x22b",
     "recurrentgemma_2b",
     "llama_3_2_vision_11b",

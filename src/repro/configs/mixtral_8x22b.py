@@ -18,6 +18,7 @@ CONFIG = ModelConfig(
     num_experts=8,
     top_k=2,
     moe_d_ff=16384,
+    norm_topk_prob=True,  # softmax over the top-k logits
     rope_theta=1000000.0,
     vq_C=2,
 )
@@ -36,5 +37,6 @@ SMOKE = ModelConfig(
     num_experts=4,
     top_k=2,
     moe_d_ff=256,
+    norm_topk_prob=True,
     vq_C=2,
 )

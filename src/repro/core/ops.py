@@ -32,7 +32,7 @@ sweep (the PR-1 batched-decode regression).
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -202,8 +202,8 @@ def _in_mesh_context() -> bool:
     reshards its 3-tuple gather indices, both forcing collectives.
 
     Uses the same private thread_resources accessor as models/common.py's
-    _mesh_divides/_maybe_constrain (no public ambient-mesh API on this
-    jax); if a jax upgrade moves it, all three degrade together to the
+    _maybe_constrain (no public ambient-mesh API on this jax); if a jax
+    upgrade moves it, both degrade together to the
     single-host behavior and distributed callers should set
     PlanPolicy(epilogue="flat") explicitly."""
     try:
@@ -437,6 +437,100 @@ def eva_matmul(
     policy = plan_mod.PlanPolicy(vq_mode="eva", impl=impl, epilogue=epi,
                                  block_v=bv, interpret=interpret)
     return plan_mod.plan_vq(x, vq, policy, out_dtype=out_dtype).execute(x, vq)
+
+
+# ---------------------------------------------------------------------------
+# Expert-grouped rows (dropless MoE): every routed (token, expert) pair is
+# one row; rows are sorted by expert and each expert's rows padded to a
+# whole number of EXPERT_TILE-row tiles, so a tile holds one expert's rows
+# ---------------------------------------------------------------------------
+
+EXPERT_TILE = 8  # rows per tile: one sublane tile of the kernel's output
+
+
+class ExpertRows(NamedTuple):
+    """Rows of a grouped expert matmul. ``x`` (R, K) holds each expert's
+    rows in expert order, padded with zero rows to whole tiles; tile t
+    (rows t*EXPERT_TILE ...) belongs to expert ``tile_expert[t]``. Tiles
+    from ``tiles`` on hold no rows (their expert repeats the last real
+    tile's). ``group_sizes`` (E,) counts each expert's padded rows."""
+    x: jax.Array
+    tile_expert: jax.Array
+    tiles: jax.Array
+    group_sizes: jax.Array
+
+
+def expert_rows(x: jax.Array, expert_of: jax.Array, num_experts: int
+                ) -> Tuple[ExpertRows, jax.Array]:
+    """Sort routed rows ``x`` (S, K), row i going to expert
+    ``expert_of[i]``, into the tiled expert layout. Returns the layout
+    and ``dest`` (S,): the layout row that holds row i. A row whose
+    expert is ``num_experts`` or more goes to an expert held elsewhere:
+    it takes no row, and its ``dest`` is the layout's height. The layout
+    has a static height: every route plus, at most, a tile's padding for
+    each expert that can be hit."""
+    S, E, t = expert_of.shape[0], num_experts, EXPERT_TILE
+    counts = jnp.zeros((E,), jnp.int32).at[expert_of].add(1, mode="drop")
+    padded = (counts + t - 1) // t * t
+    ends = jnp.cumsum(padded)
+    order = jnp.argsort(expert_of, stable=True)
+    sorted_e = jnp.minimum(expert_of[order], E - 1)
+    first = (jnp.cumsum(counts) - counts)[sorted_e]
+    R = -(-(S + (t - 1) * min(E, S)) // t) * t
+    at = (ends - padded)[sorted_e] + jnp.arange(S, dtype=jnp.int32) - first
+    dest = jnp.zeros((S,), jnp.int32).at[order].set(
+        jnp.where(expert_of[order] < E, at, R))
+    rows = jnp.zeros((R, x.shape[-1]), x.dtype).at[dest].set(x, mode="drop")
+    tiles = ends[-1] // t
+    starts = jnp.arange(R // t, dtype=jnp.int32) * t
+    te = jnp.sum(ends[None, :] <= starts[:, None], axis=1, dtype=jnp.int32)
+    te = jnp.where(starts < ends[-1], te, te[jnp.maximum(tiles - 1, 0)])
+    return ExpertRows(rows, jnp.minimum(te, E - 1), tiles, padded), dest
+
+
+def grouped_eva_matmul(rows: ExpertRows, vq: VQWeight, *, out_dtype=None
+                       ) -> jax.Array:
+    """EVA over expert tiles in jnp (the CPU path of the grouped kernel):
+    per tile, its expert's output codebook for the tile's rows, then the
+    lookup-add over that expert's indices. ``vq`` is stacked on a
+    leading expert axis."""
+    out_dtype = out_dtype or rows.x.dtype
+    R, K = rows.x.shape
+    d = vq.d
+    X = rows.x.reshape(R // EXPERT_TILE, EXPERT_TILE, K // d, d
+                       ).astype(jnp.float32)
+
+    def tile(args):
+        xt, e = args
+        O = jnp.einsum("mvd,cdk->cmvk", xt,
+                       vq.codebooks[e].astype(jnp.float32))
+        g = jnp.take_along_axis(O, vq.idx[e][:, None].astype(jnp.int32),
+                                axis=3)
+        return g.sum(axis=(0, 2)) * vq.scale[e].astype(jnp.float32)
+
+    y = jax.lax.map(tile, (X, rows.tile_expert))
+    return y.reshape(R, vq.N).astype(out_dtype)
+
+
+def grouped_dequant_matmul(rows: ExpertRows, vq: VQWeight, *,
+                           out_dtype=None) -> jax.Array:
+    """The conventional-VQ baseline of a grouped expert matmul: every
+    expert's weight reconstructed, then one ragged matmul over the rows."""
+    from repro.core.vq import dequantize
+
+    out_dtype = out_dtype or rows.x.dtype
+    w = jax.vmap(dequantize)(vq)                           # (E, K, N)
+    return grouped_fp_matmul(rows, w, out_dtype=out_dtype)
+
+
+def grouped_fp_matmul(rows: ExpertRows, w: jax.Array, *, out_dtype=None
+                      ) -> jax.Array:
+    """rows.x @ w[e] per expert group, w (E, K, N) dense."""
+    out_dtype = out_dtype or rows.x.dtype
+    x = rows.x.astype(jnp.float32)
+    y = jax.lax.ragged_dot(x, w.astype(jnp.float32), rows.group_sizes,
+                           preferred_element_type=jnp.float32)
+    return y.astype(out_dtype)
 
 
 def split_grouped_outputs(y: jax.Array, vq: VQWeight) -> Tuple[jax.Array, ...]:

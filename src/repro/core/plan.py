@@ -69,7 +69,7 @@ from repro.core.vq import VQWeight
 
 log = logging.getLogger(__name__)
 
-WEIGHT_KINDS = ("dense", "int8", "vq", "kvq_attn", "vq_logits")
+WEIGHT_KINDS = ("dense", "int8", "vq", "vq_grouped", "kvq_attn", "vq_logits")
 VQ_MODES = ("none", "eva", "dequant")
 IMPLS = ("jnp", "pallas")
 
@@ -89,8 +89,10 @@ class LinearSpec:
     """Shape + weight-kind signature of one matmul site.
 
     ``kind`` is the *resolved* weight kind: "dense" (fp path), "int8"
-    (a dense weight executed through the INT8 prefill GEMM), "vq", or
-    "kvq_attn" (a KV-VQ decode-attention site — see
+    (a dense weight executed through the INT8 prefill GEMM), "vq",
+    "vq_grouped" (VQ experts stacked on a leading axis, applied to rows
+    sorted by expert — ``core/ops.ExpertRows``; M counts the layout's
+    rows), or "kvq_attn" (a KV-VQ decode-attention site — see
     ``kvq_attention_spec`` for the field mapping). The VQ geometry
     fields are zero for non-VQ kinds. ``in_mesh``
     records whether the spec was derived inside an active mesh context
@@ -117,13 +119,14 @@ class LinearSpec:
 
     @classmethod
     def for_vq(cls, vq: VQWeight, *, M: int, x_dtype, out_dtype,
-               in_mesh: Optional[bool] = None) -> "LinearSpec":
+               in_mesh: Optional[bool] = None, kind: str = "vq"
+               ) -> "LinearSpec":
         """Spec for a VQ weight leaf: geometry read off the ``VQWeight``
         (K/N/C/V/centroids/splits), ``M`` supplied by the call site.
         ``in_mesh=None`` auto-detects an active pjit/shard_map context."""
         k = vq.codebooks.shape[-1] if hasattr(vq.codebooks, "shape") else 2 ** vq.n
         return cls(
-            M=int(M), K=vq.K, N=vq.N, kind="vq",
+            M=int(M), K=vq.K, N=vq.N, kind=kind,
             x_dtype=jnp.dtype(x_dtype).name, out_dtype=jnp.dtype(out_dtype).name,
             C=vq.C, V=vq.V, k=int(k), d=vq.d, splits=tuple(vq.splits),
             in_mesh=ops._in_mesh_context() if in_mesh is None else in_mesh,
@@ -339,6 +342,7 @@ _REGISTRY_LOCK = threading.Lock()
 # no-match retry) so pure-jnp workloads never import pallas
 _KERNEL_BACKEND_MODULES = (
     "repro.kernels.fused_vq_matmul.ops",
+    "repro.kernels.grouped_vq_matmul.ops",
     "repro.kernels.oc_lookup.ops",
     "repro.kernels.dequant_gemv.ops",
     "repro.kernels.int8_gemm.ops",
@@ -711,6 +715,13 @@ def plan_node(p: Any, x, *, mode: str, policy: PlanPolicy,
     ``x`` under run ``mode``. This is the single dispatch point used by
     ``models.common.linear`` — the weight-kind decision lives in the spec
     derivation, the formulation choice in the backend registry."""
+    if "vq" in p and p["vq"].idx.ndim == 4:
+        # experts stacked on a leading axis (idx (E, C, V, N)): one
+        # grouped matmul over rows sorted by expert (x: ops.ExpertRows)
+        spec = LinearSpec.for_vq(p["vq"], M=x.x.shape[0], x_dtype=x.x.dtype,
+                                 out_dtype=out_dtype or x.x.dtype,
+                                 kind="vq_grouped")
+        return _PLANNER.plan(spec, policy.resolve_vq_mode(mode))
     out_dtype = out_dtype or x.dtype
     if "vq" in p:
         vq: VQWeight = p["vq"]
@@ -745,14 +756,14 @@ def preplan_params(params: Any, policy: PlanPolicy, *, mode: str, m: int,
     (tokens in flight), warming the planner cache before the first trace
     and returning (path, plan) pairs for logging/introspection.
 
-    Leaves executed at other M (e.g. MoE capacity buffers under vmap)
-    simply plan again on first trace — pre-planning is a warm-up plus a
-    report, never a constraint."""
+    Expert leaves are left out: they run as grouped matmuls over the
+    rows routed to them, planned on first trace — pre-planning is a
+    warm-up plus a report, never a constraint."""
     planner = planner or _PLANNER
     out: List[Tuple[Tuple[str, ...], MatmulPlan]] = []
 
     def walk(node, path):
-        if not isinstance(node, dict):
+        if not isinstance(node, dict) or "experts" in path:
             return
         if "vq" in node:
             vq: VQWeight = node["vq"]
@@ -908,6 +919,24 @@ def _make_eva_jnp_planner(kind: str):
     return planner_fn
 
 
+def _plan_grouped_jnp(kind: str):
+    exec_fn = {"eva": ops.grouped_eva_matmul,
+               "dequant": ops.grouped_dequant_matmul}[kind]
+
+    def planner_fn(spec: LinearSpec, policy: PlanPolicy) -> MatmulPlan:
+        out_dt = jnp.dtype(spec.out_dtype)
+
+        def run(rows, vq):
+            return exec_fn(rows, vq, out_dtype=out_dt)
+
+        cost = PlanCost(macs=spec.M * spec.K * spec.N,
+                        lookup_adds=spec.C * spec.V * spec.N * spec.d,
+                        weight_bytes=vq_weight_bytes(spec))
+        return MatmulPlan(f"grouped_{kind}_jnp", spec, policy, (), cost, run)
+
+    return planner_fn
+
+
 def _register_jnp_backends() -> None:
     register_backend(
         "fp",
@@ -924,6 +953,17 @@ def _register_jnp_backends() -> None:
         lambda s, p: s.kind == "vq" and p.vq_mode == "dequant"
         and p.impl == "jnp",
         _plan_dequant_jnp,
+    )
+    register_backend(
+        "grouped_eva_jnp",
+        lambda s, p: s.kind == "vq_grouped" and p.impl == "jnp"
+        and p.vq_mode in ("eva", "none"),
+        _plan_grouped_jnp("eva"),
+    )
+    register_backend(
+        "grouped_dequant_jnp",
+        lambda s, p: s.kind == "vq_grouped" and p.vq_mode == "dequant",
+        _plan_grouped_jnp("dequant"),
     )
     for kind in EPILOGUES:
         register_backend(

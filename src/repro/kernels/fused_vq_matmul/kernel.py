@@ -58,8 +58,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.gather import (lookup_accumulate, row_group, vmem_limit,
-                                  write_rows)
+from repro.kernels.gather import (LANES, lookup_accumulate, row_group,
+                                  vmem_limit, write_rows)
+
+
+def x_stage_bytes(block_v: int, k: int) -> int:
+    """VMEM per token row of the VQ-GEMM stage besides the OC scratch:
+    the double-buffered (bv, d) activation block and its (bv, d) reshape,
+    each padded to 128 lanes, and the (bv, 2^n) f32 product."""
+    return 4 * block_v * (3 * LANES + k)
 
 
 def _fused_kernel(
@@ -125,7 +132,7 @@ def fused_vq_matmul_pallas(
     g = row_group(block_v)
     grid = (M // mt, N // block_n, n_v_tiles)
     resident = (4 * C * mt * V * k + 4 * mt * g * block_n
-                + 4 * C * block_v * block_n
+                + mt * x_stage_bytes(block_v, k) + 4 * C * block_v * block_n
                 + 2 * C * block_v * block_n * I.dtype.itemsize)
 
     kernel = functools.partial(_fused_kernel, n_v_tiles=n_v_tiles,

@@ -29,7 +29,8 @@ from repro.core import ops as core_ops
 from repro.core import plan as plan_mod
 from repro.core.vq import VQWeight
 from repro.kernels.gather import SUBLANES, token_tile
-from repro.kernels.fused_vq_matmul.kernel import fused_vq_matmul_pallas
+from repro.kernels.fused_vq_matmul.kernel import (fused_vq_matmul_pallas,
+                                                  x_stage_bytes)
 from repro.kernels.fused_vq_matmul.ref import fused_vq_matmul_ref
 
 
@@ -49,14 +50,16 @@ def select_fused_tiles(M: int, V: int, N: int, C: int, k: int = 256, *,
 
     block_v is the paper's v=32 tile height (or the one pinned) and
     block_n 512 output lanes, each clamped to the problem. m_tile is every
-    row of the call when the rows' OC scratch (fused_oc_bytes) and
-    (8, bn) f32 accumulators fit ``oc_budget``, else the tile of a
+    row of the call when the rows' OC scratch (fused_oc_bytes), (8, bn)
+    f32 accumulators and activation stage (kernel.x_stage_bytes) fit
+    ``oc_budget``, else the tile of a
     multiple of 8 rows that fits and pads M least (gather.token_tile); 0
     when not even 8 rows fit. The per-step index tile (C, bv, bn) and its
     int32 widening stay far below the budget."""
     bv = min(block_v or core_ops.DEFAULT_BLOCK_V, V)
     bn = min(512, N)
-    per_token = fused_oc_bytes(V, C, k, bv, 1) + 4 * SUBLANES * bn
+    per_token = (fused_oc_bytes(V, C, k, bv, 1) + 4 * SUBLANES * bn
+                 + x_stage_bytes(bv, k))
     return token_tile(M, per_token, oc_budget), bv, bn
 
 

@@ -41,6 +41,10 @@ _VMEM_HEADROOM = 8 * 1024 * 1024
 # v-tile's sweep
 TOKEN_GROUP = 16
 
+# 128-lane chunks of an index tile unrolled in the lookup (the fused
+# kernel's 512-lane tiles); wider tiles loop over their chunks
+UNROLLED_CHUNKS = 4
+
 
 def vmem_limit(resident_bytes: int) -> int:
     """Scoped-VMEM request for a kernel keeping ``resident_bytes`` live."""
@@ -111,6 +115,34 @@ def token_tile(M: int, per_token_bytes: int, budget: int) -> int:
                key=lambda mt: (-(-M // mt) * mt, -mt), default=0)
 
 
+def _accumulate_cols(table_at: Callable, idx_scr, acc_scr, m0, size: int,
+                     cols) -> None:
+    """One lane chunk ``cols`` of the lookup for tokens m0..m0+size:
+    the tile's v-groups in an unrolled loop, the accumulators carried in
+    registers and stored once."""
+    C, bv, _ = idx_scr.shape
+    g = acc_scr.shape[1]
+
+    def body(i, acc):
+        j0 = pl.multiple_of(i * g, g)
+        acc = list(acc)
+        for c in range(C):
+            tables = [table_at(c, m0 + t, j0) for t in range(size)]
+            lo, masks = lane_split(idx_scr[c, pl.ds(j0, g), cols],
+                                   tables[0].shape[-1])
+            for t in range(size):
+                acc[t] = acc[t] + gather_split(tables[t], lo, masks)
+        return tuple(acc)
+
+    # unrolled, so that the scheduler overlaps one v-group's gathers with
+    # the next one's loads and index handling
+    acc = jax.lax.fori_loop(
+        0, bv // g, body,
+        tuple(acc_scr[m0 + t, :, cols] for t in range(size)), unroll=True)
+    for t in range(size):
+        acc_scr[m0 + t, :, cols] = acc[t]
+
+
 def lookup_accumulate(table_at: Callable, idx_scr, acc_scr) -> None:
     """Epilogue of one (v-tile, n-tile) step of the EVA lookup.
 
@@ -123,34 +155,24 @@ def lookup_accumulate(table_at: Callable, idx_scr, acc_scr) -> None:
     done once and shared by every token: for each token a lookup is the
     gathers, the select and an add into that token's accumulator, whose
     sublanes hold partial sums over v (summed once, at the end of the V
-    sweep, by the caller)."""
-    C, bv, bn = idx_scr.shape
-    mt, g, _ = acc_scr.shape
+    sweep, by the caller). Up to UNROLLED_CHUNKS 128-lane chunks of an
+    index tile are unrolled; a wider tile (a grouped kernel's spans the
+    whole N) loops over them, which keeps the kernel's code small."""
+    bn = idx_scr.shape[-1]
+    mt = acc_scr.shape[0]
     w = lane_width(bn)
 
     def token_group(m0, size):
+        if bn // w > UNROLLED_CHUNKS:
+            def chunk(q, carry):
+                _accumulate_cols(table_at, idx_scr, acc_scr, m0, size,
+                                 pl.ds(pl.multiple_of(q * w, w), w))
+                return carry
+            jax.lax.fori_loop(0, bn // w, chunk, 0)
+            return
         for q in range(bn // w):
-            cols = slice(q * w, (q + 1) * w)
-
-            def body(i, acc):
-                j0 = pl.multiple_of(i * g, g)
-                acc = list(acc)
-                for c in range(C):
-                    tables = [table_at(c, m0 + t, j0) for t in range(size)]
-                    lo, masks = lane_split(idx_scr[c, pl.ds(j0, g), cols],
-                                           tables[0].shape[-1])
-                    for t in range(size):
-                        acc[t] = acc[t] + gather_split(tables[t], lo, masks)
-                return tuple(acc)
-
-            # unrolled, so that the scheduler overlaps one v-group's
-            # gathers with the next one's loads and index handling
-            acc = jax.lax.fori_loop(
-                0, bv // g, body,
-                tuple(acc_scr[m0 + t, :, cols] for t in range(size)),
-                unroll=True)
-            for t in range(size):
-                acc_scr[m0 + t, :, cols] = acc[t]
+            _accumulate_cols(table_at, idx_scr, acc_scr, m0, size,
+                             slice(q * w, (q + 1) * w))
 
     full, rest = divmod(mt, TOKEN_GROUP)
     if full == 1:

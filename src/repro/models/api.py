@@ -92,11 +92,16 @@ class Model:
         return kw
 
     def forward(self, params, batch: Dict[str, Any], rc: RunConfig,
-                caches=None) -> Tuple[jax.Array, Any]:
+                caches=None, stats: Optional[Dict[str, Any]] = None
+                ) -> Tuple[jax.Array, Any]:
+        """``stats`` (a dict, transformer families) receives the step's
+        traced counters, e.g. ``moe_expert_visits``."""
+        kw = self._extra_kwargs(batch)
+        if stats is not None:
+            kw["stats"] = stats
         return self.module.forward(
             params, batch["tokens"], rc, self.cfg,
-            positions=batch.get("positions"),
-            caches=caches, **self._extra_kwargs(batch),
+            positions=batch.get("positions"), caches=caches, **kw,
         )
 
     def loss(self, params, batch: Dict[str, Any], rc: RunConfig) -> jax.Array:
@@ -135,11 +140,12 @@ class Model:
         logits, caches = self.forward(params, batch, rc)
         return logits, caches
 
-    def decode(self, params, tokens, positions, caches, rc: RunConfig):
+    def decode(self, params, tokens, positions, caches, rc: RunConfig,
+               stats: Optional[Dict[str, Any]] = None):
         """tokens (B,1), positions (B,1)."""
         rc = rc.replace(mode="decode")
         batch = {"tokens": tokens, "positions": positions}
-        return self.forward(params, batch, rc, caches=caches)
+        return self.forward(params, batch, rc, caches=caches, stats=stats)
 
     # ------------------------------------------------------------- dry-run
     def input_specs(self, shape: str, *, global_batch: Optional[int] = None,

@@ -45,6 +45,14 @@ class ModelConfig:
     qk_norm: bool = False
     qkv_bias: bool = False
     rope_theta: float = 10000.0
+    # YaRN rotary scaling (deepseek-v2 rope_scaling); yarn_factor 0 is
+    # plain rotary
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
     sliding_window: int = 0          # >0: SWA for all attn layers (mixtral)
     local_window: int = 0            # >0: local attention window (recurrentgemma)
     # MLA (deepseek)
@@ -59,7 +67,7 @@ class ModelConfig:
     top_k: int = 0
     moe_d_ff: int = 0
     first_dense_layers: int = 0
-    capacity_factor: float = 1.25
+    norm_topk_prob: bool = True      # renormalize the top-k gates to sum 1
     # hybrid (recurrentgemma): layers % pattern applied in order
     rec_pattern: Tuple[str, ...] = ()   # e.g. ("rec", "rec", "attn")
     d_rnn: int = 0
@@ -134,7 +142,6 @@ class RunConfig:
     remat: bool = True
     # ---- perf-iteration levers (EXPERIMENTS.md §Perf) ----
     lm_head_last_only: bool = False  # prefill: project only the last token
-    mla_absorb: bool = False         # MLA decode in latent space (weight absorption)
     kv_cache_int8: bool = False      # int8-quantized KV cache (GQA decode)
     kv_cache_int4: bool = False      # int4-quantized KV cache (more aggressive)
     # vector-quantized KV cache (core/vq.py KVQuantConfig; frozen and
@@ -253,13 +260,63 @@ def rope_freqs(head_dim: int, theta: float) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (B, S, H, hd); positions: (B, S) int32."""
+def yarn_mscale(scale: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor 0.1 * mscale * ln(scale) + 1
+    (1 for scale <= 1)."""
+    return 1.0 if scale <= 1.0 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_inv_freq(dim: int, cfg: "ModelConfig") -> np.ndarray:
+    """Rotary frequencies of ``dim`` dimensions under YaRN scaling, as
+    DeepseekV2YarnRotaryEmbedding builds them: the plain frequencies
+    ``freq_extra`` above the correction range, ``freq_extra / factor``
+    below it, and a linear ramp between."""
+    base, f = cfg.rope_theta, cfg.yarn_factor
+    extra = 1.0 / base ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+    def corr_dim(rotations: float) -> float:
+        return (dim * math.log(cfg.yarn_original_max_pos
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    low = max(math.floor(corr_dim(cfg.yarn_beta_fast)), 0)
+    high = min(math.ceil(corr_dim(cfg.yarn_beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3),
+                   0.0, 1.0)
+    return (extra / f * ramp + extra * (1.0 - ramp)).astype(np.float32)
+
+
+def rope_tables(dim: int, cfg: "ModelConfig") -> Tuple[jax.Array, float]:
+    """(inverse frequencies, cos/sin magnitude) of ``dim`` rotary
+    dimensions: plain rotary, or YaRN's when ``cfg.yarn_factor`` is set."""
+    if not cfg.yarn_factor:
+        return rope_freqs(dim, cfg.rope_theta), 1.0
+    mag = (yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale)
+           / yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim))
+    return jnp.asarray(yarn_inv_freq(dim, cfg)), mag
+
+
+def mla_softmax_scale(cfg: "ModelConfig") -> float:
+    """MLA's attention scale: 1/sqrt(qk head dim), times YaRN's
+    mscale(factor, mscale_all_dim) squared when YaRN is on."""
+    scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+    if cfg.yarn_factor and cfg.yarn_mscale_all_dim:
+        scale *= yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim) ** 2
+    return scale
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float, *,
+               freqs: Optional[jax.Array] = None, mag: float = 1.0
+               ) -> jax.Array:
+    """x: (B, S, H, hd); positions: (B, S) int32. Rotate-half pairing
+    (dimension i with i + hd/2); ``freqs`` (hd/2,) and ``mag`` (the
+    cos/sin magnitude) default to plain rotary at ``theta``."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)                       # (hd/2,)
+    if freqs is None:
+        freqs = rope_freqs(hd, theta)                   # (hd/2,)
     ang = positions[..., None].astype(jnp.float32) * freqs  # (B, S, hd/2)
-    cos = jnp.cos(ang)[:, :, None, :]
-    sin = jnp.sin(ang)[:, :, None, :]
+    cos = mag * jnp.cos(ang)[:, :, None, :]
+    sin = mag * jnp.sin(ang)[:, :, None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
@@ -300,6 +357,7 @@ def blocked_attention(
     q_offset: int = 0,         # absolute position of q[0] (for cached decode)
     chunk: int = 1024,
     skip_oob_chunks: bool = False,
+    scale: Optional[float] = None,  # softmax scale; 1/sqrt(hd) by default
 ) -> jax.Array:
     """Memory-bounded attention: q processed in chunks (unrolled), kv scanned
     with online softmax. `skip_oob_chunks` statically skips kv chunks that
@@ -308,7 +366,8 @@ def blocked_attention(
     B, Sq, H, hd = q.shape
     hd_v = v.shape[-1]          # may differ from hd (MLA)
     Skv = k.shape[1]
-    scale = 1.0 / math.sqrt(hd)
+    if scale is None:
+        scale = 1.0 / math.sqrt(hd)
     cq = min(chunk, Sq)
     ck = min(chunk, Skv)
     # pad to multiples
@@ -767,6 +826,54 @@ def make_mla(key, cfg: ModelConfig) -> Params:
     }
 
 
+def _mla_wkv_b(p: Params, r: int, H: int, dn: int, dv: int
+               ) -> Tuple[jax.Array, jax.Array]:
+    """wkv_b as float32 (r, H, dn) key and (r, H, dv) value halves for
+    the absorbed decode (dequantized when VQ'd: r x H(dn+dv) is small)."""
+    if "vq" in p["wkv_b"]:
+        from repro.core.vq import dequantize
+
+        wb = dequantize(p["wkv_b"]["vq"])
+    else:
+        wb = p["wkv_b"]["w"]
+    wb = wb.astype(jnp.float32).reshape(r, H, dn + dv)
+    return wb[..., :dn], wb[..., dn:]
+
+
+def _einsum_f32(spec: str, a: jax.Array, b: jax.Array) -> jax.Array:
+    """einsum over operands in their storage dtype with float32
+    accumulation. XLA's CPU backend has no batched bfloat16 dot with a
+    float32 result, so there the operands widen first (same values)."""
+    if jax.default_backend() == "cpu":
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+    return jnp.einsum(spec, a, b, preferred_element_type=jnp.float32)
+
+
+def _mla_absorbed_attention(q_nope, q_rope, lat_cache, kr_cache, new_len,
+                            Wk, Wv, scale: float) -> jax.Array:
+    """Decode attention in the latent space: wkv_b folded into the query
+    (``q_nope @ Wk``) and output (``@ Wv``) sides, so the S-length
+    latent cache is read once, in its storage dtype, with float32
+    accumulation, and never re-expanded. Returns (B, 1, H, dv) f32."""
+    cdt = lat_cache.dtype
+    f32 = jnp.float32
+    q_eff = jnp.einsum("bshd,rhd->bshr", q_nope.astype(f32), Wk)  # (B,1,H,r)
+    # queries are tiny — replicate them over 'model' so the scores stay
+    # S-sharded like the latent cache (otherwise GSPMD all-to-alls the
+    # whole cache to head-sharded layout, §Perf)
+    dpq = ("pod", "data")
+    q_eff = _maybe_constrain(q_eff.astype(cdt), (dpq, None, None, None))
+    q_rope = _maybe_constrain(q_rope.astype(cdt), (dpq, None, None, None))
+    s_nope = _einsum_f32("bshr,bSr->bhsS", q_eff, lat_cache)
+    s_rope = _einsum_f32("bshd,bSd->bhsS", q_rope, kr_cache.astype(cdt))
+    scores = (s_nope + s_rope) * scale
+    valid = jnp.arange(lat_cache.shape[1])[None, :] < new_len[:, None]
+    scores = jnp.where(valid[:, None, None, :], scores, -1e30)
+    attn = jax.nn.softmax(scores, axis=-1)                        # (B,H,1,S)
+    o_lat = _einsum_f32("bhsS,bSr->bshr", attn.astype(cdt), lat_cache)
+    return jnp.einsum("bshr,rhv->bshv", o_lat, Wv)
+
+
 def mla_fwd(
     p: Params,
     x: jax.Array,
@@ -777,10 +884,16 @@ def mla_fwd(
     cache: Optional[Dict] = None,
 ) -> Tuple[jax.Array, Optional[Dict]]:
     """Multi-head Latent Attention: KV compressed to (kv_lora_rank +
-    qk_rope_dim) per token — the decode cache stores only the latent."""
+    qk_rope_dim) per token — the decode cache stores only the latent.
+    Prefill expands the latent through wkv_b; decode runs absorbed
+    (``_mla_absorbed_attention``). Rotary (YaRN when configured) covers
+    the qk_rope dimensions, and the softmax scale is
+    ``mla_softmax_scale``."""
     B, S, D = x.shape
     H = cfg.num_heads
     dn, dr, dv, r = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim, cfg.kv_lora_rank
+    freqs, mag = rope_tables(dr, cfg)
+    scale = mla_softmax_scale(cfg)
 
     if "wq_kva" in p:
         # grouped q + kv_a (both consume x): ONE wide EVA matmul sliced at
@@ -792,21 +905,13 @@ def mla_fwd(
         q = linear(p["wq"], x, rc).reshape(B, S, H, dn + dr)
         kv_a = linear(p["wkv_a"], x, rc)                  # (B, S, r + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, freqs=freqs,
+                        mag=mag)
 
     latent, k_rope = kv_a[..., :r], kv_a[..., r:]
     latent = rmsnorm(p["kv_norm"], latent, cfg.norm_eps)
-    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta)  # (B,S,1,dr)
-
-    def expand(latent_, k_rope_):
-        kv = linear(p["wkv_b"], latent_, rc).reshape(
-            latent_.shape[0], latent_.shape[1], H, dn + dv
-        )
-        k_nope, vv = kv[..., :dn], kv[..., dn:]
-        kk = jnp.concatenate(
-            [k_nope, jnp.broadcast_to(k_rope_, (*k_nope.shape[:3], dr))], axis=-1
-        )
-        return kk, vv
+    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta,
+                        freqs=freqs, mag=mag)             # (B,S,1,dr)
 
     new_cache = None
     if (cache is not None and "block_table" in cache
@@ -815,124 +920,75 @@ def mla_fwd(
             "chunked prefill for MLA latent caches is not supported "
             "(serve/engine.py gates chunking off for use_mla models)")
     if rc.mode == "decode" and cache is not None:
+        if S != 1:
+            raise ValueError("MLA decode runs one token per step")
         cache_len = cache["len"]
         new_len = cache_len + 1
-        if "block_table" in cache:
-            # paged decode: scatter latent/k_rope through the block
-            # table, run the (absorbed or expanded) attention over the
-            # gathered view — same math, view shape == contiguous shape.
-            bt = cache["block_table"]                  # (B, W)
-            bs_blk = cache["latent"].shape[1]
-            Sc = bt.shape[1] * bs_blk
-            slot = jnp.minimum(cache_len, Sc - 1)
-            blk = jnp.take_along_axis(bt, (slot // bs_blk)[:, None],
-                                      axis=1)[:, 0]
-            off = slot % bs_blk
-            kr_arena = cache["k_rope"].at[blk, off].set(
-                k_rope.astype(cache["k_rope"].dtype).reshape(B, dr),
-                mode="drop")
-            kr_cache = _paged_view(kr_arena, bt)       # (B, Sc, dr)
-            if "latent_s" in cache:
-                # KV-VQ latent: encode against the (single-"head")
-                # latent codebook, scatter uint8 indices + scale, then
-                # dequantize the gathered view — the absorb/expand math
-                # below is layout-blind.
-                variant = (rc.kv_vq.variant if rc.kv_vq is not None
-                           else "outlier")
-                cb_lat = p["kv_cb"]["lat"]             # (1, R, E, vd)
-                idx, sc = kv_encode(latent[:, :, None, :], cb_lat, variant)
-                lat_arena = cache["latent"].at[blk, off].set(
-                    idx.reshape(B, -1), mode="drop")
-                ls_arena = cache["latent_s"].at[blk, off].set(
-                    sc.reshape(B, 1).astype(cache["latent_s"].dtype),
-                    mode="drop")
-                lat_cache = kv_decode(
-                    _paged_view(lat_arena, bt)[:, :, None, :],
-                    _paged_view(ls_arena, bt), cb_lat)[:, :, 0, :]
-                new_cache = {"latent": lat_arena, "latent_s": ls_arena,
-                             "k_rope": kr_arena, "len": new_len,
-                             "block_table": bt}
-            else:
-                lat_arena = cache["latent"].at[blk, off].set(
-                    latent.astype(cache["latent"].dtype).reshape(B, r),
-                    mode="drop")
-                lat_cache = _paged_view(lat_arena, bt)  # (B, Sc, r)
-                new_cache = {"latent": lat_arena, "k_rope": kr_arena,
-                             "len": new_len, "block_table": bt}
-        else:
-            Sc = cache["latent"].shape[1]
-            slot = jnp.minimum(cache_len, Sc - 1)
-            upd = lambda c, s_, n: jax.lax.dynamic_update_slice(c, n, (s_, 0))
-            kr_cache = jax.vmap(upd)(
-                cache["k_rope"], slot,
-                k_rope.astype(cache["k_rope"].dtype).reshape(B, 1, dr)
-            )
-            if "latent_s" in cache:
-                variant = (rc.kv_vq.variant if rc.kv_vq is not None
-                           else "outlier")
-                cb_lat = p["kv_cb"]["lat"]
-                idx, sc = kv_encode(latent[:, :, None, :], cb_lat, variant)
-                lat_idx = jax.vmap(upd)(
-                    cache["latent"], slot, idx.reshape(B, 1, -1))
-                ls_cache = jax.vmap(upd)(
-                    cache["latent_s"], slot,
-                    sc.reshape(B, 1, 1).astype(cache["latent_s"].dtype))
-                lat_cache = kv_decode(
-                    lat_idx[:, :, None, :], ls_cache, cb_lat)[:, :, 0, :]
-                new_cache = {"latent": lat_idx, "latent_s": ls_cache,
-                             "k_rope": kr_cache, "len": new_len}
-            else:
-                lat_cache = jax.vmap(upd)(
-                    cache["latent"], slot,
-                    latent.astype(cache["latent"].dtype).reshape(B, 1, r)
-                )
-                new_cache = {"latent": lat_cache, "k_rope": kr_cache,
-                             "len": new_len}
-        if rc.mla_absorb:
-            # Weight-absorbed MLA (§Perf): attention runs in the latent
-            # space — wkv_b is folded into the query/output sides so the
-            # S-length cache is never re-expanded through wkv_b.
-            # wkv_b is tiny (r x H(dn+dv)); dequantize it if VQ'd.
-            if "vq" in p["wkv_b"]:
-                from repro.core.vq import dequantize as _deq
+        variant = rc.kv_vq.variant if rc.kv_vq is not None else "outlier"
+        with jax.named_scope("kv_write"):
+            if "block_table" in cache:
+                # paged decode: scatter latent/k_rope through the block
+                # table, attend over the gathered view — same math, view
+                # shape == contiguous shape.
+                bt = cache["block_table"]                  # (B, W)
+                bs_blk = cache["latent"].shape[1]
+                Sc = bt.shape[1] * bs_blk
+                slot = jnp.minimum(cache_len, Sc - 1)
+                blk = jnp.take_along_axis(bt, (slot // bs_blk)[:, None],
+                                          axis=1)[:, 0]
+                off = slot % bs_blk
 
-                wb = _deq(p["wkv_b"]["vq"])
+                def put(arena, val):
+                    return arena.at[blk, off].set(
+                        val.astype(arena.dtype).reshape(B, -1), mode="drop")
+
+                def view(arena):
+                    return _paged_view(arena, bt)
+                new_cache = {"len": new_len, "block_table": bt}
             else:
-                wb = p["wkv_b"]["w"]
-            wb = wb.astype(jnp.float32).reshape(r, H, dn + dv)
-            Wk, Wv = wb[..., :dn], wb[..., dn:]
-            latf = lat_cache.astype(jnp.float32)          # (B, S, r)
-            krf = kr_cache.astype(jnp.float32)            # (B, S, dr)
-            q_eff = jnp.einsum("bshd,rhd->bshr",
-                               q_nope.astype(jnp.float32), Wk)  # (B,1,H,r)
-            # queries are tiny — replicate them over 'model' so the scores
-            # stay S-sharded like the latent cache (otherwise GSPMD
-            # all-to-alls the whole cache to head-sharded layout, §Perf)
-            dpq = ("pod", "data")
-            q_eff = _maybe_constrain(q_eff, (dpq, None, None, None))
-            q_rope_r = _maybe_constrain(
-                q_rope.astype(jnp.float32), (dpq, None, None, None))
-            s_nope = jnp.einsum("bshr,bSr->bhsS", q_eff, latf)
-            s_rope = jnp.einsum("bshd,bSd->bhsS", q_rope_r, krf)
-            scores = (s_nope + s_rope) / jnp.sqrt(float(dn + dr))
-            pos = jnp.arange(Sc)[None, :]
-            valid = pos < new_len[:, None]
-            scores = jnp.where(valid[:, None, None, :], scores, -1e30)
-            attn = jax.nn.softmax(scores, axis=-1)        # (B,H,1,S)
-            o_lat = jnp.einsum("bhsS,bSr->bshr", attn, latf)
-            o = jnp.einsum("bshr,rhv->bshv", o_lat, Wv).astype(x.dtype)
-        else:
-            # faithful baseline: expand the whole latent cache per step
-            kk, vv = expand(lat_cache, kr_cache[:, :, None, :])
-            qq = jnp.concatenate([q_nope, q_rope], axis=-1)   # (B,1,H,dn+dr)
-            o = decode_attention(qq, kk, vv, new_len)
+                slot = jnp.minimum(cache_len, cache["latent"].shape[1] - 1)
+
+                def put(c, val):
+                    return jax.vmap(
+                        lambda c_, s_, n: jax.lax.dynamic_update_slice(
+                            c_, n, (s_, 0)))(
+                        c, slot, val.astype(c.dtype).reshape(B, 1, -1))
+
+                def view(c):
+                    return c
+                new_cache = {"len": new_len}
+            new_cache["k_rope"] = put(cache["k_rope"], k_rope)
+            if "latent_s" in cache:
+                # KV-VQ latent: encode against the (single-"head") latent
+                # codebook, write uint8 indices + scale, then dequantize
+                # the view — the absorbed math below is layout-blind.
+                cb_lat = p["kv_cb"]["lat"]                 # (1, R, E, vd)
+                idx, sc = kv_encode(latent[:, :, None, :], cb_lat, variant)
+                new_cache["latent"] = put(cache["latent"], idx)
+                new_cache["latent_s"] = put(cache["latent_s"], sc)
+                lat_cache = kv_decode(
+                    view(new_cache["latent"])[:, :, None, :],
+                    view(new_cache["latent_s"]), cb_lat)[:, :, 0, :]
+            else:
+                new_cache["latent"] = put(cache["latent"], latent)
+                lat_cache = view(new_cache["latent"])      # (B, Sc, r)
+            kr_cache = view(new_cache["k_rope"])           # (B, Sc, dr)
+        with jax.named_scope("attend"):
+            Wk, Wv = _mla_wkv_b(p, r, H, dn, dv)
+            o = _mla_absorbed_attention(q_nope, q_rope, lat_cache, kr_cache,
+                                        new_len, Wk, Wv, scale
+                                        ).astype(x.dtype)
     else:
-        kk, vv = expand(latent, k_rope)
+        kv = linear(p["wkv_b"], latent, rc).reshape(B, S, H, dn + dv)
+        k_nope, vv = kv[..., :dn], kv[..., dn:]
+        kk = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_rope, (B, S, H, dr))], axis=-1)
         qq = jnp.concatenate([q_nope, q_rope], axis=-1)
-        o = blocked_attention(
-            qq, kk, vv, causal=True, chunk=rc.attn_chunk,
-            skip_oob_chunks=rc.attn_skip_oob_chunks,
-        )
+        with jax.named_scope("attend"):
+            o = blocked_attention(
+                qq, kk, vv, causal=True, chunk=rc.attn_chunk,
+                skip_oob_chunks=rc.attn_skip_oob_chunks, scale=scale,
+            )
         if rc.mode == "prefill":
             new_cache = {
                 "latent": latent, "k_rope": k_rope.reshape(B, S, dr),
@@ -993,41 +1049,36 @@ def make_moe(key, cfg: ModelConfig) -> Params:
     return p
 
 
-def _expert_ffn(ep: Params, x: jax.Array, rc: RunConfig) -> jax.Array:
-    """x: (E, cap, D) with per-expert stacked params (leading E)."""
-    if "gu" in ep:  # grouped gate+up per expert (splits survive the vmap)
-        def one_g(e_gu, e_down, xe):
-            g, u = grouped_linear(e_gu, xe, rc)
-            return linear(e_down, jax.nn.silu(g) * u, rc)
-
-        return jax.vmap(one_g)(ep["gu"], ep["down"], x)
-
-    def one(e_gate, e_up, e_down, xe):
-        h = jax.nn.silu(linear(e_gate, xe, rc)) * linear(e_up, xe, rc)
-        return linear(e_down, h, rc)
-
-    return jax.vmap(one)(ep["gate"], ep["up"], ep["down"], x)
+def expert_linear(p: Params, rows: core_ops.ExpertRows, rc: RunConfig
+                  ) -> jax.Array:
+    """One linear of every expert over the rows routed to it: a grouped
+    matmul over weights stacked on a leading expert axis. VQ experts
+    plan through core/plan.py (the grouped EVA kernel under
+    impl="pallas"); dense experts run one ragged matmul."""
+    if "w" in p:
+        return core_ops.grouped_fp_matmul(rows, p["w"])
+    pl = plan_mod.plan_node(p, rows, mode=rc.mode, policy=rc.policy)
+    return pl.execute(rows, p["vq"])
 
 
-def _mesh_divides(axis: str, dim: int) -> bool:
-    try:
-        from jax._src import mesh as mesh_lib
-
-        mesh = mesh_lib.thread_resources.env.physical_mesh
-        if mesh.empty or axis not in mesh.axis_names:
-            return False
-        return dim % mesh.shape[axis] == 0
-    except Exception:
-        return False
+def _expert_ffn(ep: Params, rows: core_ops.ExpertRows, rc: RunConfig
+                ) -> jax.Array:
+    """SwiGLU of each row's expert: gate|up as one grouped linear (two
+    when ungrouped), then down."""
+    if "gu" in ep:
+        g, u = core_ops.split_grouped_outputs(expert_linear(ep["gu"], rows, rc),
+                                              ep["gu"]["vq"])
+    else:
+        g = expert_linear(ep["gate"], rows, rc)
+        u = expert_linear(ep["up"], rows, rc)
+    return expert_linear(ep["down"], rows._replace(x=jax.nn.silu(g) * u), rc)
 
 
 def _maybe_constrain(x: jax.Array, spec_axes) -> jax.Array:
     """Apply a sharding constraint when running under a mesh context.
 
-    MoE dispatch/combine buffers have no input sharding to propagate from;
-    without an explicit constraint SPMD tends to replicate them, turning
-    expert FFNs into (chips x) redundant compute. spec_axes maps axis ->
-    preferred mesh axis name (skipped when the axis is absent)."""
+    spec_axes maps axis -> preferred mesh axis name (skipped when the
+    axis is absent)."""
     try:
         from jax._src import mesh as mesh_lib
         from jax.sharding import NamedSharding, PartitionSpec
@@ -1051,68 +1102,91 @@ def _maybe_constrain(x: jax.Array, spec_axes) -> jax.Array:
         return x
 
 
-def moe_fwd(p: Params, x: jax.Array, rc: RunConfig, cfg: ModelConfig) -> jax.Array:
-    """Token-choice top-k MoE with capacity-based dense dispatch
-    (einsum dispatch/combine — shardable over the expert axis)."""
-    orig_shape = x.shape
+def _expert_mesh(num_experts: int):
+    """The active mesh when it shards the expert axis over 'model'
+    (runtime/sharding.py does wherever the axis divides the experts),
+    else None."""
+    try:
+        from jax._src import mesh as mesh_lib
+
+        mesh = mesh_lib.thread_resources.env.physical_mesh
+    except Exception:
+        return None
+    if (mesh.empty or "model" not in mesh.axis_names
+            or mesh.shape["model"] == 1 or num_experts % mesh.shape["model"]):
+        return None
+    return mesh
+
+
+def _held_experts(ep: Params, xt: jax.Array, topi: jax.Array,
+                  topv: jax.Array, first, rc: RunConfig
+                  ) -> Tuple[jax.Array, jax.Array]:
+    """The routes ``topi``/``topv`` (T, k) that go to the experts ``ep``
+    holds, experts ``first`` on: each expert runs over exactly its own
+    rows and the gated outputs sum per token; routes to experts held
+    elsewhere add nothing. Returns (y (T, D) float32, held experts with
+    at least one row)."""
+    T, k = topi.shape
+    held = jax.tree_util.tree_leaves(ep)[0].shape[0]
+    local = topi.reshape(T * k) - first
+    with jax.named_scope("moe_route"):
+        rows, dest = core_ops.expert_rows(
+            jnp.repeat(xt, k, axis=0),
+            jnp.where((local >= 0) & (local < held), local, held), held)
+    with jax.named_scope("moe_experts"):
+        y_rows = _expert_ffn(ep, rows, rc)                  # (R, D)
+        y = jnp.take(y_rows, dest, axis=0, mode="fill", fill_value=0)
+        y = jnp.sum(y.astype(jnp.float32).reshape(T, k, -1)
+                    * topv[..., None], axis=1)
+    return y, jnp.count_nonzero(rows.group_sizes)
+
+
+def moe_fwd(p: Params, x: jax.Array, rc: RunConfig, cfg: ModelConfig
+            ) -> Tuple[jax.Array, jax.Array]:
+    """Dropless token-choice top-k MoE.
+
+    A float32 softmax over every expert's router logit scores each
+    token; the greedy top-k are its routes, their gates renormalized
+    only where ``cfg.norm_topk_prob``. Each device runs the experts it
+    holds over exactly their own rows (``_held_experts``): on one chip
+    every expert; where the mesh shards the expert axis over 'model',
+    each shard its own experts over every token's routes, and the
+    shards' outputs sum over 'model'. Shared experts add unweighted. No
+    route is dropped, so a token's output does not depend on the rest of
+    its batch. Returns (y, experts with at least one row)."""
     D = x.shape[-1]
     xt = x.reshape(-1, D)                                   # (T, D)
-    T = xt.shape[0]
     E, k = cfg.num_experts, cfg.top_k
 
-    logits = core_ops.fp_matmul(xt, p["router"]["wr"].astype(xt.dtype),
-                                out_dtype=jnp.float32)      # (T, E)
-    gates = jax.nn.softmax(logits, axis=-1)
-    topv, topi = jax.lax.top_k(gates, k)                    # (T, k)
-    topv = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-9)
-
-    cap = max(1, int(math.ceil(T * k / E * cfg.capacity_factor)))
-    cap = min(cap, T)
-    # position of each (t, k) selection within its expert's capacity buffer
-    sel_onehot = jax.nn.one_hot(topi, E, dtype=jnp.float32)     # (T, k, E)
-    flat = sel_onehot.reshape(T * k, E)
-    pos = jnp.cumsum(flat, axis=0) - flat                       # (T*k, E)
-    pos = jnp.einsum("se,se->s", pos, flat).astype(jnp.int32)   # (T*k,)
-    keep = pos < cap
-    expert_of = topi.reshape(T * k)
-    weight_of = (topv.reshape(T * k) * keep).astype(jnp.float32)
-
-    # dispatch: (E, cap, D) — expert axis on 'model' (EP) when divisible,
-    # else capacity over 'data'; without these constraints SPMD replicates
-    # the dispatch buffer and every chip computes every expert.
-    ep_ok = _mesh_divides("model", E)
-    disp_spec = ("model", None, None) if ep_ok else (None, "data", None)
-    tok_of = jnp.repeat(jnp.arange(T), k)
-    slot = jnp.minimum(pos, cap - 1)
-    if T * k * E * cap <= (1 << 22):
-        # §Perf: decode-sized dispatch via one-hot einsums — GSPMD
-        # partitions matmuls far better than scatters (the scatter path
-        # produced ~5x extra all-to-all/permute traffic per layer).
-        oh = (jax.nn.one_hot(expert_of, E, dtype=jnp.float32)
-              * keep[:, None].astype(jnp.float32))               # (S', E)
-        ohc = oh[:, :, None] * jax.nn.one_hot(slot, cap,
-                                              dtype=jnp.float32)[:, None, :]
-        disp = jnp.einsum("sec,sd->ecd", ohc,
-                          xt[tok_of].astype(jnp.float32)).astype(xt.dtype)
-        disp = _maybe_constrain(disp, disp_spec)
-        out_e = _expert_ffn(p["experts"], disp, rc)              # (E, cap, D)
-        out_e = _maybe_constrain(out_e, disp_spec)
-        gathered = jnp.einsum("sec,ecd->sd", ohc,
-                              out_e.astype(jnp.float32))         # (T*k, D)
+    with jax.named_scope("moe_route"):
+        logits = core_ops.fp_matmul(xt, p["router"]["wr"].astype(xt.dtype),
+                                    out_dtype=jnp.float32)  # (T, E)
+        gates = jax.nn.softmax(logits, axis=-1)
+        topv, topi = jax.lax.top_k(gates, k)                # (T, k)
+        if cfg.norm_topk_prob:
+            topv = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-9)
+    mesh = _expert_mesh(E)
+    if mesh is None:
+        y, visits = _held_experts(p["experts"], xt, topi, topv, 0, rc)
     else:
-        disp = jnp.zeros((E, cap, D), xt.dtype)
-        disp = disp.at[expert_of, slot].add(
-            jnp.where(keep[:, None], xt[tok_of], 0).astype(xt.dtype)
-        )
-        disp = _maybe_constrain(disp, disp_spec)
-        out_e = _expert_ffn(p["experts"], disp, rc)              # (E, cap, D)
-        out_e = _maybe_constrain(out_e, disp_spec)
-        gathered = out_e[expert_of, slot].astype(jnp.float32)    # (T*k, D)
-    comb = (gathered.astype(jnp.float32) * weight_of[:, None]).reshape(T, k, D).sum(1)
-    y = comb.astype(x.dtype)
+        from jax.sharding import PartitionSpec as P
+
+        def shard(ep, xt, topi, topv):
+            first = jax.lax.axis_index("model") * (E // mesh.shape["model"])
+            y, visits = _held_experts(ep, xt, topi, topv, first, rc)
+            return jax.lax.psum(y, "model"), jax.lax.psum(visits, "model")
+
+        especs = jax.tree_util.tree_map(lambda _: P("model"), p["experts"])
+        # unchecked: the grouped Pallas kernel's outputs carry no
+        # varying-axes type
+        y, visits = jax.shard_map(
+            shard, mesh=mesh, in_specs=(especs, P(), P(), P()),
+            out_specs=(P(), P()), check_vma=False)(p["experts"], xt, topi,
+                                                   topv)
+    y = y.astype(x.dtype)
     if cfg.num_shared_experts:
         y = y + mlp_fwd(p["shared"], xt, rc)
-    return y.reshape(orig_shape)
+    return y.reshape(x.shape), visits
 
 
 # ---------------------------------------------------------------------------

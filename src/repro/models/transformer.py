@@ -2,7 +2,8 @@
 
 Covers the dense GQA archs (minitron-4b, qwen3-0.6b, llama3-8b, qwen2-72b,
 llama2-7b) and the MoE archs (mixtral-8x22b with SWA, deepseek-v2-lite with
-MLA + shared/routed experts + a leading dense layer).
+MLA + shared/routed experts + a leading dense layer), whose expert layers
+are dropless (models/common.py moe_fwd).
 
 Layer stacks are scanned (stacked params, one layer's HLO regardless of
 depth); `first_dense_layers` splits the stack into an unstacked prefix +
@@ -93,10 +94,10 @@ def _layer_fwd(
     x = x + a
     h = cm.rmsnorm(lp["mlp_norm"], x, cfg.norm_eps)
     if moe:
-        f = cm.moe_fwd(lp["moe"], h, rc, cfg)
+        f, visits = cm.moe_fwd(lp["moe"], h, rc, cfg)
     else:
-        f = cm.mlp_fwd(lp["mlp"], h, rc)
-    return x + f, new_cache
+        f, visits = cm.mlp_fwd(lp["mlp"], h, rc), None
+    return x + f, (new_cache, visits)
 
 
 def _scan_layers(
@@ -113,26 +114,21 @@ def _scan_layers(
 
     def step(carry, xs):
         lp, cache = xs
-        fn = body
         if rc.remat and rc.mode == "train":
             fn = jax.checkpoint(
                 lambda lp_, x_, c_: body(lp_, x_, cache=c_),
                 policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
             )
-            y, nc = fn(lp, carry, cache)
-        else:
-            y, nc = body(lp, carry, cache=cache)
-        return y, nc
+            return fn(lp, carry, cache)
+        return body(lp, carry, cache=cache)
 
     if caches is None:
-        n_layers = jax.tree_util.tree_leaves(stacked)[0].shape[0]
-        caches_xs = None
-        x, new_caches = jax.lax.scan(
+        x, (new_caches, visits) = jax.lax.scan(
             lambda c, lp: step(c, (lp, None)), x, stacked
         )
     else:
-        x, new_caches = jax.lax.scan(step, x, (stacked, caches))
-    return x, new_caches
+        x, (new_caches, visits) = jax.lax.scan(step, x, (stacked, caches))
+    return x, new_caches, (None if visits is None else jnp.sum(visits))
 
 
 # ---------------------------------------------------------------------------
@@ -148,7 +144,11 @@ def forward(
     *,
     positions: Optional[jax.Array] = None,
     caches: Optional[Any] = None,  # {"pre": ..., "body": ...} stacked per layer
+    stats: Optional[Dict[str, Any]] = None,
 ) -> Tuple[jax.Array, Optional[Any]]:
+    """Logits and caches. ``stats``, when given, receives
+    ``moe_expert_visits``: the experts with at least one routed row,
+    summed over the MoE layers (an int32 traced in the same program)."""
     B, S = tokens.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
@@ -158,18 +158,20 @@ def forward(
     new_caches: Dict[str, Any] = {}
     if cfg.first_dense_layers:
         pre_caches = None if caches is None else caches["pre"]
-        x, nc = _scan_layers(
+        x, nc, _ = _scan_layers(
             params["pre_layers"], x, rc, cfg,
             positions=positions, caches=pre_caches, moe=False,
         )
         new_caches["pre"] = nc
 
     body_caches = None if caches is None else caches["body"]
-    x, nc = _scan_layers(
+    x, nc, visits = _scan_layers(
         params["layers"], x, rc, cfg,
         positions=positions, caches=body_caches, moe=is_moe,
     )
     new_caches["body"] = nc
+    if stats is not None and visits is not None:
+        stats["moe_expert_visits"] = visits.astype(jnp.int32)
 
     if rc.mode == "prefill" and rc.lm_head_last_only:
         x = x[:, -1:]  # §Perf: skip the vocab projection for prompt tokens

@@ -25,9 +25,10 @@ Prefill is length-BUCKETED for attention families: prompts right-pad
 (edge mode — the pad value is causally masked) to power-of-two buckets,
 the true length rides along as a traced scalar, and the jitted prefill
 step retraces at most once per bucket instead of once per prompt length.
-Families whose prefill is not padding-invariant (recurrent state
-integrates pad tokens: xlstm/rglru; MoE capacity-drop routing depends on
-the token count: moe) run exact-length prefill instead.
+MoE layers are dropless, so pad tokens route and compute but no real
+row depends on them. Families whose prefill is not padding-invariant
+(recurrent state integrates pad tokens: xlstm/rglru) run exact-length
+prefill instead.
 
 All caches are batched on axis 1 (axis 0 is the scanned layer/group axis),
 so slot insertion is a tree-wide dynamic_update_slice at index b.
@@ -66,10 +67,10 @@ from repro.serve.scheduler import QueueFull, Scheduler, TrackedRequest
 log = logging.getLogger(__name__)
 
 # families whose prefill output is invariant to causal right-padding
-# (pure-attention stacks); recurrent state (xlstm/rglru) integrates pad
-# tokens and MoE capacity-based routing depends on the total token count,
-# so those families prefill at exact prompt length
-_BUCKETABLE_FAMILIES = ("dense", "whisper", "vision")
+# (attention stacks; dropless MoE routes each token on its own);
+# recurrent state (xlstm/rglru) integrates pad tokens, so those families
+# prefill at exact prompt length
+_BUCKETABLE_FAMILIES = ("dense", "moe", "whisper", "vision")
 
 
 def _named_jit(impl, **bound):
@@ -147,9 +148,8 @@ class EngineConfig:
     # K > 0 turns every batched decode step into a K-draft verify
     # window: up to K+1 tokens emit per slot per step, streams stay
     # token-identical to K=0 (the acceptance rule replays the exact
-    # sampling epilogue). Requires window == 0, an attention family
-    # (dense/moe is how caches append; MoE capacity routing depends on
-    # the token count, so only dense keeps bit-identity) and no MLA.
+    # sampling epilogue). Requires window == 0, the dense family and no
+    # MLA.
     # Per-request opt-out: GenerationRequest.speculate=False
     speculate_k: int = 0
 
@@ -165,6 +165,10 @@ class Engine:
         self.sched = Scheduler(ecfg.num_slots, max_queue=ecfg.max_queue)
         cfg = model.cfg
         self.window = cfg.sliding_window or cfg.local_window
+        # expert layers of the decode step (0 for dense models), whose
+        # routing the step reports (EngineMetrics.moe_*)
+        self._moe_layers = (cfg.num_layers - cfg.first_dense_layers
+                            if cfg.family == "moe" else 0)
         self.metrics_counters = EngineMetrics(num_slots=ecfg.num_slots)
 
         # ---- compressed KV layout (EngineConfig.kv_bits) ----
@@ -262,9 +266,8 @@ class Engine:
                     "ring caches decode one token at a time")
             if cfg.family != "dense":
                 raise ValueError(
-                    f"speculate_k > 0 requires family='dense' (MoE capacity "
-                    f"routing depends on the token count, breaking "
-                    f"token-identity), got {cfg.family!r}")
+                    f"speculate_k > 0 requires family='dense', got "
+                    f"{cfg.family!r}")
             if getattr(cfg, "use_mla", False):
                 raise ValueError(
                     "speculate_k > 0 is not supported with MLA decode")
@@ -801,8 +804,10 @@ class Engine:
         the all-finite check; it rides the existing readback, costing no
         extra device sync."""
         self.trace_counts["decode"] += 1
+        stats: Dict[str, Any] = {}
+        kw = {"stats": stats} if self._moe_layers else {}
         logits, new_caches = self.model.decode(
-            params, tokens[:, None], positions[:, None], caches, rc)
+            params, tokens[:, None], positions[:, None], caches, rc, **kw)
         # named scopes (with models/common.py's kv_write, attend and
         # lm_head) let a profiler trace attribute device time by part
         with jax.named_scope("lm_head"):
@@ -814,6 +819,11 @@ class Engine:
                 top_p=top_p, greedy=greedy, stop_ids=stop_ids,
                 remaining=remaining, active=active)
             lp = api.token_logprobs(logits, tok)
+        if self._moe_layers:
+            # the step's expert visits ride the token readback: one int32
+            # past the last slot's token
+            tok = jnp.concatenate(
+                [tok, stats["moe_expert_visits"].reshape(1).astype(tok.dtype)])
         return tok, done, bad, lp, new_keys, new_caches
 
     def _spec_decode_impl(self, params, caches, tokens, positions, succ,
@@ -1079,7 +1089,10 @@ class Engine:
                     self.succ = np.array(new_succ)
                 else:
                     tok, done, bad, lp, new_keys = outs
-                    toks = np.asarray(tok)[:, None]         # (B, 1)
+                    toks = np.asarray(tok)
+                    if self._moe_layers:
+                        visits, toks = int(toks[-1]), toks[:-1]
+                    toks = toks[:, None]                    # (B, 1)
                     lps = np.asarray(lp)[:, None]
                     acc = None
                 done = np.asarray(done)
@@ -1109,6 +1122,12 @@ class Engine:
                 m.extra_decode_tokens += (n_emit
                                           - (int(active_idx.size) - n_bad))
                 m.poisoned_slot_steps += n_bad
+                if self._moe_layers:
+                    # every row of the step routes, active or not
+                    m.moe_routed_rows += (self.ecfg.num_slots
+                                          * self.model.cfg.top_k
+                                          * self._moe_layers)
+                    m.moe_expert_visits += visits
                 if self.spec_k:
                     spec_lanes = self.active & ~bad & self.spec_on
                     n_spec = int(np.count_nonzero(spec_lanes))

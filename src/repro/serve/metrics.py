@@ -65,6 +65,12 @@ class EngineMetrics:
     snapshots: int = 0
     restores: int = 0
     straggler_steps: int = 0         # watchdog-flagged slow decode steps
+    # ---- expert layers (zero for dense models) ----
+    # decode rows x top_k x MoE layers: the routes every decode step runs
+    moe_routed_rows: int = 0
+    # experts with at least one routed row, summed over each decode
+    # step's MoE layers (counted in the decode program)
+    moe_expert_visits: int = 0
     # most token tiles of any decode EVA kernel (set at construction): 1
     # when every decode row shares each index tile's handling, more when
     # the kernel's VMEM budget split the rows

@@ -288,12 +288,15 @@ class TestFusedTiles:
         multiple of 8 rows that pads M least; nothing when not even 8
         rows fit (the plan then leaves the shape to the split
         backend)."""
+        from repro.kernels.fused_vq_matmul.kernel import x_stage_bytes
         from repro.kernels.fused_vq_matmul.ops import (fused_oc_bytes,
                                                        select_fused_tiles)
 
         V, C = 1152, 2
+        # per row: the OC scratch, the (8, bn) accumulator and the
+        # activation stage of the VQ-GEMM
         room = lambda rows: rows * (fused_oc_bytes(V, C, 256, 32, 1)
-                                    + 4 * 8 * 512)
+                                    + 4 * 8 * 512 + x_stage_bytes(32, 256))
         got = select_fused_tiles(M, V, 3072, C, 256, oc_budget=room(16))[0]
         assert (got, -(-M // got)) == (mt, tiles)
         assert select_fused_tiles(M, V, 3072, C, 256,

@@ -252,7 +252,7 @@ class TestGroupedNewFamilies:
         from repro.core.quantize import quantize_params
         from repro.models.common import RunConfig, make_mla, mla_fwd
 
-        cfg = dataclasses.replace(get_smoke_config("deepseek_v2_lite_16b"),
+        cfg = dataclasses.replace(get_smoke_config("deepseek_v2_lite"),
                                   dtype="float32")
         block = make_mla(KEY, cfg)
         pg = quantize_params({"layers": {"attn": block}}, cfg,

@@ -169,15 +169,11 @@ class TestPlanBackends:
 # --------------------------------------------------------------- model level
 
 
-KVQ_ARCHS = ["llama2_7b", "mixtral_8x22b", "deepseek_v2_lite_16b"]
+KVQ_ARCHS = ["llama2_7b", "mixtral_8x22b", "deepseek_v2_lite"]
 
 
 def _fp32_cfg(arch):
-    cfg = dataclasses.replace(get_smoke_config(arch), dtype="float32")
-    if cfg.num_experts:
-        cfg = dataclasses.replace(
-            cfg, capacity_factor=float(cfg.num_experts) / cfg.top_k)
-    return cfg
+    return dataclasses.replace(get_smoke_config(arch), dtype="float32")
 
 
 def _family_setup(arch, kvq):
@@ -254,7 +250,7 @@ def test_kvvq_paged_decode_matches_contiguous(arch):
                                    rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("arch", ["llama2_7b", "deepseek_v2_lite_16b"])
+@pytest.mark.parametrize("arch", ["llama2_7b", "deepseek_v2_lite"])
 def test_kvvq_index_arena_byte_identity(arch):
     """The paged uint8 index arenas, gathered through the block table,
     are byte-identical to the contiguous index cache — same codes, same
@@ -369,7 +365,7 @@ def test_engine_kv_bits_validation(setup):
 def test_engine_mla_int8_rejected():
     """int8 per-channel KV is a GQA layout; MLA latents only support
     fp16/fp32 or KV-VQ — the engine refuses the combination loudly."""
-    cfg = _fp32_cfg("deepseek_v2_lite_16b")
+    cfg = _fp32_cfg("deepseek_v2_lite")
     model = build_model(cfg)
     params = model.init(KEY)
     rc = RunConfig(mode="decode", remat=False, attn_chunk=16)

@@ -41,7 +41,7 @@ def test_forward_shapes_and_finite(arch):
 
 # the two slowest train-step archs (~40 s each on the CI host) are tier-2;
 # every family keeps test_forward_shapes_and_finite as its fast smoke
-_SLOW_TRAIN_ARCHS = ("recurrentgemma_2b", "deepseek_v2_lite_16b")
+_SLOW_TRAIN_ARCHS = ("recurrentgemma_2b", "deepseek_v2_lite")
 
 
 @pytest.mark.parametrize("arch", [
@@ -77,7 +77,7 @@ def test_full_config_matches_assignment(arch):
         "qwen2_72b": (80, 8192, 64, 8, 29568, 152064),
         "whisper_medium": (24, 1024, 16, 16, 4096, 51865),
         "xlstm_125m": (12, 768, 4, 4, 0, 50304),
-        "deepseek_v2_lite_16b": (27, 2048, 16, 16, 10944, 102400),
+        "deepseek_v2_lite": (27, 2048, 16, 16, 10944, 102400),
         "mixtral_8x22b": (56, 6144, 48, 8, 16384, 32768),
         "recurrentgemma_2b": (26, 2560, 10, 1, 7680, 256000),
         "llama_3_2_vision_11b": (40, 4096, 32, 8, 14336, 128256),
@@ -89,7 +89,7 @@ def test_full_config_matches_assignment(arch):
 
 
 def test_moe_extras():
-    ds = get_config("deepseek_v2_lite_16b")
+    ds = get_config("deepseek_v2_lite")
     assert (ds.num_experts, ds.num_shared_experts, ds.top_k) == (64, 2, 6)
     assert ds.kv_lora_rank == 512 and ds.use_mla
     mx = get_config("mixtral_8x22b")
@@ -115,7 +115,7 @@ def test_long_500k_applicability():
             for a in ARCH_IDS}
     assert runs["xlstm_125m"] and runs["recurrentgemma_2b"] and runs["mixtral_8x22b"]
     for a in ("minitron_4b", "qwen3_0_6b", "llama3_8b", "qwen2_72b",
-              "whisper_medium", "deepseek_v2_lite_16b", "llama_3_2_vision_11b"):
+              "whisper_medium", "deepseek_v2_lite", "llama_3_2_vision_11b"):
         assert not runs[a], a
 
 

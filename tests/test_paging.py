@@ -122,17 +122,13 @@ class TestBlockPool:
 # --------------------------------------------------------------- model level
 
 
-PAGED_ARCHS = ["llama2_7b", "mixtral_8x22b", "deepseek_v2_lite_16b",
+PAGED_ARCHS = ["llama2_7b", "mixtral_8x22b", "deepseek_v2_lite",
                "whisper_medium", "recurrentgemma_2b", "xlstm_125m",
                "llama_3_2_vision_11b"]
 
 
 def _fp32_cfg(arch):
-    cfg = dataclasses.replace(get_smoke_config(arch), dtype="float32")
-    if cfg.num_experts:
-        cfg = dataclasses.replace(
-            cfg, capacity_factor=float(cfg.num_experts) / cfg.top_k)
-    return cfg
+    return dataclasses.replace(get_smoke_config(arch), dtype="float32")
 
 
 def _extras(cfg, B=1):

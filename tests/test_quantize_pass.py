@@ -70,7 +70,7 @@ class TestEligibility:
         assert "wqkv" not in g1            # sLSTM wz/wi/wf/wo never grouped
 
     def test_mla_q_kva_grouped(self):
-        cfg, model, params = _params("deepseek_v2_lite_16b")
+        cfg, model, params = _params("deepseek_v2_lite")
         q = quantize_params(params, cfg, method="synthetic", key=KEY)
         for block in (q["layers"]["attn"], q["pre_layers"]["attn"]):
             assert "wq" not in block and "wkv_a" not in block

@@ -8,7 +8,9 @@ the EVA kernels also at M=16 (the long-decode cell's slots, one token
 tile) and M=1 (one live request, unpadded); the int8 prefill GEMM at a
 512-token bucket; decode attention at B=8 over a 2048-token cache. The
 Qwen2-72B down projection (K=29568) compiles at M=16 in two token tiles
-of 8, its OC scratch being too large for one.
+of 8, its OC scratch being too large for one. The grouped EVA kernel
+compiles at DeepSeek-V2-Lite's expert widths for the expert layouts of
+a 32-row decode step and a 128-token prefill bucket.
 
 The topology is described inside a module fixture (never at import:
 only one process may load the TPU library, and pytest-xdist workers all
@@ -168,3 +170,32 @@ def test_flash_decode_kvq_compiles(one_chip, kv_bits):
     cb = _sds(one_chip, (HK, kvq.residual, kvq.entries, kvq.vec_d),
               jnp.float32)
     _assert_kernel(flash_decode_kvq.lower(q, idx, idx, sc, sc, lens, cb, cb))
+
+
+# DeepSeek-V2-Lite's routed experts: 64 of them stacked, top-6, d_model
+# 2048, expert width 1408 (gate|up one grouped linear of N 2816). The
+# layout holds every route plus at most a tile's padding per expert:
+# 32 decode rows x 6 routes and a 128-token prefill bucket x 6 routes.
+EXPERTS = 64
+EXPERT_LINEARS = [("gu", 2048, 2816, (1408, 1408)), ("down", 1408, 2048, ())]
+
+
+@pytest.mark.parametrize("routes", [32 * 6, 128 * 6])
+@pytest.mark.parametrize("name,K,N,splits", EXPERT_LINEARS)
+def test_grouped_vq_matmul_compiles(one_chip, name, K, N, splits, routes):
+    from repro.core.ops import EXPERT_TILE, ExpertRows
+    from repro.kernels.grouped_vq_matmul import grouped_vq_matmul
+
+    R = -(-(routes + (EXPERT_TILE - 1) * EXPERTS) // EXPERT_TILE) \
+        * EXPERT_TILE
+    vq = VQWeight(idx=_sds(one_chip, (EXPERTS, 2, K // 8, N), jnp.uint8),
+                  codebooks=_sds(one_chip, (EXPERTS, 2, 8, 256), jnp.float32),
+                  scale=_sds(one_chip, (EXPERTS, N), jnp.float32),
+                  K=K, N=N, d=8, n=8, splits=splits)
+    rows = ExpertRows(_sds(one_chip, (R, K), jnp.bfloat16),
+                      _sds(one_chip, (R // EXPERT_TILE,), jnp.int32),
+                      _sds(one_chip, (), jnp.int32),
+                      _sds(one_chip, (EXPERTS,), jnp.int32))
+    compiled = _assert_kernel(grouped_vq_matmul.lower(
+        rows, vq, out_dtype=jnp.bfloat16))
+    assert "grouped_vq_matmul" in compiled.as_text()
