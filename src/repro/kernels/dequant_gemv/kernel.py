@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.gather import (SUBLANES, lane_gather, lane_width,
+from repro.kernels.gather import (SUBLANES, centroid_rows, lane_width,
                                   row_group)
 
 
@@ -48,12 +48,9 @@ def _dequant_gemv_kernel(x_ref, cb_ref, i_ref, s_ref, y_ref, idx_scr, w_scr,
         rows = [idx_scr[c, pl.ds(j0, g), :] for c in range(C)]  # (g, bn)
         for s in range(g):
             for q in range(bn // w):
-                wv = jnp.zeros((d, w), jnp.float32)
-                for c in range(C):
-                    col = rows[c][s:s + 1, q * w:(q + 1) * w]
-                    wv = wv + lane_gather(cb_ref[c],
-                                          jnp.broadcast_to(col, (d, w)))
-                w_scr[j0 + s, :, q * w:(q + 1) * w] = wv
+                w_scr[j0 + s, :, q * w:(q + 1) * w] = centroid_rows(
+                    [cb_ref[c] for c in range(C)],
+                    [r[s:s + 1, q * w:(q + 1) * w] for r in rows])
         return carry
 
     jax.lax.fori_loop(0, bv // g, body, 0)

@@ -16,6 +16,10 @@ than 128 lanes split the table into correspondingly narrower tiles, and
 tables narrower than one chunk are zero-padded (their indices never
 reach the pad).
 
+``centroid_rows`` rebuilds a weight's rows from its indices and
+codebooks with the same gather (the dequantizing kernels,
+``dequant_gemv`` and ``vq_dequant``).
+
 The output-codebook epilogue that the fused and the split EVA kernels
 share (``lookup_accumulate`` and ``write_rows``: sublanes are 8 v-rows
 of one token, one table per token), their token tile (``token_tile``)
@@ -23,7 +27,7 @@ and their scoped-VMEM request (``vmem_limit``) live here too.
 """
 from __future__ import annotations
 
-from typing import Callable, List, Tuple
+from typing import Callable, List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -100,6 +104,25 @@ def lane_gather(table: jax.Array, idx: jax.Array) -> jax.Array:
     """``out[r, j] = table[r, idx[r, j]]`` for table (R, k) and int32
     idx (R, w) with values in [0, k)."""
     return gather_split(table, *lane_split(idx, table.shape[-1]))
+
+
+def centroid_rows(tables: Sequence[jax.Array],
+                  idx_rows: Sequence[jax.Array]) -> jax.Array:
+    """The d weight rows of one v-row, rebuilt from its indices:
+    ``out[e, j] = sum over c of tables[c][e, idx_rows[c][0, j]]``.
+
+    ``tables[c]`` is codebook c as a (d, 2^n) table (sublane e holds
+    coordinate e of every centroid) and ``idx_rows[c]`` the v-row's
+    (1, w) int32 index row of codebook c, broadcast over the d sublanes
+    so that one lane gather yields coordinate e of column j's centroid
+    in sublane e. The sum over the codebooks is taken in the tables'
+    dtype, from zero, in codebook order."""
+    d = tables[0].shape[0]
+    w = idx_rows[0].shape[-1]
+    out = jnp.zeros((d, w), tables[0].dtype)
+    for table, row in zip(tables, idx_rows):
+        out = out + lane_gather(table, jnp.broadcast_to(row, (d, w)))
+    return out
 
 
 def token_tile(M: int, per_token_bytes: int, budget: int) -> int:

@@ -826,14 +826,37 @@ def make_mla(key, cfg: ModelConfig) -> Params:
     }
 
 
-def _mla_wkv_b(p: Params, r: int, H: int, dn: int, dv: int
+def _mla_wkv_b(p: Params, rc: RunConfig, r: int, H: int, dn: int, dv: int
                ) -> Tuple[jax.Array, jax.Array]:
     """wkv_b as float32 (r, H, dn) key and (r, H, dv) value halves for
-    the absorbed decode (dequantized when VQ'd: r x H(dn+dv) is small)."""
+    the absorbed decode. A VQ'd wkv_b is rebuilt on every decode step,
+    in every MLA layer: few bytes (r x H(dn+dv) weights), but as an XLA
+    gather of one d-wide codebook row per index it took 27% of
+    DeepSeek-V2-Lite's decode step on a v5e. Under impl="pallas" the
+    ``vq_dequant`` kernel rebuilds it with in-register gathers, bit for
+    bit ``dequantize``. A Mosaic kernel cannot be partitioned
+    automatically, so under a mesh of several devices it runs in a
+    ``shard_map`` on whole, replicated inputs (the indices of one layer
+    are 0.5 MB)."""
     if "vq" in p["wkv_b"]:
-        from repro.core.vq import dequantize
+        vq = p["wkv_b"]["vq"]
+        if rc.policy.impl == "pallas":
+            from repro.kernels.vq_dequant import vq_dequant
 
-        wb = dequantize(p["wkv_b"]["vq"])
+            def rebuild(vq):
+                return vq_dequant(vq, interpret=rc.policy.interpret)
+
+            mesh = _active_mesh()
+            if mesh is not None:
+                from jax.sharding import PartitionSpec as P
+
+                rebuild = jax.shard_map(rebuild, mesh=mesh, in_specs=P(),
+                                        out_specs=P(), check_vma=False)
+            wb = rebuild(vq)
+        else:
+            from repro.core.vq import dequantize
+
+            wb = dequantize(vq)
     else:
         wb = p["wkv_b"]["w"]
     wb = wb.astype(jnp.float32).reshape(r, H, dn + dv)
@@ -974,7 +997,7 @@ def mla_fwd(
                 lat_cache = view(new_cache["latent"])      # (B, Sc, r)
             kr_cache = view(new_cache["k_rope"])           # (B, Sc, dr)
         with jax.named_scope("attend"):
-            Wk, Wv = _mla_wkv_b(p, r, H, dn, dv)
+            Wk, Wv = _mla_wkv_b(p, rc, r, H, dn, dv)
             o = _mla_absorbed_attention(q_nope, q_rope, lat_cache, kr_cache,
                                         new_len, Wk, Wv, scale
                                         ).astype(x.dtype)
@@ -1102,17 +1125,23 @@ def _maybe_constrain(x: jax.Array, spec_axes) -> jax.Array:
         return x
 
 
-def _expert_mesh(num_experts: int):
-    """The active mesh when it shards the expert axis over 'model'
-    (runtime/sharding.py does wherever the axis divides the experts),
-    else None."""
+def _active_mesh():
+    """The active mesh when it holds more than one device, else None."""
     try:
         from jax._src import mesh as mesh_lib
 
         mesh = mesh_lib.thread_resources.env.physical_mesh
     except Exception:
         return None
-    if (mesh.empty or "model" not in mesh.axis_names
+    return None if mesh.empty or mesh.size == 1 else mesh
+
+
+def _expert_mesh(num_experts: int):
+    """The active mesh when it shards the expert axis over 'model'
+    (runtime/sharding.py does wherever the axis divides the experts),
+    else None."""
+    mesh = _active_mesh()
+    if (mesh is None or "model" not in mesh.axis_names
             or mesh.shape["model"] == 1 or num_experts % mesh.shape["model"]):
         return None
     return mesh

@@ -17,7 +17,7 @@ import pytest
 from repro.configs import get_config, get_smoke_config
 from repro.core import ops as core_ops
 from repro.core.plan import PlanPolicy
-from repro.core.vq import VQWeight, dequantize
+from repro.core.vq import VQWeight, dequantize, synthetic_vq
 from repro.models import build_model
 from repro.models import common as cm
 from repro.models.common import RunConfig
@@ -265,6 +265,43 @@ def test_absorbed_mla_decode_matches_expanded(dtype, tol):
     want = o.reshape(B, 1, H * dv) @ p["wo"]["w"]
     np.testing.assert_allclose(np.asarray(y_abs, np.float32),
                                np.asarray(want), rtol=tol, atol=tol)
+
+
+def test_absorbed_mla_decode_pallas_equals_jnp_bit_for_bit():
+    """Under impl="pallas" the absorbed decode rebuilds a VQ'd wkv_b with
+    the vq_dequant kernel, under impl="jnp" with ``dequantize``: the same
+    W to the bit, so the same output and cache. The other projections
+    stay dense so that wkv_b is the only piece the policy moves, and the
+    activations float32 so that no rounding to bfloat16 hides a bit."""
+    cfg = dataclasses.replace(get_smoke_config("deepseek_v2_lite"),
+                              dtype="float32")
+    H, dn, dv, r = cfg.num_heads, cfg.qk_nope_dim, cfg.v_head_dim, \
+        cfg.kv_lora_rank
+    p = dict(cm.make_mla(KEY, cfg))
+    vq = synthetic_vq(jax.random.PRNGKey(3), r, H * (dn + dv))
+    p["wkv_b"] = {"vq": dataclasses.replace(
+        vq, scale=jax.random.uniform(jax.random.PRNGKey(4), (vq.N,),
+                                     minval=0.5, maxval=2.0))}
+    B, S = 2, 9
+    x = jax.random.normal(jax.random.PRNGKey(2), (B, S + 1, cfg.d_model)
+                          ).astype(cfg.dtype)
+    pos = jnp.broadcast_to(jnp.arange(S + 1, dtype=jnp.int32), (B, S + 1))
+    _, cache = cm.mla_fwd(p, x[:, :S], RunConfig(mode="prefill", remat=False,
+                                                 attn_chunk=4),
+                          cfg, positions=pos[:, :S])
+    cache = {k: (jnp.pad(v, ((0, 0), (0, 16 - S), (0, 0))) if v.ndim == 3
+                 else v) for k, v in cache.items()}
+    got = [cm.mla_fwd(p, x[:, S:], RunConfig(
+        mode="decode", remat=False, plan_policy=PlanPolicy(
+            vq_mode="eva", impl=impl, interpret=True)),
+        cfg, positions=pos[:, S:], cache=cache) for impl in ("jnp", "pallas")]
+    (y_jnp, c_jnp), (y_pal, c_pal) = got
+    np.testing.assert_array_equal(np.asarray(y_pal, np.float32),
+                                  np.asarray(y_jnp, np.float32))
+    assert c_pal.keys() == c_jnp.keys()
+    for k in c_jnp:
+        np.testing.assert_array_equal(np.asarray(c_pal[k], np.float32),
+                                      np.asarray(c_jnp[k], np.float32))
 
 
 # --------------------------------------------------------------- engine

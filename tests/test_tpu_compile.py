@@ -10,7 +10,8 @@ tile) and M=1 (one live request, unpadded); the int8 prefill GEMM at a
 Qwen2-72B down projection (K=29568) compiles at M=16 in two token tiles
 of 8, its OC scratch being too large for one. The grouped EVA kernel
 compiles at DeepSeek-V2-Lite's expert widths for the expert layouts of
-a 32-row decode step and a 128-token prefill bucket.
+a 32-row decode step and a 128-token prefill bucket, and the VQ
+dequantization kernel at its MLA ``wkv_b``.
 
 The topology is described inside a module fixture (never at import:
 only one process may load the TPU library, and pytest-xdist workers all
@@ -138,6 +139,42 @@ def test_dequant_gemv_compiles(one_chip, name, K, N, splits):
 
     x = _sds(one_chip, (M_DECODE, K), jnp.bfloat16)
     _assert_kernel(dequant_gemv.lower(x, _vq(one_chip, K, N, splits)))
+
+
+def test_vq_dequant_compiles(one_chip):
+    """DeepSeek-V2-Lite's MLA wkv_b (kv_lora_rank 512, 16 heads x
+    (128 + 128)), rebuilt on every decode step of the absorbed MLA."""
+    from repro.kernels.vq_dequant import vq_dequant
+
+    compiled = _assert_kernel(vq_dequant.lower(_vq(one_chip, 512, 4096, ())))
+    assert "vq_dequant" in compiled.as_text()
+
+
+def test_mla_wkv_b_kernel_compiles_on_a_mesh(topo, one_chip):
+    """On four chips, with wkv_b's columns sharded over 'model', the
+    absorbed MLA decode runs the kernel inside a shard_map on whole,
+    replicated inputs: the TPU compiler refuses to partition a Mosaic
+    kernel itself."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core.plan import PlanPolicy
+    from repro.models import common as cm
+
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+    vq = VQWeight(
+        idx=_sds(NamedSharding(mesh, P(None, None, "model")), (2, 64, 4096),
+                 jnp.uint8),
+        codebooks=_sds(NamedSharding(mesh, P()), (2, 8, 256), jnp.float32),
+        scale=_sds(NamedSharding(mesh, P("model")), (4096,), jnp.float32),
+        K=512, N=4096, d=8, n=8)
+    rc = cm.RunConfig(mode="decode", plan_policy=PlanPolicy(
+        vq_mode="eva", impl="pallas"))
+    with mesh:
+        compiled = jax.jit(lambda vq: cm._mla_wkv_b(
+            {"wkv_b": {"vq": vq}}, rc, 512, 16, 128, 128)).lower(vq).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "all-gather" in text
 
 
 def test_int8_gemm_compiles(one_chip):
