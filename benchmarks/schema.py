@@ -1,11 +1,8 @@
 """Machine-readability gate for `eva-bench-rows/v1` bench JSON.
 
-BENCH_measured.json is load-bearing: `core/calibrate.py` fits the
-Planner's per-backend time constants from its rows, so a row that loses
-its `plan`/`derived`/cost fields silently breaks calibration. This
-module is the schema check CI runs against both the committed file and
-a fresh tiny-shape `benchmarks/run.py smoke --json` emission — the build
-fails on the first malformed row.
+CI runs this check against a fresh `benchmarks/run.py serve --json`
+emission, so the serving rows stay machine-readable across PRs — the
+build fails on the first malformed row.
 
 Validation is hand-rolled over the stdlib (the container pins its
 packages; no jsonschema dependency):
@@ -14,18 +11,14 @@ packages; no jsonschema dependency):
                "failures": [str, ...]? }
   row       : {"module": str, "name": str, "us_per_call": number,
                "derived": dict}
-  timed rows of the `measured`/`smoke` modules (every row except
-  harness-failure rows, name `*/ERROR`) must additionally carry the
-  calibration fields in `derived`:
-      plan (str), backend (str),
-      macs / lookup_adds / weight_bytes (non-negative numbers)
-  timed rows of the `serve` module (engine throughput traces) must carry
-  the engine totals in `derived`:
+  timed rows of the `serve` module (engine throughput traces; every row
+  except harness-failure rows, name `*/ERROR`) must carry the engine
+  totals in `derived`:
       tokens / tok_per_s / requests (non-negative numbers)
 
 CLI (exit 1 on the first error, listing all of them):
 
-    PYTHONPATH=src python -m benchmarks.schema BENCH_measured.json
+    PYTHONPATH=src python -m benchmarks.schema bench_serve.json
 """
 from __future__ import annotations
 
@@ -34,10 +27,6 @@ import sys
 from typing import Any, Dict, List, Optional, Sequence
 
 SCHEMA = "eva-bench-rows/v1"
-
-# modules whose timed rows must be calibration-ready
-CALIBRATED_MODULES = ("measured", "smoke")
-COST_FIELDS = ("macs", "lookup_adds", "weight_bytes")
 
 # serving-engine throughput rows must carry the engine totals — and the
 # KV memory accounting (serve/paging.py gauges; a contiguous engine
@@ -91,19 +80,6 @@ def validate_rows(doc: Any) -> List[str]:
         if not isinstance(derived, dict):
             errors.append(f"{where}: derived must be an object")
             continue
-        if row.get("module") in CALIBRATED_MODULES \
-                and not name.endswith("/ERROR"):
-            if not isinstance(derived.get("plan"), str):
-                errors.append(f"{where}: calibrated row missing derived.plan")
-            if not isinstance(derived.get("backend"), str):
-                errors.append(
-                    f"{where}: calibrated row missing derived.backend")
-            for f in COST_FIELDS:
-                v = derived.get(f)
-                if not _is_num(v) or v < 0:
-                    errors.append(
-                        f"{where}: calibrated row needs non-negative "
-                        f"derived.{f}, got {v!r}")
         if row.get("module") in SERVE_MODULES \
                 and not name.endswith("/ERROR"):
             for f in SERVE_FIELDS:

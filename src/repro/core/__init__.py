@@ -1,5 +1,5 @@
 """Core quantization + execution-planning package: weight/KV vector
-quantization (vq), fused ops, and the cost-ranked matmul planner (plan).
+quantization (vq), fused ops, and the matmul planner (plan).
 """
 from repro.core.vq import VQWeight, fit_vq, dequantize, synthetic_vq, vq_specs
 from repro.core.ops import (

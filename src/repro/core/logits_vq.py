@@ -16,9 +16,10 @@ The head is a param-tree node ``{"vql": VQLogitsHead}``, attached by
 ``core.quantize.attach_vq_logits_head`` and consumed by
 ``models.common.linear`` through the same ``core.plan`` dispatch as
 every other weight family: ``plan_node`` derives a ``kind="vq_logits"``
-spec and the two jnp formulations below compete on the cost model —
-gather-scoring (the point of the scheme) vs. expand-to-dense (the exact
-oracle, also used by parity tests).
+spec and one of the two jnp formulations below runs it —
+gather-scoring (the point of the scheme) wherever ``_gather_admits``
+accepts the shape, expand-to-dense (the exact oracle, also used by
+parity tests) elsewhere.
 
 Constructors mirror ``core.vq``: ``synthetic_logits_vq`` draws a random
 head whose implied dense weight is exact by construction (for parity
@@ -81,9 +82,13 @@ def expand(head: VQLogitsHead) -> jax.Array:
 
 def synthetic_logits_vq(key, d_model: int, vocab: int, kc: int,
                         dtype=jnp.float32) -> VQLogitsHead:
-    """Random head whose implied dense weight is exact by construction:
+    """Random head whose implied dense weight is exact by construction
+    (unit scales, so ``expand(head)`` holds the codebook's own columns):
     parity tests compare a model using this head against the same model
-    with ``{"w": expand(head)}`` and demand bit-identical logits."""
+    with ``{"w": expand(head)}``. The two score through differently
+    shaped GEMMs, ``(M, D) @ (D, Kc)`` then a gather against
+    ``(M, D) @ (D, V)``, so their logits agree to float32 rounding, not
+    bit for bit."""
     k_cb, k_as = jax.random.split(key)
     cb = (jax.random.normal(k_cb, (d_model, kc), jnp.float32)
           / jnp.sqrt(jnp.float32(d_model))).astype(dtype)
@@ -122,6 +127,19 @@ def vq_logits_spec(head: VQLogitsHead, *, M: int, x_dtype,
 # ---------------------------------------------------------------------------
 
 
+def _gather_admits(spec: plan_mod.LinearSpec) -> bool:
+    """Shapes on which gather-scoring is planned over expand-to-dense.
+    This is the inequality the planner's former CPU-rate cost guess
+    reduced to, kept so that the choice does not change; no chip run
+    has measured it. Gather loses only where the codebook has about as
+    many entries as the head has columns (``Kc`` near ``V``)."""
+    M, D, V, Kc = spec.M, spec.K, spec.N, spec.k
+    x_size = jnp.dtype(spec.x_dtype).itemsize
+    out_size = jnp.dtype(spec.out_dtype).itemsize
+    return (M * (2 * D * Kc + 20 * V + Kc * out_size)
+            <= D * V * (2 * M + 20 + x_size))
+
+
 def _plan_vql_gather(spec: plan_mod.LinearSpec,
                      policy: plan_mod.PlanPolicy) -> plan_mod.MatmulPlan:
     """Codebook-vocab scoring + gather: the VQ-Logits formulation."""
@@ -135,21 +153,14 @@ def _plan_vql_gather(spec: plan_mod.LinearSpec,
         y = jnp.take(y, head.assign, axis=-1)             # (..., V)
         return y * head.scale.astype(out_dt)
 
-    itemsize = jnp.dtype(spec.x_dtype).itemsize
-    cost = plan_mod.PlanCost(
-        macs=spec.M * spec.K * spec.k,
-        lookup_adds=spec.M * spec.N,
-        weight_bytes=spec.K * spec.k * itemsize + spec.N * 8,
-        intermediate_bytes=spec.M * spec.k * out_dt.itemsize,
-    )
-    return plan_mod.MatmulPlan("vql_gather_jnp", spec, policy, (), cost, run)
+    return plan_mod.MatmulPlan("vql_gather_jnp", spec, policy, (), run)
 
 
 def _plan_vql_dequant(spec: plan_mod.LinearSpec,
                       policy: plan_mod.PlanPolicy) -> plan_mod.MatmulPlan:
     """Expand-to-dense oracle: materialize the implied head, dense GEMM.
-    Never the cost winner at decode M, but competes in the same ranking
-    and anchors parity."""
+    Planned where gather-scoring declines the shape, and gather's
+    fallback everywhere else; it anchors parity."""
     out_dt = jnp.dtype(spec.out_dtype)
 
     def run(x, head: VQLogitsHead):
@@ -158,20 +169,13 @@ def _plan_vql_dequant(spec: plan_mod.LinearSpec,
             w = w.astype(x.dtype)
         return ops.fp_matmul(x, w, out_dtype=out_dt)
 
-    itemsize = jnp.dtype(spec.x_dtype).itemsize
-    cost = plan_mod.PlanCost(
-        macs=spec.M * spec.K * spec.N,
-        lookup_adds=spec.K * spec.N,
-        weight_bytes=spec.K * spec.k * itemsize + spec.N * 8,
-        intermediate_bytes=spec.K * spec.N * itemsize,
-    )
-    return plan_mod.MatmulPlan("vql_dequant_jnp", spec, policy, (), cost, run)
+    return plan_mod.MatmulPlan("vql_dequant_jnp", spec, policy, (), run)
 
 
 def _register_backends() -> None:
     plan_mod.register_backend(
         "vql_gather_jnp",
-        lambda s, p: s.kind == "vq_logits",
+        lambda s, p: s.kind == "vq_logits" and _gather_admits(s),
         _plan_vql_gather,
     )
     plan_mod.register_backend(

@@ -87,8 +87,8 @@ DEFAULT_BLOCK_V = 32
 # i.e. direct wins while gather work < reconstruction work (M < d) and
 # recon wins beyond it; the v-blocked gather only leads the gather
 # family when the direct intermediate spills the cache budget at M < d
-# (large-N mlp shapes). This is what fixed the `measured/batch32`
-# regression (EVA < 1x vs dequant with the old always-direct default).
+# (large-N mlp shapes). This is what fixed the batch-32 CPU regression
+# (EVA < 1x vs dequant with the old always-direct default).
 # ---------------------------------------------------------------------------
 
 EPILOGUES = ("direct", "flat", "blocked", "recon")
@@ -180,7 +180,7 @@ def select_epilogue(
         bound; the slab-tiled reconstruct-and-GEMM does the minimal
         C*V*N*d gathers once and rides BLAS for the M axis. This is the
         regime where the old always-direct default regressed below the
-        dequant baseline (measured/batch32).
+        dequant baseline (batch 32 on the CPU).
     """
     if distributed:
         return "flat", None
@@ -416,7 +416,7 @@ def eva_matmul(
     Thin convenience wrapper over ``Planner.plan(...).execute(...)`` —
     derives a LinearSpec from (x, vq), builds a PlanPolicy from the
     keyword surface and executes the cached plan. See core/plan.py for
-    the ranked dispatch layer and `select_epilogue` for the cost models /
+    the dispatch layer and `select_epilogue` for the cost models /
     the measured regime table of the jnp epilogues:
 
       epilogue="auto" / block_v="auto" (the default): choose per shape —
@@ -426,9 +426,9 @@ def eva_matmul(
       epilogue="direct" | "flat" | "blocked" | "recon": force a
         formulation; an int ``block_v`` pins the v-block of the
         v-blocked kinds.
-      impl="pallas": the Planner ranks the fused tiled kernel against
-        the two-kernel vq_gemm+oc_lookup split backend by calibrated
-        predicted time (an int ``block_v`` pins the chosen kernel's
+      impl="pallas": the fused tiled kernel, or the two-kernel
+        vq_gemm+oc_lookup split backend where the fused kernel's OC tile
+        does not fit VMEM (an int ``block_v`` pins the chosen kernel's
         v-tiles; jnp epilogue requests are invalid there).
     """
     from repro.core import plan as plan_mod
@@ -581,7 +581,7 @@ def vq_matmul(
 
 
 # ---------------------------------------------------------------------------
-# Analytic op counts (used by tests + the accelerator model)
+# Analytic op counts (the compute-collapse claim, pinned by tests)
 # ---------------------------------------------------------------------------
 
 
@@ -593,11 +593,6 @@ def vq_gemm_macs(M: int, K: int, n: int, C: int, d: int) -> int:
     """MACs of the VQ-GEMM stage: (M*K/d) rows x 2^n cols x d depth, per
     codebook."""
     return C * M * (K // d) * (2 ** n) * d
-
-
-def epilogue_adds(M: int, K: int, N: int, C: int, d: int) -> int:
-    """Add-only epilogue work: one add per (m, v, j, c)."""
-    return C * M * (K // d) * N
 
 
 def compute_collapse_ratio(N: int, n: int) -> float:

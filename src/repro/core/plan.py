@@ -18,26 +18,21 @@ module is the selection layer:
   MatmulPlan   : the concrete executable: chosen backend plus every
                  resolved number (epilogue kind + block_v for jnp;
                  m/v/n tiles for the Pallas kernels — nothing re-derived
-                 at execute time), cost-model estimates, the predicted
-                 execution time that ranked it and the provenance of
-                 that prediction. ``plan.execute(x, leaf)`` runs it;
-                 ``plan.describe()`` names it for logs/benchmarks.
+                 at execute time). ``plan.execute(x, leaf)`` runs it;
+                 ``plan.describe()`` names it for logs.
   Planner      : LRU cache mapping (LinearSpec, PlanPolicy) -> MatmulPlan.
                  Same spec+policy returns the SAME plan object; inside a
                  jitted decode step the planner is only consulted while
                  tracing, never on the executed path.
 
 Backends register via ``register_backend(name, matcher, planner_fn)``.
-Selection is COST-RANKED: the planner collects every backend whose
-matcher accepts (spec, policy), prices each candidate's PlanCost through
-the per-backend time model in ``core/calibrate.py`` (constants fitted
-from committed BENCH_measured.json rows when CALIBRATION.json is
-present, shared analytic rates otherwise) and picks the cheapest;
-registration order only breaks exact ties. The losing candidates are
-recorded on the chosen plan (``plan.ranking``) so logs and benchmarks
-can show the decision. Most policies admit a single candidate — the
-genuine trade-off today is ``impl="pallas"``, where the fused kernel
-and the two-kernel vq_gemm+oc_lookup split backend both match.
+Selection is BY RULE: the plan is the first healthy (not quarantined)
+backend whose matcher accepts (spec, policy), in registration order,
+and the matches after it, in the same order, are its trace-time
+fallback chain. Nothing is priced: where one backend should win only on
+some shapes, its matcher declines the others (the fused EVA kernel
+admits only shapes whose OC tile fits VMEM, leaving the rest to the
+two-kernel split backend registered after it).
 
 The pure-jnp formulations are registered here; the Pallas kernels
 register themselves from ``kernels/*/ops.py`` (each owns its tile model)
@@ -59,10 +54,8 @@ import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
-import jax
 import jax.numpy as jnp
 
-from repro.core import calibrate as calibrate_mod
 from repro.core import ops
 from repro.core.ops import EPILOGUES
 from repro.core.vq import VQWeight
@@ -211,47 +204,18 @@ class PlanPolicy:
 
 
 @dataclasses.dataclass(frozen=True)
-class PlanCost:
-    """Analytic estimates for ranking, introspection and benchmarking.
-
-    ``macs``         : multiply-accumulates on the GEMM/MXU path.
-    ``lookup_adds``  : add-only lookup/reconstruction work (the paper's
-                       epilogue adds; 0 for dense/int8).
-    ``weight_bytes`` : per-call weight-side HBM traffic (compressed for
-                       VQ kinds).
-    ``intermediate_bytes`` : extra HBM round-trip traffic of multi-kernel
-                       formulations (the split backend's (C, M, V, 2^n)
-                       output-codebook buffer; 0 for fused/jnp paths).
-    ``launches``     : kernel launches per call (prices dispatch overhead
-                       in the calibrated time model)."""
-
-    macs: int
-    lookup_adds: int
-    weight_bytes: int
-    intermediate_bytes: int = 0
-    launches: int = 1
-
-
-@dataclasses.dataclass(frozen=True)
 class MatmulPlan:
     """A frozen, executable matmul choice.
 
     ``config`` holds every resolved number the backend needs (epilogue
     kind, block_v, kernel tiles, ...) — ``execute`` re-derives nothing.
-    ``predicted_us``/``provenance``/``ranking`` record how the Planner
-    ranked this backend against the other eligible candidates
-    ("analytic" constants or a fitted "eva-calibration/v1" entry).
     """
 
     backend: str
     spec: LinearSpec
     policy: PlanPolicy
     config: Tuple[Tuple[str, Any], ...]
-    cost: PlanCost
     run: Callable[[Any, Any], Any]
-    predicted_us: Optional[float] = None
-    provenance: str = "analytic"
-    ranking: Tuple[Tuple[str, float], ...] = ()
 
     def execute(self, x, leaf):
         """Run the planned matmul. ``leaf`` is the weight leaf the spec
@@ -264,8 +228,7 @@ class MatmulPlan:
         return dict(self.config)
 
     def describe(self) -> str:
-        """One-line human summary: backend, shape, resolved config and
-        the ranked prediction (``pred=..us(analytic|eva-calibration/v1)``)."""
+        """One-line human summary: backend, shape and resolved config."""
         s = self.spec
         parts = [self.backend, f"M={s.M}", f"K={s.K}", f"N={s.N}"]
         if s.splits:
@@ -273,16 +236,7 @@ class MatmulPlan:
         parts += [f"{k}={v}" for k, v in self.config]
         if self.policy.interpret:
             parts.append("interpret")
-        if self.predicted_us is not None:
-            parts.append(f"pred={self.predicted_us:.0f}us({self.provenance})")
         return " ".join(parts)
-
-    def describe_ranking(self) -> str:
-        """The ranked candidate set, cheapest first ('' when only one
-        backend was eligible)."""
-        if len(self.ranking) < 2:
-            return ""
-        return " < ".join(f"{b}={us:.0f}us" for b, us in self.ranking)
 
 
 def kvq_attention_spec(*, B: int, S: int, H: int, Hk: int, hd: int,
@@ -291,13 +245,13 @@ def kvq_attention_spec(*, B: int, S: int, H: int, Hk: int, hd: int,
     """Spec for a KV-VQ decode-attention site (kind="kvq_attn").
 
     Decode attention over a vector-quantized cache is a matmul-shaped
-    site the planner can rank like any other: the field mapping is
+    site the planner resolves like any other: the field mapping is
     M=batch, K=cache length S, N=H*hd (the per-token attention output),
     C=Hk (kv heads), V=idx_width (uint8 indices per token per head),
     k=entries (codebook rows), d=hd. Backends registered from
-    ``kernels/flash_decode/ops.py`` match on the kind; cost-ranked
-    selection chooses between the dequantize-jnp path and the fused
-    Pallas kernel.
+    ``kernels/flash_decode/ops.py`` match on the kind: the fused Pallas
+    kernel where its matcher admits the shape, the dequantize-jnp path
+    otherwise.
 
     Args:
       B/S/H/Hk/hd: decode-attention geometry (static at trace time).
@@ -313,13 +267,6 @@ def kvq_attention_spec(*, B: int, S: int, H: int, Hk: int, hd: int,
         x_dtype=jnp.dtype(x_dtype).name, out_dtype=jnp.dtype(out_dtype).name,
         C=int(Hk), V=int(idx_width), k=int(entries), d=int(hd),
     )
-
-
-def vq_weight_bytes(spec: LinearSpec) -> int:
-    """Compressed per-call weight traffic of a VQ leaf: uint8 (n<=8) or
-    int32 indices + codebooks + per-channel scales."""
-    idx = spec.C * spec.V * spec.N * (1 if spec.k <= 256 else 4)
-    return idx + spec.C * spec.d * spec.k * 4 + spec.N * 4
 
 
 # ---------------------------------------------------------------------------
@@ -359,9 +306,8 @@ def register_backend(name: str,
 
     ``matcher(spec, policy)`` says whether this backend can execute the
     site; ``planner_fn(spec, policy)`` freezes every tile size / epilogue
-    choice into a MatmulPlan. Every matching backend becomes a ranking
-    candidate priced by its cost model; registration order only breaks
-    exact predicted-time ties."""
+    choice into a MatmulPlan. Among the matching backends the first
+    registered wins; the later ones are its fallback chain."""
     with _REGISTRY_LOCK:
         _REGISTRY[name] = _Backend(name, matcher, planner_fn)
 
@@ -399,20 +345,12 @@ class Planner:
     consults the planner while tracing and bakes ``plan.run`` into the
     program, so repeated executed steps never re-enter ``plan``.
 
-    Selection is cost-ranked: every backend whose matcher accepts the
-    (spec, policy) pair is built as a candidate and priced through the
-    per-backend time model (``calibration`` — fitted constants from
-    CALIBRATION.json — when an entry exists and the calibration was
-    measured on this device kind, the shared analytic rates otherwise);
-    the cheapest predicted time wins and ties fall back to
-    registration order. ``calibration="default"`` loads the file named
-    by $EVA_CALIBRATION (default ./CALIBRATION.json) at construction;
-    ``reload_calibration`` swaps the model for FUTURE planning without
-    touching cached plans — plan identity never depends on the cost
-    model, only the choice among multiple eligible backends does."""
+    Selection is by rule: the first healthy backend whose matcher
+    accepts the (spec, policy) pair, in registration order, is the plan;
+    the other healthy matches, in the same order, back it up as a
+    trace-time fallback chain."""
 
     def __init__(self, maxsize: int = 1024,
-                 calibration: Any = "default",
                  cooloff_s: float = DEFAULT_BACKEND_COOLOFF_S):
         self._cache: "collections.OrderedDict[Tuple[LinearSpec, PlanPolicy], MatmulPlan]" = (
             collections.OrderedDict())
@@ -420,12 +358,8 @@ class Planner:
         self._lock = threading.Lock()
         self._hits = 0
         self._misses = 0
-        self._calibration: Optional[calibrate_mod.Calibration] = (
-            calibrate_mod.load_default_calibration()
-            if calibration == "default" else calibration)
-        self._refused_for: Any = None  # last (calibration, device) refused
         # graceful degradation: backend name -> monotonic quarantine
-        # expiry. A quarantined backend is skipped by ranking until its
+        # expiry. A quarantined backend is skipped by planning until its
         # cool-off passes; both quarantine and release clear the plan
         # cache so re-planning actually changes the choice.
         self.cooloff_s = cooloff_s
@@ -437,9 +371,9 @@ class Planner:
     def record_backend_failure(self, backend: str,
                                cooloff_s: Optional[float] = None) -> None:
         """Quarantine ``backend`` for ``cooloff_s`` (planner default when
-        None): ranking skips it until the cool-off expires, then it
+        None): planning skips it until the cool-off expires, then it
         becomes a candidate again (transient failures recover). The plan
-        cache is cleared so already-planned sites re-rank too."""
+        cache is cleared so already-planned sites re-plan too."""
         with self._lock:
             self._backend_failures[backend] += 1
             self._quarantine[backend] = time.monotonic() + (
@@ -452,7 +386,7 @@ class Planner:
     def _active_quarantine(self) -> Tuple[str, ...]:
         """Currently-quarantined backend names; expired entries are
         released here (and the cache cleared, so the recovered backend
-        is actually re-ranked rather than shadowed by cached fallbacks)."""
+        is actually re-planned rather than shadowed by cached fallbacks)."""
         now = time.monotonic()
         with self._lock:
             expired = [b for b, t in self._quarantine.items() if now >= t]
@@ -463,7 +397,7 @@ class Planner:
             active = tuple(self._quarantine)
         for b in expired:
             log.info("backend %r released from quarantine (cool-off "
-                     "expired); re-ranking on next plan", b)
+                     "expired); re-planning on next plan", b)
         return active
 
     def reset_quarantine(self) -> None:
@@ -487,21 +421,9 @@ class Planner:
                 "quarantined": self._active_quarantine(),
                 "exec_fallbacks": fallbacks}
 
-    @property
-    def calibration(self) -> Optional[calibrate_mod.Calibration]:
-        """The loaded cost-model constants (None = analytic only)."""
-        return self._calibration
-
-    def reload_calibration(self, calibration: Any = "default") -> None:
-        """Swap the cost model used for future planning. Cached plans are
-        untouched: the same (spec, policy) keeps returning the SAME plan
-        object (re-planning under new constants requires cache_clear)."""
-        self._calibration = (calibrate_mod.load_default_calibration()
-                             if calibration == "default" else calibration)
-
     def plan(self, spec: LinearSpec, policy: PlanPolicy) -> MatmulPlan:
-        """Resolve (spec, policy) to the cheapest eligible MatmulPlan
-        (LRU-cached; quarantined backends are skipped).
+        """Resolve (spec, policy) to the first eligible MatmulPlan in
+        registration order (LRU-cached; quarantined backends are skipped).
 
         Raises:
           ValueError: no registered backend matches the pair — or, on a
@@ -557,7 +479,7 @@ class Planner:
                 log.error(
                     "all backends quarantined even under the degraded jnp "
                     "policy for spec=%s; ignoring quarantine", spec)
-        built = self._rank(matched, spec, policy)
+        built = self._build(matched, spec, policy)
         with self._lock:  # (re-planning a raced key is harmless)
             self._misses += 1
             self._cache[key] = built
@@ -565,53 +487,29 @@ class Planner:
                 self._cache.popitem(last=False)
         return built
 
-    def _rank(self, matched: Tuple[_Backend, ...], spec: LinearSpec,
-              policy: PlanPolicy) -> MatmulPlan:
-        """Build every eligible candidate, price it, pick the cheapest
-        (registration order breaks ties), and record the ranking +
-        provenance on the chosen plan.
+    def _build(self, matched: Tuple[_Backend, ...], spec: LinearSpec,
+               policy: PlanPolicy) -> MatmulPlan:
+        """Build every matched candidate in registration order: the first
+        is the plan, and the rest back it up through the fallback chain."""
+        candidates = tuple(be.planner_fn(spec, policy) for be in matched)
+        chosen = candidates[0]
+        if len(candidates) == 1:
+            return chosen
+        return dataclasses.replace(chosen, run=self._chain_run(candidates))
 
-        Candidates are only cross-compared under ONE model: calibrated
-        when EVERY candidate has a usable fitted entry, analytic
-        otherwise — mixing a backend's fitted microseconds against
-        another's order-of-magnitude analytic constants would make the
-        comparison meaningless (a partial CALIBRATION.json must not
-        flip rankings)."""
-        candidates = [be.planner_fn(spec, policy) for be in matched]
-        entries = [self._usable_entry(c.backend) for c in candidates]
-        if all(e is not None for e in entries):
-            prov = self._calibration.version
-        else:
-            prov = "analytic"
-            entries = [None] * len(candidates)
-        scored: List[Tuple[float, int, MatmulPlan]] = []
-        for order, (candidate, entry) in enumerate(zip(candidates, entries)):
-            us = calibrate_mod.predict_us(
-                candidate.cost, entry or calibrate_mod.ANALYTIC)
-            scored.append((us, order, candidate))
-        scored.sort(key=lambda t: (t[0], t[1]))
-        us, _, chosen = scored[0]
-        ranked_plans = tuple(c for _, _, c in scored)
-        run = (self._chain_run(ranked_plans) if len(ranked_plans) > 1
-               else chosen.run)
-        return dataclasses.replace(
-            chosen, run=run, predicted_us=us, provenance=prov,
-            ranking=tuple((c.backend, round(u, 3)) for u, _, c in scored),
-        )
-
-    def _chain_run(self, ranked: Tuple[MatmulPlan, ...]
+    def _chain_run(self, candidates: Tuple[MatmulPlan, ...]
                    ) -> Callable[[Any, Any], Any]:
-        """Bake the ranked candidates into one run callable: when the
-        chosen backend raises while the planned matmul is being BUILT
+        """Bake the candidates into one run callable: when the chosen
+        backend raises while the planned matmul is being BUILT
         (trace/lowering time — where Pallas kernel failures surface),
-        the next-cheapest candidate takes over in place, the failed
+        the next candidate takes over in place, the failed
         backend is quarantined for the cool-off and the fallback is
         counted. Already-compiled executions never re-enter Python, so
         the chain costs nothing on the steady-state path."""
 
         def run(x, leaf):
             last_err: Optional[Exception] = None
-            for cand in ranked:
+            for cand in candidates:
                 try:
                     return cand.run(x, leaf)
                 except Exception as e:  # noqa: BLE001 - any backend fault
@@ -620,39 +518,11 @@ class Planner:
                     with self._lock:
                         self._exec_fallbacks += 1
                     log.warning("planned backend %r failed at execute "
-                                "(%s: %s); trying next-cheapest candidate",
+                                "(%s: %s); trying next candidate",
                                 cand.backend, type(e).__name__, e)
             raise last_err
 
         return run
-
-    def _applied_calibration(self) -> Optional[calibrate_mod.Calibration]:
-        """The loaded calibration when its rows ran on this process's
-        device kind; None (analytic ranking) otherwise."""
-        calib = self._calibration
-        if calib is None:
-            return None
-        kind = jax.devices()[0].device_kind
-        if calib.applies_to(kind):
-            return calib
-        if self._refused_for != (calib, kind):
-            self._refused_for = (calib, kind)
-            log.info("calibration %s was measured on %s, not %s: ranking "
-                     "is analytic", calib.source,
-                     calib.device or "no named device", kind)
-        return None
-
-    def _usable_entry(self, backend: str
-                      ) -> Optional["calibrate_mod.BackendCalibration"]:
-        """The backend's fitted entry when the calibration applies to
-        this device and the entry rests on enough samples to trust
-        (calibrate.MIN_FIT_ROWS — an NNLS over fewer rows than free
-        parameters fits perfectly but means nothing)."""
-        calib = self._applied_calibration()
-        entry = calib.get(backend) if calib is not None else None
-        if entry is not None and entry.rows >= calibrate_mod.MIN_FIT_ROWS:
-            return entry
-        return None
 
     @staticmethod
     def _match_all(spec: LinearSpec, policy: PlanPolicy
@@ -691,16 +561,6 @@ def reset_quarantine() -> None:
 def plan(spec: LinearSpec, policy: PlanPolicy) -> MatmulPlan:
     """Resolve (spec, policy) through the default planner's cache."""
     return _PLANNER.plan(spec, policy)
-
-
-def first_match_backend(spec: LinearSpec, policy: PlanPolicy
-                        ) -> Optional[str]:
-    """The backend the pre-ranking FIRST-MATCH dispatch would have
-    chosen (registration order). Benchmarks report it next to the ranked
-    choice so ranked-vs-first-match decisions stay visible."""
-    _ensure_kernel_backends()
-    matched = Planner._match_all(spec, policy)
-    return matched[0].name if matched else None
 
 
 # ---------------------------------------------------------------------------
@@ -816,7 +676,7 @@ def preplan_prefill_buckets(params: Any, policy: PlanPolicy, *,
 # jnp backend registrations (fp / int8 / dequant / EVA epilogues)
 #
 # The Pallas counterparts register from kernels/*/ops.py, each owning its
-# tile model; these jnp formulations own the epilogue cost models in
+# tile model; these jnp formulations own the epilogue rule in
 # core/ops.py (select_epilogue + the block sizing helpers), which are
 # called from HERE only — model layers never re-derive a formulation.
 # ---------------------------------------------------------------------------
@@ -824,16 +684,13 @@ def preplan_prefill_buckets(params: Any, policy: PlanPolicy, *,
 
 def _plan_fp(spec: LinearSpec, policy: PlanPolicy) -> MatmulPlan:
     out_dt = jnp.dtype(spec.out_dtype)
-    itemsize = jnp.dtype(spec.x_dtype).itemsize
 
     def run(x, w):
         if w.dtype != x.dtype:
             w = w.astype(x.dtype)
         return ops.fp_matmul(x, w, out_dtype=out_dt)
 
-    cost = PlanCost(macs=spec.M * spec.K * spec.N, lookup_adds=0,
-                    weight_bytes=spec.K * spec.N * itemsize)
-    return MatmulPlan("fp", spec, policy, (), cost, run)
+    return MatmulPlan("fp", spec, policy, (), run)
 
 
 def _plan_int8_jnp(spec: LinearSpec, policy: PlanPolicy) -> MatmulPlan:
@@ -842,9 +699,7 @@ def _plan_int8_jnp(spec: LinearSpec, policy: PlanPolicy) -> MatmulPlan:
     def run(x, w):
         return ops.int8_matmul(x, w, out_dtype=out_dt)
 
-    cost = PlanCost(macs=spec.M * spec.K * spec.N, lookup_adds=0,
-                    weight_bytes=spec.K * spec.N)
-    return MatmulPlan("int8_jnp", spec, policy, (), cost, run)
+    return MatmulPlan("int8_jnp", spec, policy, (), run)
 
 
 def _plan_dequant_jnp(spec: LinearSpec, policy: PlanPolicy) -> MatmulPlan:
@@ -853,10 +708,7 @@ def _plan_dequant_jnp(spec: LinearSpec, policy: PlanPolicy) -> MatmulPlan:
     def run(x, vq):
         return ops.dequant_matmul(x, vq, out_dtype=out_dt)
 
-    cost = PlanCost(macs=spec.M * spec.K * spec.N,
-                    lookup_adds=spec.C * spec.V * spec.N * spec.d,
-                    weight_bytes=vq_weight_bytes(spec))
-    return MatmulPlan("dequant_jnp", spec, policy, (), cost, run)
+    return MatmulPlan("dequant_jnp", spec, policy, (), run)
 
 
 def _is_eva_jnp(spec: LinearSpec, policy: PlanPolicy) -> bool:
@@ -884,23 +736,6 @@ def _resolve_eva_epilogue(spec: LinearSpec, policy: PlanPolicy
     return epi, None
 
 
-def _eva_jnp_cost(spec: LinearSpec, kind: str) -> PlanCost:
-    if kind == "recon":
-        # slab-tiled reconstruct-and-GEMM: dequant's algebra, cache-tiled
-        return PlanCost(macs=spec.M * spec.K * spec.N,
-                        lookup_adds=spec.C * spec.V * spec.N * spec.d,
-                        weight_bytes=vq_weight_bytes(spec))
-    return PlanCost(
-        macs=ops.vq_gemm_macs(spec.M, spec.K, _log2(spec.k), spec.C, spec.d),
-        lookup_adds=ops.epilogue_adds(spec.M, spec.K, spec.N, spec.C, spec.d),
-        weight_bytes=vq_weight_bytes(spec),
-    )
-
-
-def _log2(k: int) -> int:
-    return max(int(k).bit_length() - 1, 0)
-
-
 def _make_eva_jnp_planner(kind: str):
     def planner_fn(spec: LinearSpec, policy: PlanPolicy) -> MatmulPlan:
         resolved, bv = _resolve_eva_epilogue(spec, policy)
@@ -913,8 +748,7 @@ def _make_eva_jnp_planner(kind: str):
 
         config = (("epilogue", kind),) + \
             ((("bv", bv),) if bv is not None else ())
-        return MatmulPlan(f"eva_{kind}", spec, policy, config,
-                          _eva_jnp_cost(spec, kind), run)
+        return MatmulPlan(f"eva_{kind}", spec, policy, config, run)
 
     return planner_fn
 
@@ -929,10 +763,7 @@ def _plan_grouped_jnp(kind: str):
         def run(rows, vq):
             return exec_fn(rows, vq, out_dtype=out_dt)
 
-        cost = PlanCost(macs=spec.M * spec.K * spec.N,
-                        lookup_adds=spec.C * spec.V * spec.N * spec.d,
-                        weight_bytes=vq_weight_bytes(spec))
-        return MatmulPlan(f"grouped_{kind}_jnp", spec, policy, (), cost, run)
+        return MatmulPlan(f"grouped_{kind}_jnp", spec, policy, (), run)
 
     return planner_fn
 

@@ -98,13 +98,8 @@ def _plan_dequant_pallas(spec: plan_mod.LinearSpec,
         return dequant_gemv(x, vq, block_v=bv, block_n=bn,
                             interpret=interpret, out_dtype=out_dt)
 
-    cost = plan_mod.PlanCost(
-        macs=spec.M * spec.K * spec.N,
-        lookup_adds=spec.C * spec.V * spec.N * spec.d,
-        weight_bytes=plan_mod.vq_weight_bytes(spec),
-    )
     return plan_mod.MatmulPlan("dequant_pallas", spec, policy,
-                               (("bv", bv), ("bn", bn)), cost, run)
+                               (("bv", bv), ("bn", bn)), run)
 
 
 plan_mod.register_backend("dequant_pallas", _match_dequant_pallas,

@@ -5,14 +5,15 @@ the KV-VQ decode-attention plan backends.
 entry points (``flash_decode_kvq``/``flash_decode_kvq_paged``) consume
 vector-quantized caches natively — uint8 codebook indices + per-(token,
 head) scales + params-resident codebooks — and register two backends
-with core/plan.py so the cost-ranked planner covers the new kernel:
+with core/plan.py, in this order (the first that matches is planned):
 
+  "kvq_flash_pallas" : the fused kernel — query/K-codebook dot table
+                       computed once per step, indices streamed and
+                       gathered in-kernel (impl="pallas", on the shapes
+                       ``_kvq_pallas_admits`` accepts).
   "kvq_dequant_jnp"  : reconstruct the fp cache through core.vq.kv_decode
                        then run the masked-softmax oracle (always
                        eligible for kind="kvq_attn"; the parity anchor).
-  "kvq_flash_pallas" : the fused kernel — query/K-codebook dot table
-                       computed once per step, indices streamed and
-                       gathered in-kernel (impl="pallas" only).
 """
 from __future__ import annotations
 
@@ -196,15 +197,38 @@ def flash_decode_kvq_paged(
 
 
 # ---------------------------------------------------------------------------
-# Plan backends (cost-ranked selection over kind="kvq_attn" sites)
+# Plan backends over kind="kvq_attn" sites
 # ---------------------------------------------------------------------------
 
 
-def _kvq_idx_bytes(spec: plan_mod.LinearSpec) -> int:
-    """Per-step compressed cache traffic: two uint8 index planes of
-    (B, S, Hk, idx_width) plus two bf16 scale planes."""
-    return (2 * spec.M * spec.K * spec.C * spec.V
-            + 4 * spec.M * spec.K * spec.C)
+def _kvq_pallas_admits(spec: plan_mod.LinearSpec) -> bool:
+    """Shapes on which the fused kernel is planned over the dequantize
+    path. This is the inequality the planner's former CPU-rate cost
+    guess reduced to (5 GMAC/s, 0.5 G adds/s, 10 GB/s, 50 us a launch),
+    kept so that the choice does not change: no chip run has measured
+    either side, and the KV-VQ cell is to settle it. Roughly, the
+    kernel's per-step query/codebook table must be paid back by the
+    cache positions it does not reconstruct."""
+    B, S, Hk, W, E, hd = spec.M, spec.K, spec.C, spec.V, spec.k, spec.d
+    H = spec.N // hd
+    return B * (H * E * (hd + 2 * W)
+                + S * (10 * W * (H - Hk) - 2 * hd * (H + 2 * Hk))) < 500_000
+
+
+def _match_kvq_pallas(spec: plan_mod.LinearSpec,
+                      policy: plan_mod.PlanPolicy) -> bool:
+    return (spec.kind == "kvq_attn" and policy.impl == "pallas"
+            and _kvq_pallas_admits(spec))
+
+
+def _plan_kvq_pallas(spec: plan_mod.LinearSpec,
+                     policy: plan_mod.PlanPolicy) -> plan_mod.MatmulPlan:
+    interpret = policy.interpret
+
+    def run(operands, _leaf):
+        return flash_decode_kvq(*operands, interpret=interpret)
+
+    return plan_mod.MatmulPlan("kvq_flash_pallas", spec, policy, (), run)
 
 
 def _match_kvq_jnp(spec: plan_mod.LinearSpec,
@@ -217,46 +241,9 @@ def _plan_kvq_jnp(spec: plan_mod.LinearSpec,
     def run(operands, _leaf):
         return flash_decode_kvq(*operands, use_pallas=False)
 
-    # dequantize-then-attend: QK+PV macs over the reconstructed cache,
-    # plus an HBM round trip for the two fp32 reconstructed planes
-    cost = plan_mod.PlanCost(
-        macs=2 * spec.M * spec.K * spec.N,
-        lookup_adds=2 * spec.M * spec.K * spec.C * spec.V,
-        weight_bytes=_kvq_idx_bytes(spec),
-        intermediate_bytes=8 * spec.M * spec.K * spec.C * spec.d,
-        launches=3,
-    )
-    return plan_mod.MatmulPlan("kvq_dequant_jnp", spec, policy, (),
-                               cost, run)
+    return plan_mod.MatmulPlan("kvq_dequant_jnp", spec, policy, (), run)
 
 
-def _match_kvq_pallas(spec: plan_mod.LinearSpec,
-                      policy: plan_mod.PlanPolicy) -> bool:
-    return spec.kind == "kvq_attn" and policy.impl == "pallas"
-
-
-def _plan_kvq_pallas(spec: plan_mod.LinearSpec,
-                     policy: plan_mod.PlanPolicy) -> plan_mod.MatmulPlan:
-    interpret = policy.interpret
-
-    def run(operands, _leaf):
-        return flash_decode_kvq(*operands, interpret=interpret)
-
-    # fused: the S-independent query/K-codebook table (N * E macs per
-    # batch row) + per-token index gathers; intermediates are just the
-    # qd table, not an S-length fp cache
-    H = spec.N // spec.d
-    cost = plan_mod.PlanCost(
-        macs=spec.M * spec.N * spec.k,
-        lookup_adds=spec.M * spec.K * (H + spec.C) * spec.V,
-        weight_bytes=_kvq_idx_bytes(spec),
-        intermediate_bytes=4 * spec.M * H * spec.V * spec.k,
-        launches=1,
-    )
-    return plan_mod.MatmulPlan("kvq_flash_pallas", spec, policy, (),
-                               cost, run)
-
-
-plan_mod.register_backend("kvq_dequant_jnp", _match_kvq_jnp, _plan_kvq_jnp)
 plan_mod.register_backend("kvq_flash_pallas", _match_kvq_pallas,
                           _plan_kvq_pallas)
+plan_mod.register_backend("kvq_dequant_jnp", _match_kvq_jnp, _plan_kvq_jnp)

@@ -156,18 +156,10 @@ def _plan_eva_fused(spec: plan_mod.LinearSpec, policy: plan_mod.PlanPolicy
         return fused_vq_matmul(x, vq, block_v=bv, block_n=bn, m_tile=mt,
                                interpret=interpret, out_dtype=out_dt)
 
-    cost = plan_mod.PlanCost(
-        macs=core_ops.vq_gemm_macs(spec.M, spec.K,
-                                   max(spec.k.bit_length() - 1, 0),
-                                   spec.C, spec.d),
-        lookup_adds=core_ops.epilogue_adds(spec.M, spec.K, spec.N, spec.C,
-                                           spec.d),
-        weight_bytes=plan_mod.vq_weight_bytes(spec),
-    )
     return plan_mod.MatmulPlan(
         "eva_fused_pallas", spec, policy,
         (("mt", mt), ("token_tiles", -(-spec.M // mt)), ("bv", bv),
-         ("bn", bn)), cost, run)
+         ("bn", bn)), run)
 
 
 plan_mod.register_backend("eva_fused_pallas", _match_eva_fused,

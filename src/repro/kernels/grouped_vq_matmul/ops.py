@@ -62,16 +62,9 @@ def _plan(spec: plan_mod.LinearSpec, policy: plan_mod.PlanPolicy
         return grouped_vq_matmul(rows, vq, interpret=interpret,
                                  out_dtype=out_dt)
 
-    cost = plan_mod.PlanCost(
-        macs=core_ops.vq_gemm_macs(spec.M, spec.K,
-                                   max(spec.k.bit_length() - 1, 0),
-                                   spec.C, spec.d),
-        lookup_adds=core_ops.epilogue_adds(spec.M, spec.K, spec.N, spec.C,
-                                           spec.d),
-        weight_bytes=plan_mod.vq_weight_bytes(spec))
     return plan_mod.MatmulPlan(
         "grouped_eva_pallas", spec, policy,
-        (("mt", core_ops.EXPERT_TILE), ("bv", bv), ("bn", spec.N)), cost, run)
+        (("mt", core_ops.EXPERT_TILE), ("bv", bv), ("bn", spec.N)), run)
 
 
 plan_mod.register_backend("grouped_eva_pallas", _match, _plan)

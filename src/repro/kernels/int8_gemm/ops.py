@@ -77,10 +77,8 @@ def _plan_int8_pallas(spec: plan_mod.LinearSpec,
         return int8_matmul_kernel(x, w, block_m=bm, block_n=bn, block_k=bk,
                                   interpret=interpret, out_dtype=out_dt)
 
-    cost = plan_mod.PlanCost(macs=spec.M * spec.K * spec.N, lookup_adds=0,
-                             weight_bytes=spec.K * spec.N)
     return plan_mod.MatmulPlan("int8_pallas", spec, policy,
-                               (("bm", bm), ("bn", bn), ("bk", bk)), cost, run)
+                               (("bm", bm), ("bn", bn), ("bk", bk)), run)
 
 
 plan_mod.register_backend(

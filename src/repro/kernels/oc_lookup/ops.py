@@ -5,10 +5,11 @@ The split backend is the paper-faithful no-fusion formulation: kernel 1
 (kernels/vq_gemm) materializes the full (C, M, V, 2^n) output-codebook
 buffer in HBM, kernel 2 (this module's oc_lookup) runs the structured,
 conflict-free gather + add-only reduction over it. Against the fused
-kernel it trades one extra HBM round-trip of the OC buffer (priced as
-``PlanCost.intermediate_bytes``) and a second launch for per-kernel tile
-freedom — the ranked Planner decides per shape which side of that trade
-wins (analytically the fused kernel; measured calibration can flip it).
+kernel it costs one extra HBM round-trip of the OC buffer and a second
+launch, but its lookup tile holds one v-tile of the OC, not all of V, so
+it takes every shape. The fused backend is registered first and wins
+wherever its matcher admits the shape (its OC tile fits VMEM); this one
+runs where it does not, and is the fused kernel's fallback elsewhere.
 """
 from __future__ import annotations
 
@@ -21,6 +22,9 @@ import jax.numpy as jnp
 from repro.core import ops as core_ops
 from repro.core import plan as plan_mod
 from repro.core.vq import VQWeight
+# registers eva_fused_pallas, which must come before eva_split_pallas in
+# the planner's registration order whichever module is imported first
+from repro.kernels.fused_vq_matmul import ops as _fused_ops  # noqa: F401
 from repro.kernels.gather import SUBLANES, token_tile
 from repro.kernels.oc_lookup.kernel import oc_lookup_pallas
 from repro.kernels.oc_lookup.ref import oc_lookup_ref
@@ -124,9 +128,9 @@ def eva_split_matmul(
 
 
 # ---------------------------------------------------------------------------
-# Plan backend: eva_split_pallas competes with eva_fused_pallas under
-# impl="pallas" — the first genuinely overlapping registration, resolved
-# by the Planner's calibrated predicted-time ranking.
+# Plan backend: eva_split_pallas is registered after eva_fused_pallas, so
+# under impl="pallas" it runs where the fused kernel's OC tile does not
+# fit VMEM, and backs the fused kernel up everywhere else.
 # ---------------------------------------------------------------------------
 
 
@@ -151,21 +155,10 @@ def _plan_eva_split(spec: plan_mod.LinearSpec, policy: plan_mod.PlanPolicy
         return eva_split_matmul(x, vq, block_mv=bmv, block_v=bv, block_n=bn,
                                 interpret=interpret, out_dtype=out_dt)
 
-    oc_bytes = 4 * spec.C * spec.M * spec.V * spec.k
-    cost = plan_mod.PlanCost(
-        macs=core_ops.vq_gemm_macs(spec.M, spec.K,
-                                   max(spec.k.bit_length() - 1, 0),
-                                   spec.C, spec.d),
-        lookup_adds=core_ops.epilogue_adds(spec.M, spec.K, spec.N, spec.C,
-                                           spec.d),
-        weight_bytes=plan_mod.vq_weight_bytes(spec),
-        intermediate_bytes=2 * oc_bytes,   # OC write + read-back through HBM
-        launches=2,
-    )
     return plan_mod.MatmulPlan(
         "eva_split_pallas", spec, policy,
         (("bmv", bmv), ("mt", mt), ("token_tiles", -(-spec.M // mt)),
-         ("bv", bv), ("bn", bn)), cost, run)
+         ("bv", bv), ("bn", bn)), run)
 
 
 plan_mod.register_backend("eva_split_pallas", _match_eva_split,

@@ -508,13 +508,12 @@ def _quantize_kv(x: jax.Array, dtype=jnp.int8):
 def _kvq_decode_attention(q, k_idx, v_idx, k_s, v_s, lengths, cb_k, cb_v,
                           rc: RunConfig, window: int) -> jax.Array:
     """Attend over a KV-VQ cache view (contiguous shape — paged callers
-    gather first). Full-cache sites resolve through the planner: every
-    backend matching kind="kvq_attn" (the dequantize-jnp oracle and,
-    under impl="pallas", the fused kernel) is cost-ranked and the
-    cheapest executes. Ring/SWA caches skip the planner — ring validity
-    semantics live in decode_attention — and always dequantize, as do
-    multi-query windows (speculative verify): the kvq_attn backends are
-    single-query formulations."""
+    gather first). Full-cache sites resolve through the planner: under
+    impl="pallas" the fused kernel on the shapes its rule admits, the
+    dequantize-jnp oracle otherwise. Ring/SWA caches skip the planner —
+    ring validity semantics live in decode_attention — and always
+    dequantize, as do multi-query windows (speculative verify): the
+    kvq_attn backends are single-query formulations."""
     if window == 0 and q.shape[1] == 1:
         B, S, Hk, idx_w = k_idx.shape
         H, hd = q.shape[2], q.shape[3]

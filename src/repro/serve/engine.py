@@ -333,16 +333,10 @@ class Engine:
                 act_dtype=cfg.act_dtype)
         for phase, plans in self.plans.items():
             uniq: Dict[str, int] = {}
-            rankings: Dict[str, int] = {}
             for _path, pl in plans:
                 uniq[pl.describe()] = uniq.get(pl.describe(), 0) + 1
-                rk = pl.describe_ranking()
-                if rk:  # >1 eligible backend: show the predicted-time order
-                    rankings[rk] = rankings.get(rk, 0) + 1
             for desc, count in sorted(uniq.items()):
                 log.info("%s plan [%d leaves] %s", phase, count, desc)
-            for rk, count in sorted(rankings.items()):
-                log.info("%s ranking [%d leaves] %s", phase, count, rk)
         self.metrics_counters.eva_token_tiles = max(
             (pl.config_dict.get("token_tiles", 0)
              for _path, pl in self.plans["decode"]), default=0)
@@ -964,7 +958,7 @@ class Engine:
         consecutive poisoned steps trip the circuit breaker: pending
         requests are rejected and new submits refuse. A scripted
         ``backend`` fault quarantines the planned backend and re-plans
-        (core/plan.py re-ranks; the next-cheapest candidate takes over).
+        (core/plan.py re-plans; the next matching backend takes over).
         Exceptions out of ``step()`` (scripted prefill/decode/sample
         faults, real crashes) leave this tick's events undelivered —
         ``snapshot()``/``restore()`` (serve/resilience.py
@@ -1209,8 +1203,8 @@ class Engine:
         """A scripted backend fault fired: quarantine the named backend
         (default: the decode plan's chosen one) in the default planner
         and re-jit the stepped functions — the retrace re-enters
-        core/plan.py's cost ranking, which now skips the quarantined
-        backend and bakes the next-cheapest candidate in."""
+        core/plan.py, which now skips the quarantined backend and bakes
+        the next matching backend in."""
         if name is None:
             name = self.plans["decode"][0][1].backend
         planner = plan_mod.default_planner()

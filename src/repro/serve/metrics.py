@@ -61,7 +61,7 @@ class EngineMetrics:
     accepted_draft_tokens: int = 0   # drafts that matched the verify sample
     rejected_draft_tokens: int = 0   # drafted - accepted
     extra_decode_tokens: int = 0     # emissions beyond 1 per lane per step
-    backend_fallbacks: int = 0       # planned-backend failures recovered by re-rank
+    backend_fallbacks: int = 0       # planned-backend failures recovered by re-plan
     snapshots: int = 0
     restores: int = 0
     straggler_steps: int = 0         # watchdog-flagged slow decode steps

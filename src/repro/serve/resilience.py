@@ -53,7 +53,7 @@ log = logging.getLogger(__name__)
 #   poison  : add NaN/Inf ("mode") into the target slot's logits INSIDE
 #             the jitted step — exercises the numerics quarantine
 #   backend : simulate a planned backend failing at execute time —
-#             exercises quarantine + re-ranked fallback in core/plan.py
+#             exercises quarantine + the fallback chain in core/plan.py
 BOUNDARIES = ("prefill", "decode", "sample", "poison", "backend")
 POISON_MODES = ("nan", "inf")
 

@@ -1,27 +1,23 @@
-"""benchmarks/schema.py: the eva-bench-rows/v1 gate CI runs against both
-a fresh smoke emission and the committed BENCH_measured.json."""
+"""benchmarks/schema.py: the eva-bench-rows/v1 gate CI runs against a
+fresh `benchmarks/run.py serve --json` emission."""
 import copy
-import json
-import os
 
 from benchmarks import schema
 
-REPO = os.path.join(os.path.dirname(__file__), "..")
+SERVE_TOTALS = {"tokens": 18, "tok_per_s": 10.0, "requests": 3,
+                "kv_bytes_in_use": 0, "blocks_in_use": 0, "blocks_free": 0}
 
 VALID = {
     "schema": "eva-bench-rows/v1",
     "rows": [
         {"module": "fig10", "name": "fig10/decode", "us_per_call": 12.5,
          "derived": {"note": "analytic"}},
-        {"module": "measured", "name": "measured/eva_4096x4096",
-         "us_per_call": 14715.6,
-         "derived": {"plan": "eva_direct M=1 K=4096 N=4096",
-                     "backend": "eva_direct", "macs": 1, "lookup_adds": 2,
-                     "weight_bytes": 3}},
-        {"module": "measured", "name": "measured/ERROR", "us_per_call": -1.0,
+        {"module": "serve", "name": "serve/request_trace",
+         "us_per_call": 100.0, "derived": dict(SERVE_TOTALS)},
+        {"module": "serve", "name": "serve/ERROR", "us_per_call": -1.0,
          "derived": {"note": "ValueError:boom"}},
     ],
-    "failures": ["measured: boom"],
+    "failures": ["serve: boom"],
 }
 
 
@@ -34,72 +30,35 @@ def test_wrong_schema_version():
     assert any("schema" in e for e in schema.validate_rows(doc))
 
 
-def test_measured_row_missing_plan_fails():
+def test_non_serve_modules_need_only_core_fields():
     doc = copy.deepcopy(VALID)
-    del doc["rows"][1]["derived"]["plan"]
-    errs = schema.validate_rows(doc)
-    assert any("derived.plan" in e for e in errs)
+    doc["rows"][0]["derived"] = {}  # analytic fig10 rows carry nothing
+    assert schema.validate_rows(doc) == []
 
 
-def test_measured_row_missing_cost_fields_fails():
-    for field in ("macs", "lookup_adds", "weight_bytes"):
+def test_serve_row_missing_engine_totals_fails():
+    for field in schema.SERVE_FIELDS:
         doc = copy.deepcopy(VALID)
         del doc["rows"][1]["derived"][field]
         errs = schema.validate_rows(doc)
         assert any(f"derived.{field}" in e for e in errs), field
 
 
-def test_smoke_module_held_to_same_contract():
-    doc = copy.deepcopy(VALID)
-    doc["rows"][1]["module"] = "smoke"
-    del doc["rows"][1]["derived"]["backend"]
-    assert any("derived.backend" in e for e in schema.validate_rows(doc))
-
-
-def test_non_calibrated_modules_only_need_core_fields():
-    doc = copy.deepcopy(VALID)
-    doc["rows"][0]["derived"] = {}  # fig10 rows carry no plan
-    assert schema.validate_rows(doc) == []
+def test_serve_error_rows_exempt_from_engine_totals():
+    # the harness's failure rows carry only the exception text
+    assert schema.validate_rows(copy.deepcopy(VALID)) == []
 
 
 def test_malformed_rows_reported():
     doc = copy.deepcopy(VALID)
     doc["rows"][0].pop("us_per_call")
     doc["rows"].append("not a row")
-    doc["rows"].append({"module": "measured", "name": "measured/x",
+    doc["rows"].append({"module": "fig10", "name": "fig10/x",
                         "us_per_call": 1.0, "derived": "not a dict"})
     errs = schema.validate_rows(doc)
     assert any("us_per_call" in e for e in errs)
     assert any("must be an object" in e for e in errs)
     assert any("derived must be an object" in e for e in errs)
-
-
-def test_error_rows_exempt_from_calibration_fields():
-    doc = copy.deepcopy(VALID)
-    # the harness's failure rows carry only the exception text
-    assert schema.validate_rows(doc) == []
-
-
-def test_committed_bench_file_validates():
-    """The schema gate CI applies to BENCH_measured.json must hold for
-    the file as committed in this very PR."""
-    path = os.path.join(REPO, "BENCH_measured.json")
-    assert schema.validate_file(path) == []
-
-
-def test_committed_calibration_loads():
-    """CALIBRATION.json (fitted from the committed bench rows) must load
-    under the current schema version — the Planner reads it at
-    construction."""
-    from repro.core import calibrate
-
-    path = os.path.join(REPO, "CALIBRATION.json")
-    calib = calibrate.load_calibration(path)
-    assert calib is not None, "CALIBRATION.json missing or version-skewed"
-    assert calib.backends, "no fitted backends"
-    # interpret-only backends must never have fitted entries on this host
-    assert calib.get("eva_fused_pallas") is None
-    assert calib.get("eva_split_pallas") is None
 
 
 def test_validate_file_reports_unreadable(tmp_path):

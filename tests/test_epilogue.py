@@ -97,8 +97,8 @@ class TestEquivalence:
 
 
 class TestSelection:
-    """Pin the heuristic's regime boundaries (measured crossovers on the
-    CI host, benchmarks/measured.py batch + crossover sweeps)."""
+    """Pin the heuristic's regime boundaries (crossovers measured by
+    batch and crossover sweeps on a CPU host)."""
 
     def test_single_token_decode_is_direct(self):
         # paper decode shape M=1, llama-2-7b layer: footprint 17 MB
@@ -119,7 +119,7 @@ class TestSelection:
 
     def test_batched_decode_is_recon(self):
         # M >= d: gather work C*M*V*N exceeds the C*V*N*d reconstruction
-        # gathers -> slab-tiled reconstruct-and-GEMM (the measured/batch32
+        # gathers -> slab-tiled reconstruct-and-GEMM (the batch-32 CPU
         # fix: recon ~72 ms vs dequant ~260 ms vs direct ~790 ms)
         for M in (8, 16, 32):
             kind, bv = ops.select_epilogue(M, 512, 4096, 2, 256, 8)

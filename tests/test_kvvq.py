@@ -1,7 +1,7 @@
 """KV-VQ cache subsystem (core/vq.py + serve/kvcache.py + paging +
 kernels/flash_decode): encode/decode round-trip geometry, kernel parity
 against the dequantize oracle (contiguous AND paged), planner backend
-registration/ranking, per-family logit-drift bounds vs the fp cache,
+registration and choice, per-family logit-drift bounds vs the fp cache,
 paged-vs-contiguous byte identity of the uint8 index arenas, and
 engine-level token identity at 4-bit on a mixed workload."""
 import dataclasses
@@ -147,8 +147,8 @@ class TestPlanBackends:
 
     def test_backend_selection_by_policy(self):
         """kind="kvq_attn" resolves to the dequantize oracle under jnp
-        and to the fused kernel under impl="pallas" — cost ranking
-        prefers the single-launch table+gather formulation."""
+        and to the fused kernel under impl="pallas", whose rule admits
+        this short, narrow cache."""
         spec = self._spec()
         assert plan_mod.plan(spec, PlanPolicy()).backend == "kvq_dequant_jnp"
         pl = plan_mod.plan(spec, PlanPolicy(impl="pallas", interpret=True))

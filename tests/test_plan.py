@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import logits_vq  # noqa: F401  (registers vq_logits backends)
 from repro.core import ops
 from repro.core import plan as plan_mod
 from repro.core.plan import LinearSpec, PlanPolicy
@@ -124,12 +125,6 @@ class TestPlanParity:
             tol = 0.15 if backend.startswith("int8") else 1e-5
             np.testing.assert_allclose(got, ref, rtol=tol, atol=tol)
 
-    def test_cost_estimates_present(self):
-        x, vq = _mk(80, 70, (), 1)
-        pl = plan_mod.plan_vq(x, vq, PlanPolicy(vq_mode="eva"))
-        assert pl.cost.macs > 0 and pl.cost.weight_bytes > 0
-        assert "eva" in pl.describe() and "M=1" in pl.describe()
-
 
 class TestContradictoryPolicies:
     """Ported from the resolve_epilogue error tests: contradictions are
@@ -206,11 +201,10 @@ class TestContradictoryPolicies:
 
 
 class TestRankedSelection:
-    """Tentpole: the Planner collects every matching backend and picks
-    the cheapest predicted time. impl='pallas' is the genuinely
-    overlapping registration — eva_fused_pallas vs the two-kernel
-    eva_split_pallas — so these tests pin the ranking there, with and
-    without a calibration."""
+    """The Planner plans the first healthy match in registration order.
+    impl='pallas' is where two EVA backends overlap — eva_fused_pallas,
+    registered first, and the two-kernel eva_split_pallas — so these
+    tests pin the order there."""
 
     PALLAS = PlanPolicy(vq_mode="eva", impl="pallas", interpret=True)
 
@@ -218,159 +212,72 @@ class TestRankedSelection:
         return LinearSpec.for_vq(vq, M=x.size // vq.K, x_dtype=x.dtype,
                                  out_dtype=jnp.float32)
 
-    @staticmethod
-    def _entry(overhead, rows=8):
-        from repro.core import calibrate
-
-        return calibrate.BackendCalibration(
-            overhead_us=overhead, us_per_mac=0.0, us_per_add=0.0,
-            us_per_byte=0.0, rows=rows)
-
-    @classmethod
-    def _calib(cls, fused_overhead, split_overhead, rows=8):
-        from repro.core import calibrate
-
-        return calibrate.Calibration(
-            version=calibrate.SCHEMA, source="test",
-            backends={"eva_fused_pallas": cls._entry(fused_overhead, rows),
-                      "eva_split_pallas": cls._entry(split_overhead, rows)},
-            device=jax.devices()[0].device_kind)
-
     def test_analytic_fallback_ranks_fused_first(self):
-        """No calibration: the analytic model prices the split backend's
-        OC round-trip + second launch, so fused wins — deterministically,
-        with both candidates recorded and provenance labeled."""
-        x, vq = _mk(80, 70, (), 2)
-        planner = plan_mod.Planner(calibration=None)
-        pl = planner.plan(self._spec(x, vq), self.PALLAS)
-        assert pl.backend == "eva_fused_pallas"
-        assert pl.provenance == "analytic"
-        assert [b for b, _ in pl.ranking] == ["eva_fused_pallas",
-                                              "eva_split_pallas"]
-        us = [u for _, u in pl.ranking]
-        assert us == sorted(us) and us[0] < us[1]
-        assert "pred=" in pl.describe() and "analytic" in pl.describe()
-        assert "eva_split_pallas" in pl.describe_ranking()
-
-    def test_calibration_flips_choice_to_split(self):
-        """A calibration that prices the fused kernel above the split
-        backend must flip the ranked choice — and the split plan must
-        match the dequant oracle (two kernels, OC buffer in between)."""
+        """The fused kernel is planned wherever it fits, and the split
+        backend is next in its fallback chain: it takes over once the
+        fused backend is quarantined, and matches the dequant oracle."""
         x, vq = _mk(96, 96, (50, 26, 20), 2)  # grouped family too
-        planner = plan_mod.Planner(calibration=self._calib(1e6, 1.0))
-        pl = planner.plan(self._spec(x, vq), self.PALLAS)
+        spec = self._spec(x, vq)
+        planner = plan_mod.Planner(cooloff_s=60.0)
+        assert planner.plan(spec, self.PALLAS).backend == "eva_fused_pallas"
+        matched = [be.name for be in plan_mod.Planner._match_all(
+            spec, self.PALLAS)]
+        assert matched == ["eva_fused_pallas", "eva_split_pallas"]
+        planner.record_backend_failure("eva_fused_pallas")
+        pl = planner.plan(spec, self.PALLAS)
         assert pl.backend == "eva_split_pallas"
-        assert pl.provenance == "eva-calibration/v1"
-        assert [b for b, _ in pl.ranking] == ["eva_split_pallas",
-                                              "eva_fused_pallas"]
         got = pl.execute(x, vq)
         ref = ops.dequant_matmul(x, vq, out_dtype=jnp.float32)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    rtol=2e-4, atol=2e-4)
 
-    def test_partial_calibration_never_mixes_models(self):
-        """When only ONE of the competing backends has a fitted entry,
-        the ranking must fall back to the analytic model for BOTH —
-        fitted microseconds vs analytic fantasy numbers is not a
-        comparison (a partial CALIBRATION.json must not flip choices)."""
-        from repro.core import calibrate
+    def test_split_registers_after_fused_whatever_imports_first(self):
+        """The split backend's module is imported first in a fresh
+        process, and the fused backend still precedes it."""
+        import os
+        import subprocess
+        import sys
 
-        x, vq = _mk(80, 70, (), 2)
-        partial = calibrate.Calibration(
-            version=calibrate.SCHEMA, source="partial",
-            backends={"eva_split_pallas": self._entry(1.0)})
-        planner = plan_mod.Planner(calibration=partial)
-        pl = planner.plan(self._spec(x, vq), self.PALLAS)
-        assert pl.backend == "eva_fused_pallas"  # analytic order holds
-        assert pl.provenance == "analytic"
-
-    @pytest.mark.parametrize("device", ["", "TPU v5 lite"])
-    def test_calibration_from_another_device_not_applied(self, device):
-        """A calibration whose rows name no device, or a device other
-        than this process's, leaves the ranking analytic."""
-        import dataclasses as dc
-
-        x, vq = _mk(80, 70, (), 2)
-        foreign = dc.replace(self._calib(1e6, 1.0), device=device)
-        planner = plan_mod.Planner(calibration=foreign)
-        pl = planner.plan(self._spec(x, vq), self.PALLAS)
-        assert pl.backend == "eva_fused_pallas"
-        assert pl.provenance == "analytic"
-
-    def test_underfitted_entries_not_trusted_for_ranking(self):
-        """Entries resting on fewer than MIN_FIT_ROWS samples (NNLS with
-        4 free parameters fits 1-3 rows perfectly but arbitrarily) must
-        not drive the ranking."""
-        from repro.core import calibrate
-
-        x, vq = _mk(80, 70, (), 2)
-        thin = self._calib(1e6, 1.0, rows=calibrate.MIN_FIT_ROWS - 1)
-        planner = plan_mod.Planner(calibration=thin)
-        pl = planner.plan(self._spec(x, vq), self.PALLAS)
-        assert pl.backend == "eva_fused_pallas"
-        assert pl.provenance == "analytic"
+        code = ("import repro.kernels.oc_lookup.ops\n"
+                "from repro.core import plan\n"
+                "print(' '.join(plan.registered_backends()))\n")
+        src = os.path.join(os.path.dirname(__file__), "..", "src")
+        env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=src)
+        proc = subprocess.run([sys.executable, "-c", code], env=env,
+                              capture_output=True, text=True, timeout=300)
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        names = proc.stdout.split()
+        assert names.index("eva_fused_pallas") < names.index(
+            "eva_split_pallas")
 
     def test_choice_is_deterministic_across_planners(self):
         x, vq = _mk(80, 70, (), 1)
-        for calib in (None, self._calib(10.0, 1e6), self._calib(1e6, 10.0)):
-            a = plan_mod.Planner(calibration=calib)
-            b = plan_mod.Planner(calibration=calib)
-            pa = a.plan(self._spec(x, vq), self.PALLAS)
-            pb = b.plan(self._spec(x, vq), self.PALLAS)
-            assert pa.backend == pb.backend
-            assert pa.ranking == pb.ranking
-
-    def test_cache_identity_unchanged_under_calibration_reload(self):
-        """Reloading calibration swaps the cost model for FUTURE misses
-        only: a cached (spec, policy) keeps returning the SAME plan
-        object, so traced programs and cache stats stay coherent."""
-        x, vq = _mk(80, 70, (), 2)
-        planner = plan_mod.Planner(calibration=None)
         spec = self._spec(x, vq)
-        p1 = planner.plan(spec, self.PALLAS)
-        assert p1.backend == "eva_fused_pallas"
-        planner.reload_calibration(self._calib(1e6, 1.0))
-        assert planner.plan(spec, self.PALLAS) is p1  # identity preserved
-        hits = planner.cache_info().hits
-        assert hits >= 1
-        # a NEW spec planned after the reload uses the new constants
-        x2, vq2 = _mk(88, 132, (), 2)
-        p2 = planner.plan(self._spec(x2, vq2), self.PALLAS)
-        assert p2.backend == "eva_split_pallas"
-        # clearing the cache re-ranks the original spec under the reload
-        planner.cache_clear()
-        assert planner.plan(spec, self.PALLAS).backend == "eva_split_pallas"
+        a = plan_mod.Planner().plan(spec, self.PALLAS)
+        b = plan_mod.Planner().plan(spec, self.PALLAS)
+        assert a.backend == b.backend == "eva_fused_pallas"
+        assert a.config == b.config
+        assert "pred=" not in a.describe()
 
     def test_split_plan_freezes_two_kernel_tiles(self):
-        x, vq = _mk(256, 512, (), 1)
-        planner = plan_mod.Planner(calibration=self._calib(1e6, 1.0))
-        pl = planner.plan(self._spec(x, vq), self.PALLAS)
+        """At K=53248 the fused kernel's OC tile of 8 rows does not fit
+        VMEM, so for 16 rows its matcher declines and the split backend
+        is the plan, every row in one lookup tile."""
+        spec = LinearSpec(M=16, K=53248, N=512, kind="vq", x_dtype="float32",
+                          out_dtype="float32", C=2, V=53248 // 8, k=256, d=8)
+        from repro.kernels.fused_vq_matmul.ops import select_fused_tiles
+
+        assert select_fused_tiles(spec.M, spec.V, spec.N, spec.C,
+                                  spec.k)[0] == 0
+        pl = plan_mod.Planner().plan(spec, self.PALLAS)
+        assert pl.backend == "eva_split_pallas"
         cfg = pl.config_dict
         assert set(cfg) == {"bmv", "mt", "token_tiles", "bv", "bn"}
-        assert cfg["mt"] == 1 and cfg["token_tiles"] == 1
-        assert pl.cost.launches == 2
-        # the HBM OC round-trip is priced: write + read of (C, M, V, 2^n)
-        assert pl.cost.intermediate_bytes == 2 * 4 * vq.C * 1 * vq.V * 256
-
-    def test_single_candidate_sites_report_no_ranking(self):
-        x, vq = _mk(80, 70, (), 1)
-        pl = plan_mod.plan_vq(x, vq, PlanPolicy(vq_mode="eva"))
-        assert len(pl.ranking) == 1 and pl.describe_ranking() == ""
-        assert pl.predicted_us is not None
-
-    def test_first_match_backend_reports_registration_order(self):
-        x, vq = _mk(80, 70, (), 1)
-        spec = self._spec(x, vq)
-        # registration order: fused_vq_matmul.ops imports before
-        # oc_lookup.ops in _KERNEL_BACKEND_MODULES
-        assert plan_mod.first_match_backend(spec, self.PALLAS) == \
-            "eva_fused_pallas"
-        assert plan_mod.first_match_backend(
-            spec, PlanPolicy(vq_mode="eva")) == "eva_direct"
+        assert cfg["mt"] == 16 and cfg["token_tiles"] == 1
 
     def test_engine_logs_predicted_time_ranking(self, caplog):
-        """serve/engine.py pre-plan logs surface the ranking when >1
-        backend was eligible (the pallas decode policy)."""
+        """serve/engine.py's pre-plan log names the backend each leaf was
+        planned to (the fused kernel under the pallas decode policy)."""
         import dataclasses as dc
         import logging
 
@@ -387,11 +294,124 @@ class TestRankedSelection:
                                               interpret=True))
         with caplog.at_level(logging.INFO, logger="repro.serve.engine"):
             Engine(model, params, rc, EngineConfig(num_slots=2, max_len=16))
-        ranking_lines = [r.message for r in caplog.records
-                         if "ranking" in r.message]
-        assert ranking_lines
-        assert any("eva_split_pallas" in m and "eva_fused_pallas" in m
-                   for m in ranking_lines)
+        plan_lines = [r.message for r in caplog.records
+                      if r.message.startswith("decode plan [")]
+        assert any("eva_fused_pallas" in m for m in plan_lines)
+        assert not any("ranking" in r.message for r in caplog.records)
+
+
+# (spec, policy) -> the backend the planner chose for it before choice by
+# registration order replaced the priced ranking, recorded by running
+# that planner. Each side of the kvq_attn and vq_logits rules, both sides
+# of the fused kernel's VMEM fit at the two benchmark cells' linear
+# shapes, and every single-candidate kind under each impl.
+def _vq(M, K, N, splits=(), kind="vq"):
+    return LinearSpec(M=M, K=K, N=N, kind=kind, x_dtype="bfloat16",
+                      out_dtype="bfloat16", C=2, V=K // 8, k=256, d=8,
+                      splits=splits)
+
+
+def _dense(M, K, N, kind="dense"):
+    return LinearSpec(M=M, K=K, N=N, kind=kind, x_dtype="bfloat16",
+                      out_dtype="bfloat16")
+
+
+def _kvq(B, S, H, Hk, hd, W):
+    return plan_mod.kvq_attention_spec(
+        B=B, S=S, H=H, Hk=Hk, hd=hd, idx_width=W, entries=256,
+        x_dtype=jnp.bfloat16, out_dtype=jnp.bfloat16)
+
+
+def _vql(M, D, V, Kc, x_dtype="float32"):
+    return LinearSpec(M=M, K=D, N=V, kind="vq_logits", x_dtype=x_dtype,
+                      out_dtype="float32", k=Kc)
+
+
+_JNP, _PAL = PlanPolicy(), PlanPolicy(impl="pallas")
+_EVA, _EVA_PAL = PlanPolicy(vq_mode="eva"), PlanPolicy(vq_mode="eva",
+                                                       impl="pallas")
+_DQ, _DQ_PAL = PlanPolicy(vq_mode="dequant"), PlanPolicy(vq_mode="dequant",
+                                                         impl="pallas")
+_MINITRON_KV = dict(H=24, Hk=8, hd=128)  # W=32 at kv_bits=2, 64 at 4
+
+PARENT_CHOICES = {
+    "kvq-minitron-kv2-B16-S1152": (_kvq(16, 1152, W=32, **_MINITRON_KV),
+                                   _PAL, "kvq_flash_pallas"),
+    "kvq-minitron-kv2-B16-S128": (_kvq(16, 128, W=32, **_MINITRON_KV),
+                                  _PAL, "kvq_dequant_jnp"),
+    "kvq-minitron-kv4-B16-S1152": (_kvq(16, 1152, W=64, **_MINITRON_KV),
+                                   _PAL, "kvq_dequant_jnp"),
+    "kvq-minitron-kv2-B1-S256": (_kvq(1, 256, W=32, **_MINITRON_KV),
+                                 _PAL, "kvq_flash_pallas"),
+    "kvq-minitron-kv2-B1-S64": (_kvq(1, 64, W=32, **_MINITRON_KV),
+                                _PAL, "kvq_dequant_jnp"),
+    "kvq-small-B2-S32": (_kvq(2, 32, 4, 2, 8, 4), _PAL, "kvq_flash_pallas"),
+    "kvq-small-B4-S64": (_kvq(4, 64, 8, 8, 64, 32), _PAL, "kvq_flash_pallas"),
+    "kvq-small-B8-S64": (_kvq(8, 64, 8, 8, 64, 32), _PAL, "kvq_dequant_jnp"),
+    "kvq-jnp": (_kvq(16, 1152, W=32, **_MINITRON_KV), _JNP,
+                "kvq_dequant_jnp"),
+    "vql-decode-kc4096": (_vql(16, 3072, 256000, 4096, "bfloat16"), _JNP,
+                          "vql_gather_jnp"),
+    "vql-small-pallas": (_vql(1, 64, 512, 32), _PAL, "vql_gather_jnp"),
+    "vql-kc-eq-vocab-M8": (_vql(8, 64, 128, 128), _JNP, "vql_gather_jnp"),
+    "vql-kc-eq-vocab-M256": (_vql(256, 64, 128, 128), _JNP,
+                             "vql_dequant_jnp"),
+    "vq-pallas-minitron-qkv": (_vq(16, 3072, 5120, (3072, 1024, 1024)),
+                               _EVA_PAL, "eva_fused_pallas"),
+    "vq-pallas-minitron-o": (_vq(16, 3072, 3072), _EVA_PAL,
+                             "eva_fused_pallas"),
+    "vq-pallas-minitron-gate-up": (_vq(16, 3072, 18432, (9216, 9216)),
+                                   _EVA_PAL, "eva_fused_pallas"),
+    "vq-pallas-minitron-down": (_vq(16, 9216, 3072), _EVA_PAL,
+                                "eva_fused_pallas"),
+    "vq-pallas-deepseek-q-kv-a": (_vq(32, 2048, 3648, (3072, 576)),
+                                  _EVA_PAL, "eva_fused_pallas"),
+    "vq-pallas-deepseek-o": (_vq(32, 2048, 2048), _EVA_PAL,
+                             "eva_fused_pallas"),
+    "vq-pallas-deepseek-dense-gate-up": (
+        _vq(32, 2048, 21888, (10944, 10944)), _EVA_PAL, "eva_fused_pallas"),
+    "vq-pallas-deepseek-dense-down": (_vq(32, 10944, 2048), _EVA_PAL,
+                                      "eva_fused_pallas"),
+    "vq-pallas-deepseek-shared-gate-up": (
+        _vq(32, 2048, 5632, (2816, 2816)), _EVA_PAL, "eva_fused_pallas"),
+    "vq-pallas-deepseek-shared-down": (_vq(32, 2816, 2048), _EVA_PAL,
+                                       "eva_fused_pallas"),
+    "vq-pallas-oc-too-wide-M16": (_vq(16, 53248, 2048), _EVA_PAL,
+                                  "eva_split_pallas"),
+    "vq-pallas-oc-too-wide-M32": (_vq(32, 53248, 2048), _EVA_PAL,
+                                  "eva_split_pallas"),
+    "grouped-pallas-gate-up": (
+        _vq(640, 2048, 2816, (1408, 1408), "vq_grouped"), _EVA_PAL,
+        "grouped_eva_pallas"),
+    "grouped-pallas-down": (_vq(640, 1408, 2048, kind="vq_grouped"),
+                            _EVA_PAL, "grouped_eva_pallas"),
+    "grouped-jnp-eva": (_vq(640, 2048, 2816, (1408, 1408), "vq_grouped"),
+                        _EVA, "grouped_eva_jnp"),
+    "grouped-jnp-dequant": (
+        _vq(640, 2048, 2816, (1408, 1408), "vq_grouped"), _DQ,
+        "grouped_dequant_jnp"),
+    "grouped-pallas-dequant": (
+        _vq(640, 2048, 2816, (1408, 1408), "vq_grouped"), _DQ_PAL,
+        "grouped_dequant_jnp"),
+    "dense-jnp": (_dense(16, 3072, 256000), _JNP, "fp"),
+    "dense-pallas": (_dense(16, 3072, 256000), _PAL, "fp"),
+    "int8-jnp": (_dense(128, 3072, 3072, "int8"), _JNP, "int8_jnp"),
+    "int8-pallas": (_dense(128, 3072, 3072, "int8"), _PAL, "int8_pallas"),
+    "vq-jnp-eva-M1": (_vq(1, 4096, 4096), _EVA, "eva_direct"),
+    "vq-jnp-eva-M16": (_vq(16, 3072, 3072), _EVA, "eva_recon"),
+    "vq-jnp-eva-in-mesh": (dataclasses.replace(_vq(16, 3072, 3072),
+                                               in_mesh=True),
+                           _EVA, "eva_flat"),
+    "vq-jnp-eva-prefill": (_vq(2048, 3072, 9216), _EVA, "eva_recon"),
+    "vq-jnp-dequant": (_vq(16, 3072, 3072), _DQ, "dequant_jnp"),
+    "vq-pallas-dequant": (_vq(16, 3072, 3072), _DQ_PAL, "dequant_pallas"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PARENT_CHOICES))
+def test_choice_matches_parent(case):
+    spec, policy, backend = PARENT_CHOICES[case]
+    assert plan_mod.Planner().plan(spec, policy).backend == backend
 
 
 class TestDequantPallasReachable:

@@ -2,7 +2,7 @@
 wiring): deterministic fault injection at every boundary, NaN/Inf slot
 quarantine, request deadlines, engine snapshot/restore (incl. the
 CheckpointManager wire format), the serve restart controller and backend
-quarantine + cost-ranked fallback in core/plan.py."""
+quarantine + fallback chain in core/plan.py."""
 import dataclasses
 import time
 
@@ -14,8 +14,8 @@ import pytest
 from repro.checkpoint.manager import CheckpointManager
 from repro.configs import get_smoke_config
 from repro.core import plan as plan_mod
-from repro.core.plan import (LinearSpec, MatmulPlan, PlanCost, PlanPolicy,
-                             Planner, register_backend)
+from repro.core.plan import (LinearSpec, MatmulPlan, PlanPolicy, Planner,
+                             register_backend)
 from repro.models import build_model
 from repro.models.common import RunConfig
 from repro.serve import (Engine, EngineConfig, GenerationRequest,
@@ -562,7 +562,7 @@ class TestBackendFallbackVQ:
             self, dense_setup):
         """A scripted backend fault on an EVA-quantized engine
         quarantines the PLANNED backend and re-plans through
-        core/plan.py's ranking; the next-cheapest eligible candidate
+        core/plan.py; the next eligible candidate
         (another EVA formulation, or ultimately the dequant jnp baseline
         — all token-exact) takes over and the stream stays identical."""
         cfg, model, params, rc = dense_setup
@@ -740,7 +740,7 @@ class TestAcceptance:
 _SENTINEL_N = 9973  # prime; no real layer width
 
 
-def _synthetic_backend(name, fail, us):
+def _synthetic_backend(name, fail):
     def matcher(s, p):
         return s.kind == "dense" and s.N == _SENTINEL_N
 
@@ -750,8 +750,7 @@ def _synthetic_backend(name, fail, us):
                 raise RuntimeError(f"{name} exploded")
             return x @ w
 
-        return MatmulPlan(name, s, p, (), PlanCost(
-            macs=us, lookup_adds=0, weight_bytes=1), run)
+        return MatmulPlan(name, s, p, (), run)
 
     return matcher, planner_fn
 
@@ -759,9 +758,14 @@ def _synthetic_backend(name, fail, us):
 @pytest.fixture(scope="module")
 def synthetic_backends():
     register_backend("t_cheap_flaky", *_synthetic_backend(
-        "t_cheap_flaky", fail=True, us=1))
+        "t_cheap_flaky", fail=True))
     register_backend("t_pricey_solid", *_synthetic_backend(
-        "t_pricey_solid", fail=False, us=10 ** 12))
+        "t_pricey_solid", fail=False))
+    # the plan is the first match in registration order: put the pair
+    # ahead of the built-in "fp", which matches every dense spec, so the
+    # chain for the sentinel spec is flaky -> solid -> fp
+    for name in ("t_pricey_solid", "t_cheap_flaky"):
+        plan_mod._REGISTRY.move_to_end(name, last=False)
     return LinearSpec(M=4, K=8, N=_SENTINEL_N, kind="dense",
                       x_dtype="float32", out_dtype="float32")
 
@@ -772,7 +776,7 @@ class TestPlannerQuarantine:
         spec = synthetic_backends
         pl = Planner(cooloff_s=60.0)
         plan = pl.plan(spec, PlanPolicy())
-        assert plan.backend == "t_cheap_flaky"       # cheapest candidate
+        assert plan.backend == "t_cheap_flaky"       # first registered
         x = jnp.ones((4, 8), jnp.float32)
         w = jnp.ones((8, _SENTINEL_N), jnp.float32)
         out = plan.execute(x, w)                     # chains to a survivor
@@ -791,7 +795,7 @@ class TestPlannerQuarantine:
         assert pl.plan(spec, PlanPolicy()).backend != "t_cheap_flaky"
         time.sleep(0.06)
         # expiry releases the backend AND clears the cache, so the
-        # recovered candidate is re-ranked rather than shadowed
+        # recovered candidate is re-planned rather than shadowed
         assert pl.plan(spec, PlanPolicy()).backend == "t_cheap_flaky"
         assert pl.backend_stats()["quarantined"] == ()
 
